@@ -21,6 +21,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+from pathlib import Path
 
 
 def main() -> None:
@@ -39,6 +40,8 @@ def main() -> None:
                          "BENCH_wide_ops.json")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     from benchmarks import ablation, kernels_bench, tables
     n_sets = 200 if args.full else 40
     n_time = 200 if args.full else 24

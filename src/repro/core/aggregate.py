@@ -58,14 +58,16 @@ round-robined across the mesh axis and every shard runs the same
 A one-device mesh falls back transparently to the single-dispatch path.
 
 With an ``arena`` (core/arena.py), the sharded path never re-stages
-resident rows: each shard gathers them from its LOCAL slab via
-``ShardSlabs.assembled()`` global positions inside one jit
-(``_shard_reduce_arena``, mirroring ``pairwise._topk_sharded``), so warm
-sharded aggregates move only segment ids over the bridge and fold
-partials on device -- zero container rows over PCIe, stat-asserted per
+resident rows: each row is reduced on the shard whose slab holds it,
+gathered from that LOCAL slab inside one jit (``_shard_reduce_arena``,
+mirroring ``pairwise._topk_sharded``), so warm sharded aggregates move
+only segment ids over the bridge, no row leaves its shard, and partials
+fold on device -- zero container rows over PCIe, stat-asserted per
 shard.  Cold rows ride a small replicated staged block whose row 0 is
-reserved zero, making ``assembled[pos] | staged[sidx]`` exact slot
-selection.
+reserved zero and reduce on shard 0, whose local row 0 is the arena's
+zero row, making ``slab[pos] | staged[sidx]`` exact slot selection.
+ANDNOT there reduces minuends and subtrahends as separate OR segments
+and folds ``minuend & ~subtrahends`` after the exchange.
 """
 
 from __future__ import annotations
@@ -451,9 +453,9 @@ def _dispatch(seg_keys: list, seg_rows: list[list[np.ndarray]],
             planes = _planes_for([sum(w) for w in seg_weights], tmax)
         t_arg = threshold if tvec is None else np.asarray(tvec, np.int32)
         if arena is not None:
-            # resident rows gather from each shard's LOCAL slab inside
-            # the jit (ShardSlabs.assembled positions) -- ids over the
-            # bridge, zero container rows over PCIe
+            # resident rows reduce on the shard that holds them, gathered
+            # from its LOCAL slab inside the jit -- ids over the bridge,
+            # zero container rows over PCIe
             words, cards = _shard_reduce_arena(
                 arena, seg_rows, lens, seg_weights, op, t_arg,
                 backend, mesh, planes=planes, tmax=tmax)
@@ -634,7 +636,6 @@ def _shard_reduce(slab: jax.Array, seg_sizes: list[int],
     segment no shard occupies resolves to empty -- the shard-safe
     empty-shard identity the single-device plan never needed.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     d = _mesh_size(mesh)
@@ -707,10 +708,10 @@ def _shard_reduce(slab: jax.Array, seg_sizes: list[int],
 
     spec = PartitionSpec(axis)
     with mesh:
-        words, cards = shard_map(
+        words, cards = jax.shard_map(
             body, mesh=mesh, in_specs=(spec, spec, spec),
             out_specs=(PartitionSpec(), PartitionSpec()),
-            check_rep=False)(slab_all, jnp.asarray(starts_all),
+            check_vma=False)(slab_all, jnp.asarray(starts_all),
                              jnp.asarray(w_all))
     return words[:s], cards[:s]
 
@@ -721,25 +722,30 @@ _SHARD_JIT: dict = {}       # (mesh, op, backend, d, jmax, planes) -> fn
 def _sharded_rows_fn(mesh, axis: str, op: str, backend, d: int,
                      jmax: int, planes: int | None):
     """One jit'd sharded dispatch per (mesh, op, backend, depth) class --
-    the boolean twin of ``pairwise.SimilarityEngine._sharded_fn``: gather
-    every shard's rows from the assembled per-shard slab (resident
-    positions) OR'd with a small replicated staged block (cold rows),
-    reduce per shard with the segment kernel, and fold the partials with
-    the exact ``_shard_reduce`` exchange rules.  The threshold rides as a
-    traced argument (scalar or per-segment vector), so T-sweeps and
-    coalesced batches reuse one compilation."""
+    the boolean twin of ``pairwise._sharded_topk``: under ``shard_map``
+    every shard gathers its rows from its OWN slab by local index (the
+    assembled per-shard slab splits back over ``axis``), OR'd with a
+    small replicated staged block (cold rows, all routed to shard 0,
+    whose local row 0 is the arena's reserved zero row), reduces them
+    with the segment kernel, and the partials fold with the
+    ``_shard_reduce`` exchange rules.  ANDNOT reduces with OR over
+    twice the segments -- subtrahends, then each minuend on the shard
+    that holds it -- and folds ``minuend & ~subtrahends`` after the
+    exchange.  The threshold rides as a traced argument (scalar or
+    per-segment vector), so T-sweeps and coalesced batches reuse one
+    compilation."""
     key = (mesh, op, backend, d, jmax, planes)
     fn = _SHARD_JIT.get(key)
     if fn is not None:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    def body(slab_d, starts_d, w_d, t):
-        slab_l, starts_l, w_l = slab_d[0], starts_d[0], w_d[0]
+    def body(slab, staged, lpos, sidx, starts_d, w_d, t):
+        rows = kref.gather_rows_dual(slab, staged, lpos[0], sidx[0])
+        starts_l, w_l = starts_d[0], w_d[0]
         if op == "threshold":
             local = kops.segment_counters(
-                slab_l, starts_l, jmax=jmax, planes=planes, weights=w_l,
+                rows, starts_l, jmax=jmax, planes=planes, weights=w_l,
                 backend=backend)
             allp = jax.lax.all_gather(local, axis)      # (D, S, L, WORDS)
             tot = allp[0]
@@ -747,7 +753,7 @@ def _sharded_rows_fn(mesh, axis: str, op: str, backend, d: int,
                 tot = kref.bitsliced_add(tot, allp[i])
             words = jnp.asarray(kref.counters_ge(tot, t))
         elif op == "and":
-            pw, _ = kops.segment_reduce(slab_l, starts_l, op, jmax=jmax,
+            pw, _ = kops.segment_reduce(rows, starts_l, op, jmax=jmax,
                                         backend=backend)
             occ = (starts_l[1:] - starts_l[:-1]) > 0    # local occupancy
             pw = jnp.where(occ[:, None], pw, jnp.uint32(0xFFFFFFFF))
@@ -759,28 +765,23 @@ def _sharded_rows_fn(mesh, axis: str, op: str, backend, d: int,
                 any_occ = any_occ | allo[i]
             words = jnp.where(any_occ[:, None], words, jnp.uint32(0))
         else:
-            pw, _ = kops.segment_reduce(slab_l, starts_l, op, jmax=jmax,
-                                        backend=backend)
+            pw, _ = kops.segment_reduce(
+                rows, starts_l, "or" if op == "andnot" else op,
+                jmax=jmax, backend=backend)
             allw = jax.lax.all_gather(pw, axis)         # (D, S, WORDS)
-            comb = {"or": jnp.bitwise_or, "xor": jnp.bitwise_xor,
-                    "andnot": jnp.bitwise_and}[op]
+            comb = jnp.bitwise_xor if op == "xor" else jnp.bitwise_or
             words = allw[0]
             for i in range(1, d):
                 words = comb(words, allw[i])
+            if op == "andnot":                          # [subtrahends | minuends]
+                half = words.shape[0] // 2
+                words = words[half:] & ~words[:half]
         return words, kref.popcount_words(words)
 
     sp = P(axis)
-    sm = shard_map(body, mesh=mesh, in_specs=(sp, sp, sp, P()),
-                   out_specs=(P(), P()), check_rep=False)
-
-    def run(slab, staged, pos, sidx, starts_all, w_all, t):
-        dd, n_pad = pos.shape
-        rows = kref.gather_rows_dual(
-            slab, staged, pos.reshape(-1), sidx.reshape(-1)
-        ).reshape(dd, n_pad, WORDS)
-        return sm(rows, starts_all, w_all, t)
-
-    fn = jax.jit(run)
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(sp, P(), sp, sp, sp, sp, P()),
+        out_specs=(P(), P()), check_vma=False))
     _SHARD_JIT[key] = fn
     return fn
 
@@ -791,58 +792,64 @@ def _shard_reduce_arena(arena, seg_rows: list[list], seg_sizes: list[int],
                         planes: int | None = None,
                         tmax: int | None = None):
     """Sharded segmented reduce over arena row refs, end-to-end through
-    ``ShardSlabs``: resident rows gather from each shard's LOCAL slab via
-    ``ShardSlabs.assembled()`` global positions INSIDE one jit (ids over
-    the bridge, zero container rows over PCIe -- mirroring
-    ``pairwise._topk_sharded``); only cold ndarray rows ride a small
-    replicated staged block (row 0 reserved zero, so ``assembled[pos] |
-    staged[sidx]`` is exact slot selection).  Row routing
-    (``_shard_plan``) and partial folds are identical to
-    ``_shard_reduce``, so results are bit-identical to the single-device
-    plan by construction."""
+    ``ShardSlabs``: every resident row is reduced on the shard whose slab
+    holds it (arena row ``r`` on shard ``r % S``, ``ShardSlabs.locate``)
+    and gathered there inside one jit -- ids over the bridge, zero
+    container rows over PCIe, and no row ever leaves its shard.  Cold
+    ndarray rows ride a small replicated staged block (row 0 reserved
+    zero) and reduce on shard 0.  The partials fold with the
+    ``_shard_reduce`` exchange rules (see :func:`_sharded_rows_fn`), so
+    results are bit-identical to the single-device plan: every fold is
+    exact whichever shard a row lands on."""
     shards = arena.shard_slabs(mesh)
     d, axis = shards.size, shards.axis
     s = len(seg_sizes)
-    ids, wts, starts = _shard_plan(seg_sizes, d, op, seg_weights)
-    flat = [r for rows in seg_rows for r in rows]
-    pos_flat = np.zeros(len(flat), np.int64)
-    sidx_flat = np.zeros(len(flat), np.int32)
+    s_pad = _pow2(s)
+    n_seg = 2 * s_pad if op == "andnot" else s_pad
+    seg: list[int] = []
+    wts: list[int] = []
+    res: list[int] = []                     # global row (cold: 0, the zero row)
+    sidx: list[int] = []
     host: list[np.ndarray] = []
-    res_slots: list[int] = []
-    res_ids: list[int] = []
-    for i, r in enumerate(flat):
-        if isinstance(r, np.ndarray):
-            sidx_flat[i] = 1 + len(host)    # staged row 0: reserved zero
-            host.append(r)
-        else:                               # pos 0: global row 0 is zero
-            res_slots.append(i)
-            res_ids.append(int(r))
-    if res_slots:
-        pos_flat[np.asarray(res_slots, np.int64)] = \
-            shards.positions(np.asarray(res_ids, np.int64))
+    for si, rows in enumerate(seg_rows):
+        for j, r in enumerate(rows):
+            # andnot: each minuend forms its own segment s_pad + si
+            seg.append(s_pad + si if op == "andnot" and j == 0 else si)
+            wts.append(1 if seg_weights is None else seg_weights[si][j])
+            if isinstance(r, np.ndarray):
+                host.append(r)
+                sidx.append(len(host))      # staged row 0: reserved zero
+                res.append(0)
+            else:
+                sidx.append(0)
+                res.append(int(r))
+    seg_a = np.asarray(seg, np.int64)
+    sidx_a = np.asarray(sidx, np.int32)
+    w_a = np.asarray(wts, np.int32)
+    dev, lpos = shards.locate(np.asarray(res, np.int64))
+    counts = np.bincount(dev, minlength=d)
+    n_pad = _pow2(max(int(counts.max()), 1))
+    lpos_all = np.zeros((d, n_pad), np.int32)   # pad: past the last start
+    sidx_all = np.zeros((d, n_pad), np.int32)
+    w_all = np.ones((d, n_pad), np.int32)
+    starts_all = np.zeros((d, n_seg + 1), np.int32)
+    jmax = 1
+    for k in range(d):
+        mine = np.flatnonzero(dev == k)
+        mine = mine[np.argsort(seg_a[mine], kind="stable")]  # segment-major
+        c = mine.size
+        lpos_all[k, :c] = lpos[mine]
+        sidx_all[k, :c] = sidx_a[mine]
+        w_all[k, :c] = w_a[mine]
+        per_seg = np.bincount(seg_a[mine], minlength=n_seg)
+        starts_all[k, 1:] = np.cumsum(per_seg)
+        jmax = max(jmax, int(per_seg.max(initial=1)))
+    jmax = _pow2(jmax)
     h_pad = _pow2(1 + len(host))
     hb = np.zeros((h_pad, 1024), np.uint64)
     if host:
         hb[1: 1 + len(host)] = np.stack(host)
         arena.stats.host_rows_staged += len(host)
-    n_pad = _pow2(max(max(len(i) for i in ids), 1))
-    s_pad = _pow2(s)
-    pos_all = np.zeros((d, n_pad), np.int32)
-    sidx_all = np.zeros((d, n_pad), np.int32)
-    w_all = np.ones((d, n_pad), np.int32)
-    starts_all = np.zeros((d, s_pad + 1), np.int32)
-    jmax = 1
-    for dev in range(d):
-        k = len(ids[dev])
-        sel = np.asarray(ids[dev], np.int64)
-        pos_all[dev, :k] = pos_flat[sel]
-        sidx_all[dev, :k] = sidx_flat[sel]
-        w_all[dev, :k] = wts[dev]
-        st = np.asarray(starts[dev], np.int32)
-        starts_all[dev, :s + 1] = st
-        starts_all[dev, s + 1:] = st[-1]
-        jmax = max(jmax, int(np.diff(st).max(initial=1)))
-    jmax = _pow2(jmax)
     if op == "threshold" and planes is None:
         planes = _planes_for(
             seg_sizes if seg_weights is None else
@@ -859,11 +866,10 @@ def _shard_reduce_arena(arena, seg_rows: list[list], seg_sizes: list[int],
         st_.device_gathers += 1
     fn = _sharded_rows_fn(mesh, axis, op, backend, d, jmax, planes)
     staged = jnp.asarray(hb.view(np.uint32).reshape(h_pad, WORDS))
-    with mesh:
-        words, cards = fn(shards.assembled(), staged,
-                          jnp.asarray(pos_all), jnp.asarray(sidx_all),
-                          jnp.asarray(starts_all), jnp.asarray(w_all),
-                          jnp.asarray(t_dev))
+    words, cards = fn(shards.assembled(), staged,
+                      jnp.asarray(lpos_all), jnp.asarray(sidx_all),
+                      jnp.asarray(starts_all), jnp.asarray(w_all),
+                      jnp.asarray(t_dev))
     return words[:s], cards[:s]
 
 

@@ -373,8 +373,8 @@ class ShardSlabs:
     * :meth:`assembled` presents the ``S`` slabs as ONE global
       ``(S * cap_s, 2048)`` jax array sharded over the mesh axis --
       metadata-only assembly (``make_array_from_single_device_arrays``),
-      no copies -- so global row ``r`` sits at assembled position
-      ``(r % S) * cap_s + r // S`` (:meth:`positions`);
+      no copies -- that ``shard_map`` splits back into the per-shard
+      slabs, each indexed by local row (:meth:`locate`);
     * host edits batch into per-shard CoW scatters: only shards owning
       dirty rows re-patch, each in ONE functional ``.at[].set`` (in-
       flight dispatches keep their captured slabs).
@@ -456,7 +456,8 @@ class ShardSlabs:
     def assembled(self):
         """The global ``(S * cap_s, 2048)`` uint32 slab, sharded over the
         mesh axis -- zero-copy metadata assembly of the per-shard slabs,
-        flushed first.  Index it with :meth:`positions`."""
+        flushed first.  Pass it to ``shard_map`` with ``P(axis)``: each
+        shard then sees its own ``(cap_s, 2048)`` slab."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
         self._ensure()
@@ -466,13 +467,15 @@ class ShardSlabs:
                 (self.size * self.cap_s, WORDS), sharding, self._devs)
         return self._assembled
 
-    def positions(self, ids):
-        """Assembled-array positions of global rows ``ids`` (numpy).
-        Builds/flushes the slabs first: positions are only meaningful
-        against the CURRENT ``cap_s`` (growth changes the stride)."""
+    def locate(self, ids):
+        """``(shard, local index)`` of global rows ``ids`` (numpy), after
+        building/flushing the slabs.  Sharded dispatches gather each
+        shard's rows from its OWN slab by local index inside
+        ``shard_map``; a gather from :meth:`assembled` outside it would
+        make XLA all-gather every shard's slab onto every device."""
         self._ensure()
         ids = np.asarray(ids, np.int64)
-        return (ids % self.size) * self.cap_s + ids // self.size
+        return ids % self.size, ids // self.size
 
     def shard_slab(self, s: int):
         """Shard ``s``'s ``(cap_s, 2048)`` slab (flushed)."""

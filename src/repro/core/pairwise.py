@@ -74,6 +74,7 @@ OP_IDS = {o: i for i, o in enumerate(PAIR_OPS)}   # the kernels' row op ids
 SMALL_PAIR = 16
 
 _HOST_BLOCK = 8192      # bitset rows per host block (8 kB each -> <= 64 MB)
+
 _KCODE = {ArrayContainer: 1, BitsetContainer: 2, RunContainer: 3}
 
 
@@ -721,10 +722,7 @@ class SimilarityEngine:
             self._snap = None
         self.row_col = np.asarray(row_col, np.int32)
         self.starts = starts
-        seg = int(np.diff(starts).max()) if self.n else 1
-        self.jmax = 1 if seg <= 1 else 1 << (seg - 1).bit_length()
         self._dev = None                         # lazy device upload
-        self._shard_jit = {}                     # (metric, k, backend) -> fn
 
     def refresh(self) -> bool:
         """Generation revalidation for an arena-backed engine: re-adopt
@@ -903,7 +901,6 @@ class SimilarityEngine:
                 dev_rows, dev_col, dev_starts,
                 self._query_words_dev(query),
                 qc, dev_cards, metric=metric, k=k,
-                jmax=self.jmax,
                 exclude=-1 if exclude is None else exclude,
                 backend=backend)
             return (np.asarray(idx).astype(np.int64),
@@ -912,91 +909,26 @@ class SimilarityEngine:
         return self._topk_host(self._query_words(query), qc, k, metric,
                                exclude)
 
-    # -- sharded path (per-shard arena slabs, k-list all-gather) --------
+    # -- sharded path (per-shard arena slabs, shard-local row gathers) --
 
-    def _query_words_dev_sharded(self, query, shards):
-        """(C, WORDS) uint32 device query block for the sharded path: a
-        member query gathers its rows from the ASSEMBLED per-shard slab
-        (container words never cross the host bridge); a bitmap query
-        ships only its occupied rows -- the query payload itself, never
-        candidate rows."""
-        nc = max(self.n_keys, 1)
-        zeros = jnp.zeros((nc, WORDS), jnp.uint32)
-        if isinstance(query, (int, np.integer)):
-            s, e = int(self.starts[query]), int(self.starts[query + 1])
-            if s == e:
-                return zeros
-            pos = shards.positions(self.row_ids[s:e])
-            rows = jnp.take(shards.assembled(),
-                            jnp.asarray(pos, jnp.int32), axis=0)
-            return zeros.at[jnp.asarray(self.row_col[s:e])].set(rows)
-        cols, rows = [], []
-        for key, cont in zip(query.keys, query.containers):
-            col = self.key_col.get(key)
-            if col is not None:
-                cols.append(col)
-                rows.append(C.container_words64(cont))
-        if not cols:
+    @staticmethod
+    def _query_block(q64: np.ndarray):
+        """(C, WORDS) uint32 device query block from host query words:
+        only the occupied key columns cross the host bridge and scatter
+        into a zero block on device."""
+        cols = np.flatnonzero(q64.any(axis=1))
+        zeros = jnp.zeros((q64.shape[0], WORDS), jnp.uint32)
+        if not cols.size:
             return zeros
-        stack = np.stack(rows).view(np.uint32).reshape(-1, WORDS)
-        return zeros.at[jnp.asarray(np.asarray(cols, np.int32))] \
-            .set(jnp.asarray(stack))
+        rows = np.ascontiguousarray(q64[cols]).view(np.uint32)
+        return zeros.at[jnp.asarray(cols.astype(np.int32))].set(
+            jnp.asarray(rows.reshape(-1, WORDS)))
 
-    def _sharded_fn(self, metric: str, k: int, backend):
-        """One jit'd sharded dispatch per (metric, k, backend) class:
-        gather survivor rows from the assembled sharded slab, run the
-        fused score+select per shard under ``shard_map``, all-gather
-        ONLY the k-lists, and merge to the global top-k on device."""
-        key = (metric, k, backend)
-        fn = self._shard_jit.get(key)
-        if fn is not None:
-            return fn
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
-        mesh, axis, jmax = self._mesh, self._shard_axis, self.jmax
-
-        def body(rows_d, col_d, starts_d, gidx_d, cards_d, nval_d,
-                 q, qc, ex):
-            idx, sco, itr = kops.similarity_topk_ids(
-                rows_d[0], col_d[0], starts_d[0], q, qc, cards_d[0],
-                gidx_d[0], metric=metric, k=k, jmax=jmax,
-                n_valid=nval_d[0], exclude=ex, backend=backend)
-            return (jax.lax.all_gather(idx, axis),
-                    jax.lax.all_gather(sco, axis),
-                    jax.lax.all_gather(itr, axis))
-
-        sp = P(axis)
-        sm = shard_map(body, mesh=mesh,
-                       in_specs=(sp, sp, sp, sp, sp, sp, P(), P(), P()),
-                       out_specs=(P(), P(), P()), check_rep=False)
-
-        def run(slab, pos, col, starts, gidx, cards, nval, q, qc, ex):
-            s, r = pos.shape
-            rows_all = jnp.take(slab, pos.reshape(-1),
-                                axis=0).reshape(s, r, WORDS)
-            gi, gs, gn = sm(rows_all, col, starts, gidx, cards, nval,
-                            q, qc, ex)
-            return kops.topk_merge(gs.reshape(-1), gn.reshape(-1),
-                                   gi.reshape(-1), k, backend=backend)
-
-        fn = jax.jit(run)
-        self._shard_jit[key] = fn
-        return fn
-
-    def _plan_sharded(self, q64, qc, k, metric, exclude, shards):
-        """Host planning for one sharded query: run the SAME pruning
-        derivation as :meth:`_topk_host` (bounds -> k seed exact scores
-        -> running k-th score tau -> survivors = bound >= tau), then
-        round-robin the survivors to their ``t % S`` home shards and
-        pack per-shard padded arrays for the shard_map dispatch.
-
-        Returns ``(counts, gidx, cards, starts, pos, col)``: per-shard
-        valid-candidate counts (S,), global candidate ids (S, L) (pad:
-        ``self.n``, masked by ``n_valid``), their cardinalities (S, L),
-        row segment starts (S, L+1) (pad: repeat last -- empty
-        segments), assembled-slab row positions (S, R) (pad: position 0,
-        the reserved all-zero row), and key columns (S, R).  L and R are
-        padded to powers of two so jit retraces stay bounded."""
+    def _survivors(self, q64, qc, k, metric, exclude) -> np.ndarray:
+        """The SAME pruning derivation as :meth:`_topk_host` (bounds -> k
+        seed exact scores -> running k-th score tau -> survivors = bound
+        >= tau): ascending global ids of the candidates that can still
+        reach the top k (at least k of them: the seeds survive)."""
         ub = _scores_host(np.minimum(qc, self.cards), qc, self.cards,
                           metric)
         if exclude is not None:
@@ -1007,70 +939,71 @@ class SimilarityEngine:
         tau = seed_score.min()
         # exact seed scores are <= their bounds, so seeds survive; the
         # excluded candidate's bound is -1 < 0 <= tau, so it never does
-        surv = np.where(ub >= tau)[0]
+        return np.where(ub >= tau)[0]
+
+    def _plan_sharded(self, cands: np.ndarray, shards):
+        """Pack the sharded dispatch for ascending candidates ``cands``:
+        every candidate row is scored on the shard whose slab holds it
+        (arena row ``r`` on shard ``r % S``, ``ShardSlabs.locate``), so a
+        shard gathers only from its own slab and a candidate's rows may
+        sit on several shards.
+
+        Returns ``(gidx, cards, starts, lpos, col)``: global candidate
+        ids (L,) (pad: ``self.n``, masked by ``n_valid = cands.size``),
+        their cardinalities (L,), each shard's row offsets per candidate
+        (S, L+1), and each shard's local slab indices (S, R) and key
+        columns (S, R) of those rows (pad: 0, past the last offset, so
+        never counted).  L and R are padded to powers of two so jit
+        retraces stay bounded."""
         S = self._nshards
-        sh = (surv % S).astype(np.int64)
-        counts = np.bincount(sh, minlength=S).astype(np.int32)
-        lmax = max(1, int(counts.max()))
-        lpad = 1 << (lmax - 1).bit_length()      # pow2: bounded retraces
-        gidx_p = np.full((S, lpad), self.n, np.int32)   # pad: masked slot
-        cards_p = np.zeros((S, lpad), np.int32)
-        starts_p = np.zeros((S, lpad + 1), np.int32)
-        rid_shard = []
-        rmax = 1
+        n = cands.size
+        lpad = 1 << (max(n, 1) - 1).bit_length()
+        gidx = np.full(lpad, self.n, np.int32)
+        gidx[:n] = cands
+        cards = np.zeros(lpad, np.int32)
+        cards[:n] = self.cards[cands]
+        lens = (self.starts[cands + 1] - self.starts[cands]).astype(np.int64)
+        tot = int(lens.sum())
+        ridx = np.arange(tot) - np.repeat(np.cumsum(lens) - lens, lens) \
+            + np.repeat(self.starts[cands].astype(np.int64), lens)
+        slot = np.repeat(np.arange(n), lens)     # candidate slot per row
+        shard, local = shards.locate(self.row_ids[ridx])
+        counts = np.bincount(shard, minlength=S)
+        rpad = 1 << (max(int(counts.max()), 1) - 1).bit_length()
+        starts = np.zeros((S, lpad + 1), np.int32)
+        lpos = np.zeros((S, rpad), np.int32)
+        col = np.zeros((S, rpad), np.int32)
         for s in range(S):
-            cs = surv[sh == s]                   # ascending global ids
-            gidx_p[s, : cs.size] = cs
-            cards_p[s, : cs.size] = self.cards[cs]
-            lens = (self.starts[cs + 1] - self.starts[cs]).astype(np.int64)
-            tot = int(lens.sum())
-            st = np.zeros(lpad + 1, np.int64)
-            st[1: cs.size + 1] = np.cumsum(lens)
-            st[cs.size + 1:] = tot               # pad: repeat last start
-            starts_p[s] = st
-            if tot:
-                offs = np.repeat(np.cumsum(lens) - lens, lens)
-                ridx = np.arange(tot) - offs + np.repeat(
-                    self.starts[cs].astype(np.int64), lens)
-            else:
-                ridx = np.zeros(0, np.int64)
-            rid_shard.append(ridx)
-            rmax = max(rmax, tot)
-        rpad = 1 << (rmax - 1).bit_length()
-        pos_p = np.zeros((S, rpad), np.int32)    # pad: reserved zero row 0
-        col_p = np.zeros((S, rpad), np.int32)
-        for s, ridx in enumerate(rid_shard):
-            pos_p[s, : ridx.size] = shards.positions(self.row_ids[ridx])
-            col_p[s, : ridx.size] = self.row_col[ridx]
-        return counts, gidx_p, cards_p, starts_p, pos_p, col_p
+            mine = shard == s                    # candidate-major order kept
+            lpos[s, : counts[s]] = local[mine]
+            col[s, : counts[s]] = self.row_col[ridx[mine]]
+            starts[s, 1:] = np.cumsum(np.bincount(slot[mine],
+                                                  minlength=lpad))
+        return gidx, cards, starts, lpos, col
 
     def _topk_sharded(self, query, qc, k, metric, exclude, backend):
         """The sharded query path: the host pruning planner (same bound /
         seed / tau derivation as :meth:`_topk_host`, so the SAME
-        candidates survive) selects the survivor set, survivors are
-        round-robined to their ``t % S`` home shards, each shard runs the
-        fused score+select over its survivors' arena rows (gathered from
-        the assembled per-shard slab by global position -- ids over the
-        bridge, never container words), and only the S k-lists are
-        all-gathered and merged on device.  Ties resolve to the lowest
-        GLOBAL candidate index at both the per-shard select and the
-        merge, so results are bit-identical to the single-device path."""
+        candidates survive) selects the survivor set; in ONE dispatch
+        every shard counts the query's intersections with the survivor
+        rows its own slab holds (ids over the bridge, never candidate
+        words), the per-candidate counts are summed across shards, and
+        every shard selects the same global top-k.  Ties resolve to the
+        lowest GLOBAL candidate index, so results are bit-identical to
+        the single-device path."""
         shards = self._arena.shard_slabs(self._mesh)
         q64 = self._query_words(query)           # host mirror, no PCIe
-        (counts, gidx_p, cards_p, starts_p, pos_p, col_p
-         ) = self._plan_sharded(q64, qc, k, metric, exclude, shards)
-        q_dev = self._query_words_dev_sharded(query, shards)
+        surv = self._survivors(q64, qc, k, metric, exclude)
+        gidx, cards, starts, lpos, col = self._plan_sharded(surv, shards)
         for st in shards.stats:
             st.device_gathers += 1
-        fn = self._sharded_fn(metric, k, backend)
-        with self._mesh:
-            idx, score, inter = fn(
-                shards.assembled(), jnp.asarray(pos_p),
-                jnp.asarray(col_p), jnp.asarray(starts_p),
-                jnp.asarray(gidx_p), jnp.asarray(cards_p),
-                jnp.asarray(counts.astype(np.int32)), q_dev,
-                jnp.asarray(np.int32(qc)),
-                jnp.asarray(np.int32(-1 if exclude is None else exclude)))
+        fn = _sharded_topk(self._mesh, self._shard_axis, metric, k, backend)
+        idx, score, inter = fn(
+            shards.assembled(), jnp.asarray(lpos), jnp.asarray(col),
+            jnp.asarray(starts), self._query_block(q64),
+            jnp.asarray(np.int32(qc)), jnp.asarray(cards),
+            jnp.asarray(gidx), jnp.asarray(np.int32(surv.size)),
+            jnp.asarray(np.int32(-1 if exclude is None else exclude)))
         return (np.asarray(idx).astype(np.int64), np.asarray(score),
                 np.asarray(inter).astype(np.int64))
 
@@ -1182,6 +1115,29 @@ class SimilarityEngine:
             score[exclude] = np.float32(-1.0)
         order = np.argsort(-score, kind="stable")[:k]
         return order.astype(np.int64), score[order], inter[order]
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_topk(mesh, axis: str, metric: str, k: int, backend):
+    """One jit'd sharded top-k per (mesh, metric, k, backend): under
+    ``shard_map`` each shard gathers its rows from its OWN slab (the
+    assembled per-shard slab splits back over ``axis``) and runs
+    ``kops.similarity_topk_ids`` with ``axis``, which sums the partial
+    intersection counts across shards before scoring and selecting.
+    Only (L,) int32 counts cross the interconnect; no row leaves its
+    shard, so a device holds its slab plus its own survivors' rows."""
+    from jax.sharding import PartitionSpec as P
+
+    def body(slab, lpos, col, starts, q, qc, cards, gidx, nval, ex):
+        rows = jnp.take(slab, lpos[0], axis=0)
+        return kops.similarity_topk_ids(
+            rows, col[0], starts[0], q, qc, cards, gidx, metric=metric,
+            k=k, n_valid=nval, exclude=ex, axis=axis, backend=backend)
+
+    sp, rep = P(axis), P()
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(sp, sp, sp, sp) + (rep,) * 6,
+        out_specs=(rep, rep, rep), check_vma=False))
 
 
 @functools.lru_cache(maxsize=64)

@@ -5,9 +5,10 @@ One mesh source of truth:
 
   * ``activate(mesh)`` makes a mesh current for model-side sharding
     constraints (``constrain`` / ``dp_axes`` / ``axis_sizes``) AND for
-    jax's resource env, so ``with_sharding_constraint`` with bare
-    ``PartitionSpec``s works on jax versions with or without
-    ``jax.set_mesh``;
+    jax (``jax.set_mesh``), so ``with_sharding_constraint`` with bare
+    ``PartitionSpec``s resolves against it.  Meshes are built with
+    ``Auto`` axes (``launch.mesh``), so a constraint stays a hint to the
+    partitioner;
   * ``install_wide_mesh()`` builds ``launch.mesh.make_wide_mesh`` and
     installs it as the default mesh of every wide bitmap aggregate
     (``core.aggregate.set_default_mesh`` stores through :func:`set_wide_mesh`
@@ -29,7 +30,7 @@ MODEL_AXIS = "model"
 WIDE_AXIS = "wide"
 
 _PURE_DP = False
-_ACTIVE_MESH = None     # set by activate(); jax's resource env is fallback
+_ACTIVE_MESH = None     # set by activate()
 _WIDE_MESH = None       # storage behind core.aggregate.set_default_mesh
 
 
@@ -52,41 +53,23 @@ def pure_dp() -> bool:
 # current mesh
 # ---------------------------------------------------------------------------
 
-def _resource_mesh():
-    """The mesh jax itself considers current (``with mesh:`` blocks), or
-    None.  Read at trace time, so jitted model code sees the mesh the
-    dry-run lowers under.  The resource env is a private surface that has
-    moved across jax versions -- fail soft to off-mesh (identity
-    behaviour) rather than hard on an upgrade."""
-    try:
-        from jax._src import mesh as mesh_lib
-        env = mesh_lib.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        return None
-    return None if env.empty else env
-
-
 def current_mesh():
-    """The explicitly activated mesh, else jax's resource-env mesh, else
-    None (off-mesh: every helper degrades to a no-op)."""
-    return _ACTIVE_MESH if _ACTIVE_MESH is not None else _resource_mesh()
+    """The mesh made current by :func:`activate`, else None (off-mesh:
+    every helper degrades to a no-op).  Read at trace time, so jitted
+    model code sees the mesh the dry-run lowers under."""
+    return _ACTIVE_MESH
 
 
 @contextlib.contextmanager
 def activate(mesh):
     """Make ``mesh`` current for this context AND for jax's sharding
-    machinery (``jax.set_mesh`` when available, the classic ``with mesh:``
-    resource env otherwise)."""
+    machinery (``jax.set_mesh``)."""
     global _ACTIVE_MESH
     prev = _ACTIVE_MESH
     _ACTIVE_MESH = mesh
     try:
-        if hasattr(jax, "set_mesh"):
-            with jax.set_mesh(mesh):
-                yield mesh
-        else:
-            with mesh:
-                yield mesh
+        with jax.set_mesh(mesh):
+            yield mesh
     finally:
         _ACTIVE_MESH = prev
 

@@ -2,13 +2,14 @@
 kernel: the pcmpistrm analogue.
 
 The paper divides both arrays into blocks and uses the SSE4.1 string-compare
-instruction for an all-vs-all equality test between two blocks, stepping
-blocks by comparing block maxima (Algorithm 1).  The TPU analogue of the
-all-vs-all compare is a broadcast equality outer product on the VPU; the
-block-maxima stepping becomes a *skip predicate*: the grid is static, but a
-tile pair whose value ranges cannot overlap is skipped with @pl.when, which
-on TPU elides the compute exactly like the paper's merge stepping avoids
-non-matching block pairs (sortedness makes the ranges available for free).
+instruction for an all-vs-all equality test between two blocks.  The TPU
+analogue of that all-vs-all compare broadcasts one array's values, one at
+a time from SMEM, against the other array held as a dense (32, 128) vector
+of 4096 slots: each step compares one value against every slot at once.
+(An outer-product compare would need the values on both the lane and the
+sublane axis, a relayout Mosaic does not lower.)  Invalid slots (past a
+container's cardinality) hold sentinels that can never match, so the
+kernels need no cardinality inputs.
 
 Output is A-side: a 0/1 membership mask over A's slots plus the intersection
 cardinality.  Difference (section 4.4) is the complement of this mask on
@@ -25,45 +26,87 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import ARRAY_CAP, CONTAINER_BITS
 
-TILE = 512  # values per compare tile; (TILE, TILE) i32 eq-matrix = 1 MB
+_SUB, _LANES = ARRAY_CAP // 128, 128      # one array slab as (32, 128)
+
+_VEC = pl.BlockSpec((None, _SUB, _LANES), lambda i: (i, 0, 0))
+_SCALARS = pl.BlockSpec((ARRAY_CAP,), lambda i: (i,),
+                        memory_space=pltpu.SMEM)
+_COUNT = pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0))
 
 
-def _intersect_kernel(a_ref, a_card_ref, b_ref, b_card_ref,
-                      mask_ref, count_ref):
-    a = a_ref[...]                                   # (1, ARRAY_CAP)
-    b = b_ref[...]
-    a_card, b_card = a_card_ref[0, 0], b_card_ref[0, 0]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, ARRAY_CAP), 1)
-    a_valid = pos < a_card
-    b_valid = pos < b_card
-    # invalid slots get sentinel values that can never match
-    a_v = jnp.where(a_valid, a, np.int32(CONTAINER_BITS))
-    b_v = jnp.where(b_valid, b, np.int32(CONTAINER_BITS + 1))
+def mask_invalid(vals, card, fill):
+    """(N, ARRAY_CAP) int32 values with slots >= card set to ``fill``."""
+    pos = jnp.arange(ARRAY_CAP, dtype=jnp.int32)[None, :]
+    return jnp.where(pos < card.astype(jnp.int32)[:, None],
+                     vals.astype(jnp.int32), np.int32(fill))
 
-    n_tiles = ARRAY_CAP // TILE
-    mask = jnp.zeros((1, ARRAY_CAP), jnp.int32)
-    for i in range(n_tiles):
-        at = jax.lax.dynamic_slice(a_v, (0, i * TILE), (1, TILE))
-        a_min, a_max = at[0, 0], at[0, TILE - 1]
-        hit = jnp.zeros((1, TILE), jnp.int32)
-        for j in range(n_tiles):
-            bt = jax.lax.dynamic_slice(b_v, (0, j * TILE), (1, TILE))
-            b_min, b_max = bt[0, 0], bt[0, TILE - 1]
-            # Algorithm 1's block-maxima stepping as a skip predicate:
-            # sorted tiles whose ranges don't overlap can't match.
-            overlap = (a_min <= b_max) & (b_min <= a_max)
-            eq_any = jnp.where(
-                overlap,
-                (at[0, :, None] == bt[0, None, :]).any(axis=-1)
-                .astype(jnp.int32)[None, :],
-                jnp.zeros((1, TILE), jnp.int32))
-            hit = hit | eq_any
-        mask = jax.lax.dynamic_update_slice(mask, hit, (0, i * TILE))
+
+def _hits(vec, scalars_ref):
+    """0/1 mask over ``vec``'s slots: the slot's value equals one of the
+    4096 values in ``scalars_ref``."""
+    def step(j, hit):
+        return hit | (vec == scalars_ref[j]).astype(jnp.int32)
+    return jax.lax.fori_loop(0, ARRAY_CAP, step,
+                             jnp.zeros(vec.shape, jnp.int32))
+
+
+def sum_to_cell(x):
+    """Sum of a 2-D block as a (1, 1) block: the TPU stores vectors to
+    VMEM, not scalars."""
+    return jnp.sum(jnp.sum(x, axis=0, keepdims=True), axis=1,
+                   keepdims=True)
+
+
+def _intersect_kernel(a_ref, b_smem, mask_ref, count_ref):
+    mask = _hits(a_ref[...], b_smem)
     mask_ref[...] = mask
-    count_ref[...] = mask.sum(axis=-1, dtype=jnp.int32)[:, None]
+    count_ref[...] = sum_to_cell(mask)
+
+
+def _pair_masks_kernel(a_ref, b_smem, b_ref, a_smem, mask_a_ref, mask_b_ref,
+                       count_ref):
+    """Two-sided variant of ``_intersect_kernel``: the same compare run
+    from B's side marks which B slots matched, so one dispatch feeds every
+    materializing array-array op (AND keeps A's hits, ANDNOT drops them,
+    OR appends B's misses, XOR keeps both sides' misses -- sections
+    4.2-4.5)."""
+    mask_a = _hits(a_ref[...], b_smem)
+    mask_a_ref[...] = mask_a
+    mask_b_ref[...] = _hits(b_ref[...], a_smem)
+    count_ref[...] = sum_to_cell(mask_a)
+
+
+def _intersect_card_kernel(a_ref, b_smem, count_ref):
+    """Count-only intersection (paper section 5.9 applied to the section
+    4.2 compare): the membership mask never leaves registers."""
+    count_ref[...] = sum_to_cell(_hits(a_ref[...], b_smem))
+
+
+def _sides(a_vals, a_card, b_vals, b_card):
+    """A and B with distinct never-matching sentinels in invalid slots."""
+    return (mask_invalid(a_vals, a_card, CONTAINER_BITS),
+            mask_invalid(b_vals, b_card, CONTAINER_BITS + 1))
+
+
+def _vec(x):
+    return x.reshape(x.shape[0], _SUB, _LANES)
+
+
+def _call(kernel, n, in_specs, n_masks, interpret):
+    mask = jax.ShapeDtypeStruct((n, _SUB, _LANES), jnp.int32)
+    return pl.pallas_call(
+        kernel,
+        grid=(n,),
+        in_specs=in_specs,
+        out_specs=[_VEC] * n_masks + [_COUNT],
+        out_shape=[mask] * n_masks
+        + [jax.ShapeDtypeStruct((n, 1, 1), jnp.int32)],
+        interpret=interpret,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -78,21 +121,10 @@ def array_intersect(a_vals: jax.Array, a_card: jax.Array,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n = a_vals.shape[0]
-    vspec = pl.BlockSpec((1, ARRAY_CAP), lambda i: (i, 0))
-    cspec = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    mask, count = pl.pallas_call(
-        _intersect_kernel,
-        grid=(n,),
-        in_specs=[vspec, cspec, vspec, cspec],
-        out_specs=[vspec, cspec],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, ARRAY_CAP), jnp.int32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(a_vals.astype(jnp.int32), a_card.astype(jnp.int32)[:, None],
-      b_vals.astype(jnp.int32), b_card.astype(jnp.int32)[:, None])
-    return mask, count[:, 0]
+    a, b = _sides(a_vals, a_card, b_vals, b_card)
+    mask, count = _call(_intersect_kernel, n, [_VEC, _SCALARS], 1,
+                        interpret)(_vec(a), b.reshape(-1))
+    return mask.reshape(n, ARRAY_CAP), count[:, 0, 0]
 
 
 def array_difference(a_vals, a_card, b_vals, b_card, *, interpret=None):
@@ -102,45 +134,6 @@ def array_difference(a_vals, a_card, b_vals, b_card, *, interpret=None):
     valid = (jnp.arange(ARRAY_CAP)[None, :] < a_card[:, None]).astype(jnp.int32)
     keep = valid * (1 - mask)
     return keep, (a_card.astype(jnp.int32) - inter)
-
-
-def _pair_masks_kernel(a_ref, a_card_ref, b_ref, b_card_ref,
-                       mask_a_ref, mask_b_ref, count_ref):
-    """Two-sided variant of ``_intersect_kernel``: the same tiled all-vs-all
-    compare also accumulates which B slots matched, so one dispatch feeds
-    every materializing array-array op (AND keeps A's hits, ANDNOT drops
-    them, OR appends B's misses, XOR keeps both sides' misses --
-    sections 4.2-4.5)."""
-    a = a_ref[...]
-    b = b_ref[...]
-    a_card, b_card = a_card_ref[0, 0], b_card_ref[0, 0]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, ARRAY_CAP), 1)
-    a_v = jnp.where(pos < a_card, a, np.int32(CONTAINER_BITS))
-    b_v = jnp.where(pos < b_card, b, np.int32(CONTAINER_BITS + 1))
-
-    n_tiles = ARRAY_CAP // TILE
-    mask_a = jnp.zeros((1, ARRAY_CAP), jnp.int32)
-    mask_b = jnp.zeros((1, ARRAY_CAP), jnp.int32)
-    for i in range(n_tiles):
-        at = jax.lax.dynamic_slice(a_v, (0, i * TILE), (1, TILE))
-        a_min, a_max = at[0, 0], at[0, TILE - 1]
-        hit_a = jnp.zeros((1, TILE), jnp.int32)
-        for j in range(n_tiles):
-            bt = jax.lax.dynamic_slice(b_v, (0, j * TILE), (1, TILE))
-            b_min, b_max = bt[0, 0], bt[0, TILE - 1]
-            overlap = (a_min <= b_max) & (b_min <= a_max)
-            eq = jnp.where(overlap,
-                           at[0, :, None] == bt[0, None, :],
-                           jnp.zeros((TILE, TILE), jnp.bool_))
-            hit_a = hit_a | eq.any(axis=-1).astype(jnp.int32)[None, :]
-            bj = jax.lax.dynamic_slice(mask_b, (0, j * TILE), (1, TILE))
-            mask_b = jax.lax.dynamic_update_slice(
-                mask_b, bj | eq.any(axis=0).astype(jnp.int32)[None, :],
-                (0, j * TILE))
-        mask_a = jax.lax.dynamic_update_slice(mask_a, hit_a, (0, i * TILE))
-    mask_a_ref[...] = mask_a
-    mask_b_ref[...] = mask_b
-    count_ref[...] = mask_a.sum(axis=-1, dtype=jnp.int32)[:, None]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -156,51 +149,12 @@ def array_pair_masks(a_vals: jax.Array, a_card: jax.Array,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n = a_vals.shape[0]
-    vspec = pl.BlockSpec((1, ARRAY_CAP), lambda i: (i, 0))
-    cspec = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    mask_a, mask_b, count = pl.pallas_call(
-        _pair_masks_kernel,
-        grid=(n,),
-        in_specs=[vspec, cspec, vspec, cspec],
-        out_specs=[vspec, vspec, cspec],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, ARRAY_CAP), jnp.int32),
-            jax.ShapeDtypeStruct((n, ARRAY_CAP), jnp.int32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(a_vals.astype(jnp.int32), a_card.astype(jnp.int32)[:, None],
-      b_vals.astype(jnp.int32), b_card.astype(jnp.int32)[:, None])
-    return mask_a, mask_b, count[:, 0]
-
-
-def _intersect_card_kernel(a_ref, a_card_ref, b_ref, b_card_ref, count_ref):
-    """Count-only intersection (paper section 5.9 applied to the section
-    4.2 compare): the membership mask never leaves registers."""
-    a = a_ref[...]
-    b = b_ref[...]
-    a_card, b_card = a_card_ref[0, 0], b_card_ref[0, 0]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, ARRAY_CAP), 1)
-    a_v = jnp.where(pos < a_card, a, np.int32(CONTAINER_BITS))
-    b_v = jnp.where(pos < b_card, b, np.int32(CONTAINER_BITS + 1))
-    n_tiles = ARRAY_CAP // TILE
-    total = jnp.zeros((), jnp.int32)
-    for i in range(n_tiles):
-        at = jax.lax.dynamic_slice(a_v, (0, i * TILE), (1, TILE))
-        a_min, a_max = at[0, 0], at[0, TILE - 1]
-        hit = jnp.zeros((1, TILE), jnp.int32)
-        for j in range(n_tiles):
-            bt = jax.lax.dynamic_slice(b_v, (0, j * TILE), (1, TILE))
-            b_min, b_max = bt[0, 0], bt[0, TILE - 1]
-            overlap = (a_min <= b_max) & (b_min <= a_max)
-            eq_any = jnp.where(
-                overlap,
-                (at[0, :, None] == bt[0, None, :]).any(axis=-1)
-                .astype(jnp.int32)[None, :],
-                jnp.zeros((1, TILE), jnp.int32))
-            hit = hit | eq_any
-        total = total + hit.sum(dtype=jnp.int32)
-    count_ref[...] = total[None, None]
+    a, b = _sides(a_vals, a_card, b_vals, b_card)
+    mask_a, mask_b, count = _call(
+        _pair_masks_kernel, n, [_VEC, _SCALARS, _VEC, _SCALARS], 2,
+        interpret)(_vec(a), b.reshape(-1), _vec(b), a.reshape(-1))
+    return (mask_a.reshape(n, ARRAY_CAP), mask_b.reshape(n, ARRAY_CAP),
+            count[:, 0, 0])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -211,15 +165,7 @@ def array_intersect_card(a_vals: jax.Array, a_card: jax.Array,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n = a_vals.shape[0]
-    vspec = pl.BlockSpec((1, ARRAY_CAP), lambda i: (i, 0))
-    cspec = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    count = pl.pallas_call(
-        _intersect_card_kernel,
-        grid=(n,),
-        in_specs=[vspec, cspec, vspec, cspec],
-        out_specs=cspec,
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
-        interpret=interpret,
-    )(a_vals.astype(jnp.int32), a_card.astype(jnp.int32)[:, None],
-      b_vals.astype(jnp.int32), b_card.astype(jnp.int32)[:, None])
-    return count[:, 0]
+    a, b = _sides(a_vals, a_card, b_vals, b_card)
+    (count,) = _call(_intersect_card_kernel, n, [_VEC, _SCALARS], 0,
+                     interpret)(_vec(a), b.reshape(-1))
+    return count[:, 0, 0]

@@ -3,10 +3,9 @@
 
 section 3.2 (x64): set bits of a bitset at indexes given by an array, with
 branchless cardinality tracking (`bts` + `sbb`, or the XOR trick).  TPU has
-no scatter inside a kernel, but Roaring array containers hold *distinct*
-values, so each (word, bit) contribution is disjoint and OR == +:
-the scatter becomes a masked compare-and-accumulate over word indexes --
-a shape the VPU executes well.  The cardinality delta uses exactly the
+no scatter inside a kernel: the values stream from SMEM one at a time and
+each ORs its bit into the word lane that matches, across the whole
+(16, 128) container at once.  The cardinality delta uses exactly the
 paper's XOR trick: popcount(old ^ new).
 
 section 3.1 (bitset -> array extraction, blsi/tzcnt loop): the TPU idiom is a
@@ -22,55 +21,50 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.harley_seal import harley_seal_reduce
-from repro.kernels.ref import ARRAY_CAP, WORDS
+from repro.kernels.array_ops import mask_invalid, sum_to_cell
+from repro.kernels.harley_seal import _popcount_u32
+from repro.kernels.ref import ARRAY_CAP, CONTAINER_BITS, WORDS
 
-VALUE_TILE = 512  # values processed per inner step: (WORDS, 512) i32 = 4 MB
-
-
-def _a2b_body(vals, card):
-    """(1, ARRAY_CAP) int32 values + scalar card -> (1, WORDS) uint32."""
-    valid = jax.lax.broadcasted_iota(jnp.int32, (1, ARRAY_CAP), 1) < card
-    widx = jnp.where(valid, vals >> 5, WORDS)          # OOB -> contributes 0
-    bit = jnp.where(valid,
-                    np.uint32(1) << (vals & 31).astype(jnp.uint32),
-                    np.uint32(0))
-    wids = jax.lax.broadcasted_iota(jnp.int32, (1, WORDS, 1), 1)
-    acc = jnp.zeros((1, WORDS), jnp.uint32)
-    for t in range(ARRAY_CAP // VALUE_TILE):
-        wv = jax.lax.dynamic_slice(widx, (0, t * VALUE_TILE), (1, VALUE_TILE))
-        bv = jax.lax.dynamic_slice(bit, (0, t * VALUE_TILE), (1, VALUE_TILE))
-        eq = wids == wv[:, None, :]                    # (1, WORDS, TILE)
-        acc = acc + jnp.where(eq, bv[:, None, :],
-                              np.uint32(0)).sum(axis=-1, dtype=jnp.uint32)
-    return acc
+_SUB, _LANES = WORDS // 128, 128      # one container as (16, 128) words
 
 
-def _a2b_kernel(vals_ref, card_ref, words_ref):
-    words_ref[...] = _a2b_body(vals_ref[...], card_ref[0, 0])
+def _a2b_body(vals_smem):
+    """ARRAY_CAP values in SMEM (invalid slots hold a sentinel past the
+    last word) -> (16, 128) uint32 bitset words."""
+    wid = (jax.lax.broadcasted_iota(jnp.int32, (_SUB, _LANES), 0) * _LANES
+           + jax.lax.broadcasted_iota(jnp.int32, (_SUB, _LANES), 1))
+
+    def step(j, acc):
+        v = vals_smem[j]
+        bit = jnp.uint32(1) << (v & 31).astype(jnp.uint32)
+        return acc | jnp.where(wid == (v >> 5), bit, jnp.uint32(0))
+
+    return jax.lax.fori_loop(0, ARRAY_CAP, step,
+                             jnp.zeros((_SUB, _LANES), jnp.uint32))
 
 
-def _set_many_kernel(init_ref, vals_ref, card_ref, words_ref, delta_ref):
+def _a2b_kernel(vals_smem, words_ref):
+    words_ref[...] = _a2b_body(vals_smem)
+
+
+def _set_many_kernel(init_ref, vals_smem, words_ref, delta_ref):
     """Fused section 3.2: new = old | onehot(values); delta = pc(old ^ new)."""
     old = init_ref[...]
-    add = _a2b_body(vals_ref[...], card_ref[0, 0])
-    new = old | add
+    new = old | _a2b_body(vals_smem)
     words_ref[...] = new
-    changed = old ^ new
-    delta_ref[...] = harley_seal_reduce(
-        changed.reshape(1, WORDS // 16, 16))[:, None]
+    delta_ref[...] = sum_to_cell(_popcount_u32(old ^ new))
 
 
-def _specs():
-    return dict(
-        vals=pl.BlockSpec((1, ARRAY_CAP), lambda i: (i, 0)),
-        card=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        words=pl.BlockSpec((1, WORDS), lambda i: (i, 0)),
-        delta=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-    )
+_WORDS_SPEC = pl.BlockSpec((None, _SUB, _LANES), lambda i: (i, 0, 0))
+_VALS_SPEC = pl.BlockSpec((ARRAY_CAP,), lambda i: (i,),
+                          memory_space=pltpu.SMEM)
+
+
+def _sentinel_values(values, card):
+    return mask_invalid(values, card, CONTAINER_BITS).reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -80,15 +74,14 @@ def array_to_bitset(values: jax.Array, card: jax.Array, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n = values.shape[0]
-    s = _specs()
     return pl.pallas_call(
         _a2b_kernel,
         grid=(n,),
-        in_specs=[s["vals"], s["card"]],
-        out_specs=s["words"],
-        out_shape=jax.ShapeDtypeStruct((n, WORDS), jnp.uint32),
+        in_specs=[_VALS_SPEC],
+        out_specs=_WORDS_SPEC,
+        out_shape=jax.ShapeDtypeStruct((n, _SUB, _LANES), jnp.uint32),
         interpret=interpret,
-    )(values.astype(jnp.int32), card.astype(jnp.int32)[:, None])
+    )(_sentinel_values(values, card)).reshape(n, WORDS)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -99,16 +92,17 @@ def bitset_set_many(words: jax.Array, values: jax.Array, card: jax.Array, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n = words.shape[0]
-    s = _specs()
     new, delta = pl.pallas_call(
         _set_many_kernel,
         grid=(n,),
-        in_specs=[s["words"], s["vals"], s["card"]],
-        out_specs=[s["words"], s["delta"]],
+        in_specs=[_WORDS_SPEC, _VALS_SPEC],
+        out_specs=[_WORDS_SPEC, pl.BlockSpec((None, 1, 1),
+                                             lambda i: (i, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((n, WORDS), jnp.uint32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, _SUB, _LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((n, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(words, values.astype(jnp.int32), card.astype(jnp.int32)[:, None])
-    return new, delta[:, 0]
+    )(words.astype(jnp.uint32).reshape(n, _SUB, _LANES),
+      _sentinel_values(values, card))
+    return new.reshape(n, WORDS), delta[:, 0, 0]

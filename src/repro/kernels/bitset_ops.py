@@ -39,14 +39,12 @@ def _apply(a, b, op: str):
 def _op_kernel(a_ref, b_ref, out_ref, card_ref, *, op):
     r = _apply(a_ref[...], b_ref[...], op)
     out_ref[...] = r
-    bn = r.shape[0]
-    card_ref[...] = harley_seal_reduce(r.reshape(bn, WORDS // 16, 16))[:, None]
+    card_ref[...] = harley_seal_reduce(r)
 
 
 def _card_kernel(a_ref, b_ref, card_ref, *, op):
     r = _apply(a_ref[...], b_ref[...], op)
-    bn = r.shape[0]
-    card_ref[...] = harley_seal_reduce(r.reshape(bn, WORDS // 16, 16))[:, None]
+    card_ref[...] = harley_seal_reduce(r)
 
 
 def _pad(x, block):

@@ -5,13 +5,14 @@ The paper's AVX2 version streams sixteen 256-bit vectors through a carry-save
 adder (CSA) circuit, accumulating into five bit-sliced accumulator vectors
 (ones/twos/fours/eights/sixteens) so that the expensive per-byte popcount
 runs on 5 vectors instead of 16.  On TPU the VPU register is (8, 128) x 32-bit
-= 32768 bits, so one 2^16-bit Roaring bitset container is two vregs; we lay
-the 16 CSA circuit inputs along the minor axis of a (block, 128, 16) reshape
-and vectorize the identical circuit across lanes.  The op-count saving is the
-same as the paper's: 15 CSAs x 5 logical ops per 16 words, then a SWAR
-popcount of 5 accumulators instead of 16 (TPU has no vector popcount
-instruction, which is precisely the situation the paper's circuit was
-designed for).
+= 32768 bits, so one 2^16-bit Roaring bitset container is two vregs; since
+WORDS = 2048 = 16 x 128, the 16 CSA circuit inputs are the container's 16
+lane-aligned (block, 128) column slices and the identical circuit runs
+across lanes (no relayout: Mosaic refuses a (block, 128, 16) reshape).
+The op-count saving is the same as the paper's: 15 CSAs x 5 logical ops
+per 16 words, then a SWAR popcount of 5 accumulators instead of 16 (TPU
+has no vector popcount instruction, which is precisely the situation the
+paper's circuit was designed for).
 """
 
 from __future__ import annotations
@@ -50,10 +51,12 @@ def _csa(a, b, c):
 def harley_seal_reduce(x):
     """The 16-input Harley-Seal circuit of the paper's Fig. 3, vectorized.
 
-    x: (..., 16) uint32, the 16 circuit inputs along the last axis.
-    Returns int32 popcount summed over all axes except the leading one.
+    x: (bn, WORDS) uint32 rows; circuit input ``i`` is the lane-aligned
+    slice ``x[:, 128 i : 128 (i + 1)]``.  Returns (bn, 1) int32 row
+    popcounts.
     """
-    A = [x[..., i] for i in range(16)]
+    lanes = WORDS // 16
+    A = [x[:, i * lanes:(i + 1) * lanes] for i in range(16)]
     twos_a, ones = _csa(A[0], A[1], jnp.zeros_like(A[0]))
     twos_b, ones = _csa(A[2], A[3], ones)
     fours_a, twos = _csa(twos_a, twos_b, jnp.zeros_like(A[0]))
@@ -69,20 +72,16 @@ def harley_seal_reduce(x):
     fours_b, twos = _csa(twos_a, twos_b, twos)
     eights_b, fours = _csa(fours_a, fours_b, fours)
     sixteens, eights = _csa(eights_a, eights_b, jnp.zeros_like(A[0]))
-    axes = tuple(range(1, x.ndim - 1))
     total = (16 * _popcount_u32(sixteens)
              + 8 * _popcount_u32(eights)
              + 4 * _popcount_u32(fours)
              + 2 * _popcount_u32(twos)
              + _popcount_u32(ones))
-    return total.sum(axis=axes).astype(jnp.int32)
+    return jnp.sum(total, axis=1, keepdims=True, dtype=jnp.int32)
 
 
 def _popcount_kernel(words_ref, out_ref):
-    x = words_ref[...]                       # (bn, WORDS) uint32
-    bn = x.shape[0]
-    g = x.reshape(bn, WORDS // 16, 16)       # 16 circuit inputs, minor axis
-    out_ref[...] = harley_seal_reduce(g)[:, None]
+    out_ref[...] = harley_seal_reduce(words_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))

@@ -152,10 +152,10 @@ _ref_bitset_pair_card = jax.jit(ref.bitset_pair_card)
 
 
 def similarity_topk(rows, row_col, starts, q_words, q_card, cards, *,
-                    metric: str, k: int, jmax: int, exclude=-1,
+                    metric: str, k: int, exclude=-1,
                     backend: Backend | None = None):
     """Fused similarity top-k: score a query against T device-resident
-    candidates and select the best k in ONE dispatch (score + select never
+    candidates and select the best k in ONE jit (score + select never
     leave the device; only k indices/scores return).  See
     kernels/topk_ops.py for the layout and docs/ARCHITECTURE.md for where
     this sits in the paper map."""
@@ -163,7 +163,7 @@ def similarity_topk(rows, row_col, starts, q_words, q_card, cards, *,
     if _use_pallas(backend):
         return _topk_ops.similarity_topk(rows, row_col, starts, q_words,
                                          q_card, cards, exclude,
-                                         metric=metric, k=k, jmax=jmax)
+                                         metric=metric, k=k)
     return _ref_similarity_topk(rows, row_col, starts, q_words,
                                 jnp.asarray(q_card, jnp.int32),
                                 cards, exclude, metric=metric, k=k)
@@ -174,42 +174,30 @@ _ref_similarity_topk = jax.jit(ref.similarity_topk,
 
 
 def similarity_topk_ids(rows, row_col, starts, q_words, q_card, cards,
-                        gidx, *, metric: str, k: int, jmax: int, n_valid,
-                        exclude=-1, backend: Backend | None = None):
-    """Per-shard fused similarity top-k over a candidate SUBSET labelled
-    with global ids (one shard of the sharded ``SimilarityEngine`` path,
-    or any pruned candidate list): slots >= ``n_valid`` are padding,
-    ``exclude`` is a GLOBAL candidate id, and score ties resolve to the
-    lowest GLOBAL index -- see kernels/topk_ops.py for the pinned
-    shard-merge tie rule."""
+                        gidx, *, metric: str, k: int, n_valid,
+                        exclude=-1, axis: str | None = None,
+                        backend: Backend | None = None):
+    """Fused similarity top-k over a candidate SUBSET labelled with
+    global ids (a pruned candidate list): slots >= ``n_valid`` are
+    padding, ``exclude`` is a GLOBAL candidate id, and score ties resolve
+    to the lowest GLOBAL index.  Inside ``shard_map``, ``axis`` names the
+    mesh axis the candidates' rows are split over: each shard scores the
+    rows it holds and the partial intersections are summed across the
+    axis (the sharded ``SimilarityEngine`` path).  See
+    kernels/topk_ops.py."""
     exclude = jnp.asarray(exclude, jnp.int32)
     n_valid = jnp.asarray(n_valid, jnp.int32)
     if _use_pallas(backend):
         return _topk_ops.similarity_topk_ids(
             rows, row_col, starts, q_words, q_card, cards, gidx, n_valid,
-            exclude, metric=metric, k=k, jmax=jmax)
+            exclude, metric=metric, k=k, axis=axis)
     return _ref_similarity_topk_ids(
         rows, row_col, starts, q_words, jnp.asarray(q_card, jnp.int32),
-        cards, gidx, n_valid, exclude, metric=metric, k=k)
+        cards, gidx, n_valid, exclude, metric=metric, k=k, axis=axis)
 
 
 _ref_similarity_topk_ids = jax.jit(ref.similarity_topk_ids,
-                                   static_argnames=("metric", "k"))
-
-
-def topk_merge(score, inter, gidx, k: int, *,
-               backend: Backend | None = None):
-    """Merge all-gathered per-shard k-lists to the global top-k on
-    device: one ids-select pass over the (S*k,) entries, ties to the
-    lowest GLOBAL candidate index (bit-identical to selecting over the
-    unsharded score vector)."""
-    if _use_pallas(backend):
-        return _topk_ops.topk_merge(score, inter, gidx, k)
-    return _ref_topk_select_ids(score, inter, gidx, k)
-
-
-_ref_topk_select_ids = jax.jit(ref.topk_select_ids,
-                               static_argnames=("k",))
+                                   static_argnames=("metric", "k", "axis"))
 
 
 _ref_segment_reduce = jax.jit(
@@ -277,9 +265,8 @@ def segment_reduce_rows_dual(table, staged, pos, sidx, starts, op: str, *,
     """Dual-source resident-slab reduce: slot ``i`` gathers
     ``table[pos[i]] | staged[sidx[i]]`` on-device (exactly one side real,
     the other the reserved zero row) and reduces like
-    :func:`segment_reduce`.  ``table`` is the arena's resident slab --
-    single-device or the sharded assembled per-shard layout -- and is
-    never copied per call; only the small ``staged`` block of cold rows
+    :func:`segment_reduce`.  ``table`` is the arena's resident
+    single-device slab and is never copied per call; only the small ``staged`` block of cold rows
     crosses PCIe.  See kernels/segment_ops.py."""
     t = jnp.asarray(threshold, jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)

@@ -16,9 +16,9 @@ into the kernels here:
   * ``array_bitset_probe`` -- array x bitset (the asymmetric case of
     section 4.2): each sorted array value probes the bitset row; the
     paper's per-value binary search degenerates to a word fetch + bit test
-    in the bitset domain.  On TPU the gather is a one-hot reduction over
-    value tiles (the VPU has no vector gather; the one-hot contraction is
-    the standard idiom).
+    in the bitset domain.  The VPU has no vector gather, so the bitset's
+    words stream from SMEM one at a time, each tested against every
+    value slot at once.
 
 Array x array pairs ride ``kernels.array_ops`` (the pcmpistrm analogue),
 extended by this PR with a two-sided-mask variant (feeding OR / XOR /
@@ -33,11 +33,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.array_ops import mask_invalid, sum_to_cell
 from repro.kernels.harley_seal import DEFAULT_BLOCK, harley_seal_reduce
-from repro.kernels.ref import ARRAY_CAP, WORDS
-
-TILE = 512   # values per probe tile; (TILE, WORDS) one-hot = 4 MB of VMEM
+from repro.kernels.ref import ARRAY_CAP, CONTAINER_BITS, WORDS
 
 
 def _mixed_op(a, b, opid):
@@ -52,14 +52,12 @@ def _mixed_op(a, b, opid):
 def _pair_op_kernel(opid_ref, a_ref, b_ref, out_ref, card_ref):
     r = _mixed_op(a_ref[...], b_ref[...], opid_ref[...])
     out_ref[...] = r
-    bn = r.shape[0]
-    card_ref[...] = harley_seal_reduce(r.reshape(bn, WORDS // 16, 16))[:, None]
+    card_ref[...] = harley_seal_reduce(r)
 
 
 def _pair_card_kernel(opid_ref, a_ref, b_ref, card_ref):
     r = _mixed_op(a_ref[...], b_ref[...], opid_ref[...])
-    bn = r.shape[0]
-    card_ref[...] = harley_seal_reduce(r.reshape(bn, WORDS // 16, 16))[:, None]
+    card_ref[...] = harley_seal_reduce(r)
 
 
 def _pad_rows(x, block, fill=0):
@@ -126,27 +124,21 @@ def bitset_pair_card(a: jax.Array, b: jax.Array, opids: jax.Array, *,
     return card[:n, 0]
 
 
-def _probe_kernel(vals_ref, card_ref_in, words_ref, mask_ref, count_ref):
-    vals = vals_ref[...]                             # (1, ARRAY_CAP) int32
-    words = words_ref[...]                           # (1, WORDS) uint32
-    card = card_ref_in[0, 0]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, ARRAY_CAP), 1)
-    valid = pos < card
-    v = jnp.where(valid, vals, 0)
-    wcol = jax.lax.broadcasted_iota(jnp.int32, (TILE, WORDS), 1)
-    mask = jnp.zeros((1, ARRAY_CAP), jnp.int32)
-    for i in range(ARRAY_CAP // TILE):
-        vt = jax.lax.dynamic_slice(v, (0, i * TILE), (1, TILE))[0]
-        # one-hot word select: each value hits exactly one word, so the
-        # masked sum IS the gathered word (no vector gather on the VPU)
-        onehot = (wcol == (vt >> 5)[:, None]).astype(jnp.uint32)
-        wsel = (onehot * words).sum(axis=-1)         # (TILE,) uint32
-        bit = (wsel >> (vt & 31).astype(jnp.uint32)) & jnp.uint32(1)
-        mask = jax.lax.dynamic_update_slice(
-            mask, bit.astype(jnp.int32)[None, :], (0, i * TILE))
-    mask = jnp.where(valid, mask, 0)
+def _probe_kernel(vals_ref, words_smem, mask_ref, count_ref):
+    """Each of the pair's 4096 value slots (a (32, 128) vector; invalid
+    slots hold a sentinel past the last word) tests its bit: one step per
+    bitset word, broadcast from SMEM against every slot at once."""
+    v = vals_ref[...]
+    widx, shift = v >> 5, v & 31
+
+    def step(w, hit):
+        bit = jax.lax.shift_right_logical(
+            jnp.broadcast_to(words_smem[w], v.shape), shift) & 1
+        return hit | jnp.where(widx == w, bit, 0)
+
+    mask = jax.lax.fori_loop(0, WORDS, step, jnp.zeros(v.shape, jnp.int32))
     mask_ref[...] = mask
-    count_ref[...] = mask.sum(axis=-1, dtype=jnp.int32)[:, None]
+    count_ref[...] = sum_to_cell(mask)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -163,19 +155,21 @@ def array_bitset_probe(vals: jax.Array, card: jax.Array,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n = vals.shape[0]
-    vspec = pl.BlockSpec((1, ARRAY_CAP), lambda i: (i, 0))
-    wspec = pl.BlockSpec((1, WORDS), lambda i: (i, 0))
-    cspec = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    v = mask_invalid(vals, card, CONTAINER_BITS)
+    vspec = pl.BlockSpec((None, ARRAY_CAP // 128, 128), lambda i: (i, 0, 0))
     mask, count = pl.pallas_call(
         _probe_kernel,
         grid=(n,),
-        in_specs=[vspec, cspec, wspec],
-        out_specs=[vspec, cspec],
+        in_specs=[vspec,
+                  pl.BlockSpec((WORDS,), lambda i: (i,),
+                               memory_space=pltpu.SMEM)],
+        out_specs=[vspec, pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((n, ARRAY_CAP), jnp.int32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, ARRAY_CAP // 128, 128), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(vals.astype(jnp.int32), card.astype(jnp.int32)[:, None],
-      words.astype(jnp.uint32))
-    return mask, count[:, 0]
+    )(v.reshape(n, ARRAY_CAP // 128, 128),
+      jax.lax.bitcast_convert_type(words.astype(jnp.uint32),
+                                   jnp.int32).reshape(-1))
+    return mask.reshape(n, ARRAY_CAP), count[:, 0, 0]

@@ -211,6 +211,77 @@ def array_bitset_probe(vals: jax.Array, card: jax.Array,
 METRICS = ("jaccard", "cosine", "containment")   # index == metric id
 
 
+def _f32_parts(x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Positive normal float32 -> (24-bit integer significand m, unbiased
+    exponent e) with x == m * 2^(e - 23)."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return (bits & 0x7FFFFF) | 0x800000, ((bits >> 23) & 0xFF) - 127
+
+
+def _f32_round(q: jax.Array, sticky: jax.Array, e: jax.Array) -> jax.Array:
+    """Round a 25-bit significand ``q`` (24 bits + guard; ``sticky`` marks
+    a nonzero tail) to nearest-even float32 with unbiased exponent ``e``."""
+    mant = q >> 1
+    up = ((q & 1) == 1) & (sticky | ((mant & 1) == 1))
+    mant = mant + up.astype(jnp.int32)
+    carry = mant >> 24                    # rounded up to 2^24: renormalize
+    bits = ((e + carry + 127) << 23) | ((mant >> carry) & 0x7FFFFF)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def div_rn(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Correctly rounded float32 ``a / b`` for ``a >= 0`` and ``b > 0``
+    whose quotient is 0 or a normal float32.
+
+    The TPU computes float32 division by a reciprocal approximation that
+    misses the nearest float32 on roughly a third of integer quotients
+    (and ``1/3`` and ``2/6`` then differ), so scores could neither match
+    the host twin bit for bit nor tie where the host ties.  Integer long
+    division of the significands is exact on every backend."""
+    ma, ea = _f32_parts(a)
+    mb, eb = _f32_parts(b)
+    lt = ma < mb
+
+    def digit(_, carry):
+        q, r = carry
+        r = r << 1
+        bit = r >= mb
+        return (q << 1) | bit.astype(jnp.int32), jnp.where(bit, r - mb, r)
+
+    # quotient significand in [1, 2): a leading 1, then 24 more bits
+    q, r = jax.lax.fori_loop(0, 24, digit, (
+        jnp.ones_like(ma), jnp.where(lt, ma << 1, ma) - mb))
+    out = _f32_round(q, r != 0, ea - eb - lt.astype(jnp.int32))
+    return jnp.where(a == 0, jnp.float32(0.0), out)
+
+
+def sqrt_rn(x: jax.Array) -> jax.Array:
+    """Correctly rounded float32 square root of ``x >= 0`` (normal or 0),
+    by digit-by-digit integer square root: the TPU's float32 ``sqrt`` is
+    an approximation, like its division (see :func:`div_rn`)."""
+    m, e = _f32_parts(x)
+    odd = ((e - 23) & 1) == 1
+    big = jnp.where(odd, m << 1, m)       # x = big * 2^(2h), big < 2^25
+    h = (e - 23 - odd.astype(jnp.int32)) >> 1
+
+    def digit(i, carry):                  # isqrt(big * 2^26), 2 bits a step
+        root, rem = carry
+        pair = jnp.where(i < 13, (big >> jnp.maximum(24 - 2 * i, 0)) & 3, 0)
+        rem = (rem << 2) | pair
+        trial = (root << 2) | 1
+        ge = rem >= trial
+        return (root << 1) | ge.astype(jnp.int32), \
+            jnp.where(ge, rem - trial, rem)
+
+    root, rem = jax.lax.fori_loop(0, 26, digit, (jnp.zeros_like(m),
+                                                 jnp.zeros_like(m)))
+    wide = root >= (1 << 25)              # 26-bit root: drop one more bit
+    q = jnp.where(wide, root >> 1, root)
+    sticky = (rem != 0) | (wide & ((root & 1) == 1))
+    out = _f32_round(q, sticky, h + 11 + wide.astype(jnp.int32))
+    return jnp.where(x == 0, jnp.float32(0.0), out)
+
+
 def similarity_scores(inter: jax.Array, q_card: jax.Array,
                       cards: jax.Array, metric: str) -> jax.Array:
     """Similarity scores from intersection cardinalities, float32.
@@ -220,21 +291,38 @@ def similarity_scores(inter: jax.Array, q_card: jax.Array,
     jaccard = |A∩B| / |A∪B|, cosine = |A∩B| / sqrt(|A||B|),
     containment = |A∩B| / |A| (the query side).  A zero denominator
     scores 1.0 (the host convention).  The formula is evaluated in
-    float32 with a fixed operation order so the device kernel, the jnp
-    oracle, and the numpy host twin (core.pairwise._scores_host) produce
-    bit-identical scores -- top-k tie ordering depends on it."""
+    float32 with a fixed operation order and correctly rounded division
+    and square root (:func:`div_rn`, :func:`sqrt_rn`), so the device
+    path, the jnp oracle, and the numpy host twin
+    (core.pairwise._scores_host) produce bit-identical scores -- top-k
+    tie ordering depends on it."""
     interf = inter.astype(jnp.float32)
     qc = q_card.astype(jnp.float32)
     oc = cards.astype(jnp.float32)
     if metric == "jaccard":
         denom = qc + oc - interf
     elif metric == "cosine":
-        denom = jnp.sqrt(qc * oc)
+        denom = sqrt_rn(qc * oc)
     elif metric == "containment":
         denom = jnp.broadcast_to(qc, oc.shape)
     else:
         raise ValueError(metric)
-    return jnp.where(denom > 0, interf / denom, jnp.float32(1.0))
+    return jnp.where(denom > 0, div_rn(interf, denom),
+                     jnp.float32(1.0))
+
+
+def candidate_inter(per_row: jax.Array, starts: jax.Array) -> jax.Array:
+    """Per-candidate intersection cardinalities from per-row counts:
+    candidate ``t`` owns rows ``starts[t]:starts[t+1]``; rows past
+    ``starts[-1]`` (layout padding) are dropped.  A per-segment sum, NOT
+    a global prefix: the grand total of intersection bits across all
+    candidates can overflow int32 even though each candidate's own count
+    cannot."""
+    t = starts.shape[0] - 1
+    seg_id = jnp.searchsorted(starts[1:], jnp.arange(per_row.shape[0]),
+                              side="right")
+    return jax.ops.segment_sum(per_row, seg_id, num_segments=t) \
+        .astype(jnp.int32)
 
 
 def topk_select(score: jax.Array, inter: jax.Array,
@@ -273,14 +361,7 @@ def similarity_topk(rows: jax.Array, row_col: jax.Array, starts: jax.Array,
     best-first, ties at equal score resolved to the lowest index."""
     rows = rows.astype(jnp.uint32)
     t = starts.shape[0] - 1
-    per_row = popcount_words(rows & q_words[row_col])
-    # per-segment sum, NOT a global prefix: the grand total of
-    # intersection bits across all candidates can overflow int32 even
-    # though each candidate's own count cannot
-    seg_id = jnp.searchsorted(starts[1:], jnp.arange(per_row.shape[0]),
-                              side="right")
-    inter = jax.ops.segment_sum(per_row, seg_id, num_segments=t) \
-        .astype(jnp.int32)
+    inter = candidate_inter(popcount_words(rows & q_words[row_col]), starts)
     score = similarity_scores(inter, q_card, cards, metric)
     score = jnp.where(jnp.arange(t) == exclude, jnp.float32(-1.0), score)
     return topk_select(score, inter, k)
@@ -292,14 +373,12 @@ def topk_select_ids(score: jax.Array, inter: jax.Array, gidx: jax.Array,
     LOWEST global id among the maxes), the selected id's entries masked
     to -2.0.
 
-    This is the shard-merge tie rule pinned by the sharded similarity
-    path: every entry carries its GLOBAL candidate index ``gidx``, and a
-    tie group cut at the k boundary resolves to ascending global index --
-    even when the tied entries arrived from different shards.  Applied
-    per shard (over local candidates labelled with global ids) and again
-    over the all-gathered S*k lists, it reproduces the single-device
-    ``topk_select`` order exactly, because both implement the same total
-    order (score descending, global index ascending).
+    This is the tie rule pinned by the pruned and sharded similarity
+    paths: every entry carries its GLOBAL candidate index ``gidx``, and a
+    tie group cut at the k boundary resolves to ascending global index.
+    Over any labelled subset that holds the top k it reproduces the
+    single-device ``topk_select`` order exactly, because both implement
+    the same total order (score descending, global index ascending).
 
     Returns (gidx (k,) int32, score (k,) float32, inter (k,) int32).
     ``gidx`` values may repeat only for padding entries (score < -1);
@@ -321,11 +400,12 @@ def similarity_topk_ids(rows: jax.Array, row_col: jax.Array,
                         starts: jax.Array, q_words: jax.Array,
                         q_card: jax.Array, cards: jax.Array,
                         gidx: jax.Array, n_valid: jax.Array,
-                        exclude: jax.Array, *, metric: str, k: int
+                        exclude: jax.Array, *, metric: str, k: int,
+                        axis: str | None = None
                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Per-shard fused score + select: :func:`similarity_topk` over a
-    candidate SUBSET labelled with global ids (one shard of a sharded
-    similarity dispatch, or any pruned candidate list).
+    """Fused score + select: :func:`similarity_topk` over a candidate
+    SUBSET labelled with global ids (a pruned candidate list, whole or
+    split across the shards of a mesh axis).
 
     Differences from the dense oracle: ``gidx`` (T,) int32 carries each
     local slot's GLOBAL candidate index (selection and exclusion key on
@@ -333,18 +413,19 @@ def similarity_topk_ids(rows: jax.Array, row_col: jax.Array,
     padding and score -2.0 no matter what their padded rows/cards say
     (an all-zero pad row under the cosine/zero-denominator convention
     would otherwise score 1.0 and corrupt the local top-k); ``exclude``
-    is a GLOBAL candidate id (scored -1.0 on its owning shard; -1 none).
+    is a GLOBAL candidate id (scored -1.0; -1 none).  With ``axis``
+    (inside ``shard_map``) each shard holds only some of every
+    candidate's rows: the per-candidate partial intersections are summed
+    across the axis before scoring.
 
     Returns (gidx (k,) int32, score (k,) float32, inter (k,) int32),
     best-first, score ties to the lowest GLOBAL index
     (:func:`topk_select_ids`)."""
     rows = rows.astype(jnp.uint32)
     t = starts.shape[0] - 1
-    per_row = popcount_words(rows & q_words[row_col])
-    seg_id = jnp.searchsorted(starts[1:], jnp.arange(per_row.shape[0]),
-                              side="right")
-    inter = jax.ops.segment_sum(per_row, seg_id, num_segments=t) \
-        .astype(jnp.int32)
+    inter = candidate_inter(popcount_words(rows & q_words[row_col]), starts)
+    if axis is not None:
+        inter = jax.lax.psum(inter, axis)
     score = similarity_scores(inter, q_card, cards, metric)
     score = jnp.where(gidx == exclude, jnp.float32(-1.0), score)
     score = jnp.where(jnp.arange(t) >= n_valid, jnp.float32(-2.0), score)
@@ -497,9 +578,9 @@ def gather_rows_dual(table: jax.Array, staged: jax.Array,
     row; the other points at a reserved all-zero row (``table`` row /
     position 0 is the arena's zero row, ``staged`` row 0 is the block's),
     so the OR is exact slot selection -- zero is the OR identity, never a
-    blend.  ``table`` may be a sharded assembled per-shard slab
-    (``core.arena.ShardSlabs.assembled``): under jit the take lowers to a
-    cross-device gather, so resident rows never touch the host."""
+    blend.  ``table`` may be one shard's slab inside ``shard_map``
+    (``core.aggregate._sharded_rows_fn``), whose local row 0 is not zero
+    except on shard 0 -- so staged rows are routed there."""
     return (jnp.take(table.astype(jnp.uint32), pos.astype(jnp.int32),
                      axis=0)
             | jnp.take(staged.astype(jnp.uint32), sidx.astype(jnp.int32),
@@ -514,8 +595,7 @@ def segment_reduce_rows_dual(table: jax.Array, staged: jax.Array,
                              ) -> tuple[jax.Array, jax.Array]:
     """Row-table twin of :func:`segment_reduce_rows` for the arena's
     dual-source layout: resident rows gather from ``table`` by slab
-    position (single-device slab, or the sharded assembled layout --
-    global position ``(r % S) * cap_s + r // S``), cold rows from a small
+    position (a single-device slab), cold rows from a small
     per-call ``staged`` block, via :func:`gather_rows_dual`.  Unlike
     ``segment_reduce_rows`` with an appended host block, the resident
     table is never copied per call.  Pad slots point both indices at the

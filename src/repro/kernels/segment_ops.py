@@ -19,7 +19,11 @@ The inner dimension walks a segment's rows; the output block index ignores
 it, so the accumulator stays resident in VMEM across the whole segment
 (the standard Pallas revisited-output accumulation pattern).  Row offsets
 arrive via scalar prefetch so the input index map can address ragged
-segments; steps past a segment's end contribute the op identity.
+segments; steps past a segment's end contribute the op identity.  Rows
+travel as squeezed ``(1, WORDS)`` blocks of an ``(N, 1, WORDS)`` view (the
+TPU refuses a ``(1, WORDS)`` block of an ``(N, WORDS)`` array), and a
+dispatch runs in chunks of ``SEG_CHUNK`` segments so the prefetched
+offsets fit in SMEM.
 
 ``threshold`` extends the same engine to T-occurrence queries ("Threshold
 and Symmetric Functions over Bitmaps", Kaser & Lemire): a bit-sliced
@@ -27,10 +31,10 @@ ripple-carry counter (one uint32 plane per counter bit, ``L = ceil(log2(
 jmax + 1))`` planes in VMEM scratch) counts how many inputs set each of the
 2^16 bits, and finalization runs a bitwise magnitude comparator against
 ``T`` -- a runtime scalar (scalar prefetch), so threshold sweeps over the
-same inputs reuse one compiled kernel.  Per-row integer weights (scalar
-prefetch, static bit width) generalize the counter to WEIGHTED threshold
-queries via shift-and-add: weight bit ``b`` feeds the row's plane into the
-counter at plane ``b``.
+same inputs reuse one compiled kernel.  Per-row integer weights (a
+``(1, 1)`` VMEM block beside each row, static bit width) generalize the
+counter to WEIGHTED threshold queries via shift-and-add: weight bit ``b``
+feeds the row's plane into the counter at plane ``b``.
 
 ``andnot`` runs difference chains ``a - (b1 | b2 | ...)`` as one plan: the
 minuend (each segment's first row) parks in the output block while the
@@ -56,6 +60,10 @@ _FULL = np.uint32(0xFFFFFFFF)
 
 OPS = ("or", "and", "xor", "andnot", "threshold")
 
+# segments per pallas_call: 2 * SEG_CHUNK prefetched int32 scalars (row
+# offsets + thresholds) stay well inside the TPU's 1 MiB of SMEM
+SEG_CHUNK = 8192
+
 
 def counter_planes(jmax: int) -> int:
     """Bit-sliced counter planes needed to count up to ``jmax`` inputs."""
@@ -80,10 +88,10 @@ def _finalize(words, card_ref, out_ref, seg_len):
     """Mask empty segments to zero and emit words + lazy popcount."""
     r = jnp.where(seg_len > 0, words, jnp.uint32(0))
     out_ref[...] = r
-    card_ref[...] = harley_seal_reduce(r.reshape(1, WORDS // 16, 16))[:, None]
+    card_ref[...] = harley_seal_reduce(r)
 
 
-def _reduce_kernel(starts_ref, t_ref, w_ref, slab_ref, out_ref, card_ref, *,
+def _reduce_kernel(starts_ref, t_ref, slab_ref, out_ref, card_ref, *,
                    op, jmax):
     s = pl.program_id(0)
     j = pl.program_id(1)
@@ -103,7 +111,7 @@ def _reduce_kernel(starts_ref, t_ref, w_ref, slab_ref, out_ref, card_ref, *,
         _finalize(out_ref[...], card_ref, out_ref, seg_len)
 
 
-def _andnot_kernel(starts_ref, t_ref, w_ref, slab_ref, out_ref, card_ref,
+def _andnot_kernel(starts_ref, t_ref, slab_ref, out_ref, card_ref,
                    rest_ref, *, jmax):
     """Fused difference chain: row0 & ~(row1 | row2 | ...).
 
@@ -130,8 +138,8 @@ def _andnot_kernel(starts_ref, t_ref, w_ref, slab_ref, out_ref, card_ref,
         _finalize(out_ref[...] & ~rest_ref[...], card_ref, out_ref, seg_len)
 
 
-def _threshold_kernel(starts_ref, t_ref, w_ref, slab_ref, out_ref, card_ref,
-                      cnt_ref, *, jmax, planes, wbits, n_rows):
+def _threshold_kernel(starts_ref, t_ref, slab_ref, w_ref, out_ref, card_ref,
+                      cnt_ref, *, jmax, planes, wbits):
     s = pl.program_id(0)
     j = pl.program_id(1)
     seg_len = starts_ref[s + 1] - starts_ref[s]
@@ -142,9 +150,11 @@ def _threshold_kernel(starts_ref, t_ref, w_ref, slab_ref, out_ref, card_ref,
 
     # shift-and-add of one weighted input bit-plane into the bit-sliced
     # counter: weight bit b contributes the row's plane at counter plane b
-    # (wbits == 1 degenerates to the unweighted ripple-carry add)
+    # (wbits == 1 degenerates to the unweighted ripple-carry add).  The
+    # row's weight is a (1, 1) VMEM block riding the row's own index, so
+    # no row-sized vector sits in SMEM.
     x = jnp.where(j < seg_len, slab_ref[...], jnp.uint32(0))
-    w = w_ref[jnp.minimum(starts_ref[s] + j, n_rows - 1)]
+    w = w_ref[...]
     for b in range(wbits):
         carry = jnp.where((w >> b) & 1 == 1, x, jnp.uint32(0))
         for i in range(b, planes):
@@ -168,6 +178,50 @@ def _threshold_kernel(starts_ref, t_ref, w_ref, slab_ref, out_ref, card_ref,
             gt = gt | (eq & ci & ~tmask)
             eq = eq & ~(ci ^ tmask)
         _finalize(gt | eq, card_ref, out_ref, seg_len)
+
+
+def _segment_call(slab3, w3, starts, tval, op, jmax, planes, wbits,
+                  interpret):
+    """One pallas_call over the segments described by ``starts`` (absolute
+    row offsets into ``slab3``).  Rows travel as squeezed (1, WORDS)
+    blocks of the (N, 1, WORDS) slab, which satisfies the TPU's block
+    tiling rule without padding a row to 8 sublanes."""
+    n = slab3.shape[0]
+    s = starts.shape[0] - 1
+
+    def row_index(si, j, st, tv):
+        return (jnp.minimum(st[si] + j, n - 1), 0, 0)
+
+    in_specs = [pl.BlockSpec((None, 1, WORDS), row_index)]
+    operands = [slab3]
+    if op == "threshold":
+        kernel = functools.partial(_threshold_kernel, jmax=jmax,
+                                   planes=planes, wbits=wbits)
+        scratch = [pltpu.VMEM((planes, 1, WORDS), jnp.uint32)]
+        in_specs.append(pl.BlockSpec((None, 1, 1), row_index))
+        operands.append(w3)
+    elif op == "andnot":
+        kernel = functools.partial(_andnot_kernel, jmax=jmax)
+        scratch = [pltpu.VMEM((1, WORDS), jnp.uint32)]
+    else:
+        kernel = functools.partial(_reduce_kernel, op=op, jmax=jmax)
+        scratch = []
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s, jmax),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((None, 1, WORDS), lambda si, j, st, tv: (si, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda si, j, st, tv: (si, 0, 0))],
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((s, 1, WORDS), jnp.uint32),
+                   jax.ShapeDtypeStruct((s, 1, 1), jnp.int32)],
+        interpret=interpret,
+    )(starts, tval, *operands)
 
 
 @functools.partial(jax.jit,
@@ -197,6 +251,11 @@ def segment_reduce(slab: jax.Array, starts: jax.Array, op: str, *,
             must satisfy max-per-segment-total-weight < 2^planes and
             t < 2^planes.
 
+    Segments run in chunks of at most ``SEG_CHUNK`` per ``pallas_call``:
+    each chunk's row offsets and thresholds are scalar-prefetched into
+    SMEM (1 MiB on TPU), so the chunk bounds what SMEM must hold however
+    many segments a coalesced batch carries.
+
     Returns (words (S, WORDS) uint32, cards (S,) int32).
     """
     assert op in OPS, op
@@ -209,44 +268,23 @@ def segment_reduce(slab: jax.Array, starts: jax.Array, op: str, *,
     # (S,) per-segment thresholds; a scalar T broadcasts to every segment
     tval = jnp.broadcast_to(
         jnp.asarray(threshold, jnp.int32).reshape(-1), (s,))
-    if weights is None:
-        wval = jnp.ones((n,), jnp.int32)
-    else:
-        wval = weights.astype(jnp.int32)
-
-    def row_index(si, j, st, tv, wv):
-        return (jnp.minimum(st[si] + j, n - 1), 0)
-
-    out_specs = [pl.BlockSpec((1, WORDS), lambda si, j, st, tv, wv: (si, 0)),
-                 pl.BlockSpec((1, 1), lambda si, j, st, tv, wv: (si, 0))]
-    out_shape = [jax.ShapeDtypeStruct((s, WORDS), jnp.uint32),
-                 jax.ShapeDtypeStruct((s, 1), jnp.int32)]
+    w3 = None
     if op == "threshold":
         if planes is None:
             planes = counter_planes(jmax)
-        kernel = functools.partial(_threshold_kernel, jmax=jmax,
-                                   planes=planes, wbits=wbits, n_rows=n)
-        scratch = [pltpu.VMEM((planes, 1, WORDS), jnp.uint32)]
-    elif op == "andnot":
-        kernel = functools.partial(_andnot_kernel, jmax=jmax)
-        scratch = [pltpu.VMEM((1, WORDS), jnp.uint32)]
-    else:
-        kernel = functools.partial(_reduce_kernel, op=op, jmax=jmax)
-        scratch = []
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(s, jmax),
-        in_specs=[pl.BlockSpec((1, WORDS), row_index)],
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    words, card = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(starts, tval, wval, slab.astype(jnp.uint32))
-    return words, card[:, 0]
+        w3 = (jnp.ones((n, 1, 1), jnp.int32) if weights is None
+              else weights.astype(jnp.int32).reshape(n, 1, 1))
+    slab3 = slab.astype(jnp.uint32).reshape(n, 1, WORDS)
+    words, cards = [], []
+    for c0 in range(0, s, SEG_CHUNK):
+        c1 = min(s, c0 + SEG_CHUNK)
+        w, c = _segment_call(slab3, w3, starts[c0:c1 + 1], tval[c0:c1],
+                             op, jmax, planes, wbits, interpret)
+        words.append(w)
+        cards.append(c)
+    words = words[0] if len(words) == 1 else jnp.concatenate(words)
+    cards = cards[0] if len(cards) == 1 else jnp.concatenate(cards)
+    return words.reshape(s, WORDS), cards.reshape(s)
 
 
 @functools.partial(jax.jit,
@@ -291,10 +329,8 @@ def segment_reduce_rows_dual(table: jax.Array, staged: jax.Array,
     slot is a real row, the other the reserved all-zero row -- OR is
     exact slot selection), then reduces with the Pallas segment kernel.
 
-    ``table`` is a resident arena slab -- the single-device ``(cap,
-    WORDS)`` layout or the sharded assembled per-shard layout
-    (``core.arena.ShardSlabs.assembled``, global position
-    ``(r % S) * cap_s + r // S``) -- and is NEVER copied per call;
+    ``table`` is a resident single-device ``(cap, WORDS)`` arena slab
+    and is NEVER copied per call;
     ``staged`` is the small per-call block of cold host rows (row 0
     zero).  Warm queries ship only ``pos``/``sidx``/``starts`` over
     PCIe."""

@@ -1,21 +1,20 @@
-"""Device-resident fused top-k similarity kernel.
+"""Device-resident fused top-k similarity kernels.
 
 The paper's fast-count argument (section 5.9: the logical op and the
 popcount must happen while the words sit in vector registers) extends to
 similarity joins: the *scores* never need to leave the device either.
-This module fuses the whole ``InvertedIndex.similar`` hot path --
+This module runs the whole ``InvertedIndex.similar`` hot path --
 AND-cardinality scoring of a query bitmap against T candidate bitmaps,
 metric evaluation (jaccard / cosine / containment by inclusion-exclusion
-over the AND count), and the k-selection -- into ONE engine dispatch, so
-only k indices and k scores ever cross back to the host.
+over the AND count), and the k-selection -- inside ONE jit, so only k
+indices and k scores ever cross back to the host.
 
 Layout (prepared once by ``core.pairwise.SimilarityEngine`` and cached on
 device -- the serving contract):
 
   * ``rows``    (N, WORDS) uint32: every candidate container promoted to
     the bitset domain, candidate-major (candidate t owns rows
-    ``starts[t]:starts[t+1]``; ragged, described by scalar-prefetched
-    offsets exactly like ``segment_ops``).
+    ``starts[t]:starts[t+1]``).
   * ``row_col`` (N,) int32: which global chunk key each row belongs to --
     the scoring step ANDs row r with ``q_words[row_col[r]]``, so a query
     that lacks the key contributes zero automatically.
@@ -23,23 +22,27 @@ device -- the serving contract):
     the global key columns.  This is the ONLY per-query device transfer
     (C * 8 kB); the candidate slab stays resident.
 
-Two Pallas stages compose inside one jit (one XLA dispatch at runtime):
+Three stages compose inside one jit:
 
-  1. ``_score_kernel`` -- grid (T, jmax): per-row AND + Harley-Seal
-     popcount accumulates each candidate's intersection cardinality in a
-     VMEM scalar (the revisited-output pattern of ``segment_ops``); the
-     segment's last step evaluates the float32 metric score.
-  2. ``_select_kernel`` -- a threshold-refinement pass: k rounds of
-     (max, first-index-of-max) over the score vector held in VMEM,
-     masking each winner.  Ties at equal score resolve to the LOWEST
-     candidate index -- bit-identical to a stable host argsort of the
-     negated scores, which is what the host planner runs off-device.
+  1. ``_and_card_kernel`` -- grid over 8-row blocks of ``rows``: each
+     step DMAs the 8 query rows its candidate rows name (``row_col``
+     arrives as an SMEM block, never as a prefetched N-vector, which
+     would overflow the TPU's 1 MiB of SMEM at index sizes), ANDs and
+     Harley-Seal popcounts them.
+  2. per-candidate sums (``ref.candidate_inter``) and the float32 score
+     (``ref.similarity_scores``: correctly rounded, so scores and ties
+     match the host twin bit for bit).
+  3. ``topk_merge`` -- ``_select_ids_kernel`` runs k rounds of (max,
+     lowest label among the maxes) over (64, 128) blocks of labelled
+     scores, then again over the blocks' k-lists until one block
+     remains.  Per-block selection keeps every member of the global
+     top-k (each block's share of it is a prefix of the block's own
+     order), so the result equals one selection over the whole vector.
 
-``kernels.ref.similarity_topk`` is the pure-jnp oracle; the score formula
-itself lives in ``kernels.ref.similarity_scores`` with a fixed float32
-operation order shared by the kernel, the oracle, and the numpy host twin
-so all three paths select identically.  See docs/ARCHITECTURE.md
-(sections 4.2/5.9 row of the paper map).
+Ties at equal score resolve to the LOWEST candidate index -- bit-identical
+to a stable host argsort of the negated scores, which is what the host
+planner runs off-device.  ``kernels.ref.similarity_topk`` is the pure-jnp
+oracle.  See docs/ARCHITECTURE.md (sections 4.2/5.9 row of the paper map).
 """
 
 from __future__ import annotations
@@ -52,60 +55,169 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.harley_seal import harley_seal_reduce
-from repro.kernels.ref import METRICS, WORDS, similarity_scores
+from repro.kernels.ref import (METRICS, WORDS, candidate_inter,
+                               similarity_scores)
 
 
-def _score_kernel(starts_ref, col_ref, cards_ref, misc_ref, row_ref, q_ref,
-                  score_ref, inter_ref, acc_ref, *, metric, jmax):
-    t = pl.program_id(0)
-    j = pl.program_id(1)
-    seg_len = starts_ref[t + 1] - starts_ref[t]
-    x = jnp.where(j < seg_len, row_ref[...] & q_ref[...], jnp.uint32(0))
-    pc = harley_seal_reduce(x.reshape(1, WORDS // 16, 16))[:, None]
-
-    @pl.when(j == 0)
-    def _():
-        acc_ref[...] = pc
-
-    @pl.when(j > 0)
-    def _():
-        acc_ref[...] = acc_ref[...] + pc
-
-    @pl.when(j == jmax - 1)
-    def _():
-        inter = acc_ref[0, 0]
-        # THE score formula (ref.similarity_scores): one definition
-        # serves the oracle, the kernel, and (via its numpy twin) the
-        # host planner, so tie order can never drift between paths
-        s = similarity_scores(inter, misc_ref[0], cards_ref[t], metric)
-        s = jnp.where(t == misc_ref[1], jnp.float32(-1.0), s)
-        score_ref[...] = s.reshape(1, 1)
-        inter_ref[...] = inter.reshape(1, 1)
+ROW_BLOCK = 8           # candidate rows per AND-popcount grid step
+_COL_BLOCK = 1024       # row_col entries per SMEM block (XLA tiles 1-D by 1024)
+SELECT_ROWS = 64        # select block: (64, 128) labelled entries
+_LANES = 128
+_BIG = 2**31 - 1        # label of layout padding: never the lowest
 
 
-def _select_kernel(score_ref, inter_ref, idx_ref, sco_ref, int_ref, *, k):
-    """Threshold-refinement k-selection: k rounds of (max, first index of
-    max) with the winner masked out -- first-max-wins reproduces the
-    stable host argsort tie order (lowest index first)."""
-    s = score_ref[...]                           # (1, T)
-    n = s.shape[1]
-    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    for i in range(k):
-        m = jnp.max(s)
-        j = jnp.min(jnp.where(s == m, cols, n))
-        hit = cols == j
-        idx_ref[0, i] = j
-        sco_ref[0, i] = m
-        int_ref[0, i] = jnp.sum(jnp.where(hit, inter_ref[...], 0))
-        s = jnp.where(hit, jnp.float32(-2.0), s)
+def _and_card_kernel(col_ref, rows_ref, q_hbm, out_ref, qbuf, sem):
+    """|rows[r] & q_words[row_col[r]]| for one 8-row block: the query
+    rows the block names are DMA'd from HBM by column.  Query rows live
+    as (C, 1, WORDS) so that one row is a whole tile to the DMA engine."""
+    base = (pl.program_id(0) % (_COL_BLOCK // ROW_BLOCK)) * ROW_BLOCK
+    copies = [pltpu.make_async_copy(q_hbm.at[col_ref[base + i]],
+                                    qbuf.at[i], sem.at[i])
+              for i in range(ROW_BLOCK)]
+    for c in copies:
+        c.start()
+    for i, c in enumerate(copies):
+        c.wait()
+        out_ref[pl.ds(i, 1), :] = harley_seal_reduce(
+            rows_ref[pl.ds(i, 1), :] & qbuf[i])
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("metric", "k", "jmax", "interpret"))
+def _interpret_params(interpret: bool):
+    # DMAs and semaphores need the TPU-semantics interpreter off the chip
+    return pltpu.InterpretParams() if interpret else False
+
+
+def _pad_to(x, size, fill):
+    n_pad = size - x.shape[0]
+    if not n_pad:
+        return x
+    return jnp.pad(x, ((0, n_pad),) + ((0, 0),) * (x.ndim - 1),
+                   constant_values=fill)
+
+
+def row_and_card(rows: jax.Array, row_col: jax.Array, q_words: jax.Array,
+                 *, interpret: bool) -> jax.Array:
+    """(N,) int32 per-row ``popcount(rows[r] & q_words[row_col[r]])``.
+    The candidate slab is never padded or copied: the last 8-row block
+    may run past row N-1, and its extra outputs are dropped."""
+    n = rows.shape[0]
+    steps = -(-n // ROW_BLOCK)
+    n_col = -(-n // _COL_BLOCK) * _COL_BLOCK
+    out = pl.pallas_call(
+        _and_card_kernel,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((_COL_BLOCK,),
+                               lambda i: (i // (_COL_BLOCK // ROW_BLOCK),),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((ROW_BLOCK, WORDS), lambda i: (i, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((ROW_BLOCK, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps * ROW_BLOCK, 1), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((ROW_BLOCK, 1, WORDS), jnp.uint32),
+                        pltpu.SemaphoreType.DMA((ROW_BLOCK,))],
+        interpret=_interpret_params(interpret),
+    )(_pad_to(row_col.astype(jnp.int32), n_col, 0),
+      rows.astype(jnp.uint32),
+      q_words.astype(jnp.uint32).reshape(q_words.shape[0], 1, WORDS))
+    return out[:n, 0]
+
+
+def _max2(x):
+    return jnp.max(jnp.max(x, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _min2(x):
+    return jnp.min(jnp.min(x, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _select_ids_kernel(score_ref, inter_ref, gidx_ref, idx_ref, sco_ref,
+                       int_ref, *, k):
+    """k rounds of (max, lowest GLOBAL id among the maxes) over one
+    block: the pinned global-id tie rule.  Entries of the winning id
+    mask together, so identical padding entries cannot occupy more than
+    one round.  Results accumulate in (1, kp) vectors (the TPU stores no
+    scalars to VMEM) and are written once."""
+    g = gidx_ref[...]
+    it = inter_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, idx_ref.shape, 1)
+
+    def round_(i, carry):
+        s, oi, os_, on = carry
+        m = _max2(s)
+        w = _min2(jnp.where(s == m, g, _BIG))
+        hit = (g == w) & (s == m)
+        v = _max2(jnp.where(hit, it, 0))
+        sel = lane == i
+        return (jnp.where(hit, jnp.float32(-2.0), s),
+                jnp.where(sel, w, oi), jnp.where(sel, m, os_),
+                jnp.where(sel, v, on))
+
+    zi = jnp.zeros(idx_ref.shape, jnp.int32)
+    _, oi, os_, on = jax.lax.fori_loop(
+        0, k, round_,
+        (score_ref[...], zi, jnp.zeros(idx_ref.shape, jnp.float32), zi))
+    idx_ref[...] = oi
+    sco_ref[...] = os_
+    int_ref[...] = on
+
+
+def _select_blocks(score, inter, gidx, k, interpret):
+    """One select pass: every (rows, 128) block of the flat labelled
+    entries yields its k best as (nb, 1, kp) rows."""
+    m = score.shape[0]
+    # a block at least 2k wide: every pass over > 1 block halves the
+    # entries, so the merge passes terminate
+    rows = max(SELECT_ROWS, -(-2 * k // (8 * _LANES)) * 8)
+    per_block = rows * _LANES
+    if m > per_block:
+        nb = -(-m // per_block)
+    else:
+        nb, rows = 1, max(8, -(-m // (8 * _LANES)) * 8)
+    size = nb * rows * _LANES
+    kp = -(-k // _LANES) * _LANES
+    ins = [_pad_to(score.astype(jnp.float32), size, -2.0),
+           _pad_to(inter.astype(jnp.int32), size, 0),
+           _pad_to(gidx.astype(jnp.int32), size, _BIG)]
+    blk = pl.BlockSpec((rows, _LANES), lambda i: (i, 0))
+    out = pl.BlockSpec((None, 1, kp), lambda i: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_select_ids_kernel, k=k),
+        grid=(nb,),
+        in_specs=[blk, blk, blk],
+        out_specs=[out, out, out],
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, kp), jnp.int32),
+                   jax.ShapeDtypeStruct((nb, 1, kp), jnp.float32),
+                   jax.ShapeDtypeStruct((nb, 1, kp), jnp.int32)],
+        interpret=interpret,
+    )(*[x.reshape(nb * rows, _LANES) for x in ins])
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def topk_merge(score: jax.Array, inter: jax.Array, gidx: jax.Array,
+               k: int, *, interpret: bool | None = None
+               ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Top-k over labelled (score, inter, gidx) entries: k rounds of (max
+    score, LOWEST global id among the maxes), ties to the lowest GLOBAL
+    index -- bit-identical to ``ref.topk_select_ids`` over the whole
+    vector.  Serves the single-device candidate vector (labels
+    ``arange(T)``) and labelled candidate subsets (global ids)."""
+    assert k >= 1
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    while True:
+        idx, sco, intr = _select_blocks(score, inter, gidx, k, interpret)
+        if idx.shape[0] == 1:
+            return idx[0, 0, :k], sco[0, 0, :k], intr[0, 0, :k]
+        gidx = idx[:, 0, :k].reshape(-1)
+        score = sco[:, 0, :k].reshape(-1)
+        inter = intr[:, 0, :k].reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnames=("metric", "k", "interpret"))
 def similarity_topk(rows: jax.Array, row_col: jax.Array, starts: jax.Array,
                     q_words: jax.Array, q_card: jax.Array, cards: jax.Array,
                     exclude: jax.Array = -1, *, metric: str, k: int,
-                    jmax: int, interpret: bool | None = None
+                    interpret: bool | None = None
                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused score + k-select over a device-resident candidate slab.
 
@@ -116,211 +228,72 @@ def similarity_topk(rows: jax.Array, row_col: jax.Array, starts: jax.Array,
     q_card:  scalar int32 query cardinality; cards: (T,) int32.
     exclude: runtime int32 candidate index scored -1 (-1: none).
     metric:  "jaccard" | "cosine" | "containment" (static).
-    k, jmax: static selection size / max rows per candidate.
+    k:       static selection size.
 
     Returns (idx (k,) int32, score (k,) float32, inter (k,) int32),
-    best-first, ties to the lowest index.  One dispatch end-to-end.
+    best-first, ties to the lowest index.  One jit end-to-end.
 
     Tie order is a PINNED contract: equal scores cut at the k boundary
     resolve to the lowest candidate index, and on the sharded path
-    (``similarity_topk_ids`` per shard + ``topk_merge`` over the
-    all-gathered k-lists) to the lowest GLOBAL candidate index -- so a
-    tie group straddling two shards merges in exactly the order this
-    single-device kernel (and the stable host argsort) would emit.
+    (``similarity_topk_ids`` with ``axis``) to the lowest GLOBAL
+    candidate index -- so a tie group whose rows sit on several shards
+    selects in exactly the order this single-device path (and the
+    stable host argsort) would emit.
     """
     assert metric in METRICS, metric
-    assert k >= 1 and jmax >= 1
+    assert k >= 1
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    n = rows.shape[0]
     t = starts.shape[0] - 1
-    starts = starts.astype(jnp.int32)
-    misc = jnp.stack([jnp.asarray(q_card, jnp.int32),
-                      jnp.asarray(exclude, jnp.int32)])
-
-    def row_index(ti, j, st, col, cd, ms):
-        return (jnp.minimum(st[ti] + j, n - 1), 0)
-
-    def q_index(ti, j, st, col, cd, ms):
-        return (col[jnp.minimum(st[ti] + j, n - 1)], 0)
-
-    score, inter = pl.pallas_call(
-        functools.partial(_score_kernel, metric=metric, jmax=jmax),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(t, jmax),
-            in_specs=[pl.BlockSpec((1, WORDS), row_index),
-                      pl.BlockSpec((1, WORDS), q_index)],
-            out_specs=[
-                pl.BlockSpec((1, 1), lambda ti, j, st, col, cd, ms: (ti, 0)),
-                pl.BlockSpec((1, 1), lambda ti, j, st, col, cd, ms: (ti, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((1, 1), jnp.int32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((t, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((t, 1), jnp.int32)],
-        interpret=interpret,
-    )(starts, row_col.astype(jnp.int32), cards.astype(jnp.int32), misc,
-      rows.astype(jnp.uint32), q_words.astype(jnp.uint32))
-
-    idx, sco, intr = pl.pallas_call(
-        functools.partial(_select_kernel, k=k),
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, t), lambda i: (0, 0)),
-                  pl.BlockSpec((1, t), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((1, k), lambda i: (0, 0)),
-                   pl.BlockSpec((1, k), lambda i: (0, 0)),
-                   pl.BlockSpec((1, k), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, k), jnp.int32),
-                   jax.ShapeDtypeStruct((1, k), jnp.float32),
-                   jax.ShapeDtypeStruct((1, k), jnp.int32)],
-        interpret=interpret,
-    )(score.reshape(1, t), inter.reshape(1, t))
-    return idx[0], sco[0], intr[0]
-
-
-# ---------------------------------------------------------------------------
-# sharded variants: one shard scores a candidate SUBSET labelled with
-# global ids, local k-lists all-gather, and a final ids-select merges.
-# Selection keys on (score desc, GLOBAL index asc) at every stage, so the
-# merged result is bit-identical to the single-device kernel above --
-# including tie groups that straddle shards (docs/ARCHITECTURE.md).
-# ---------------------------------------------------------------------------
-
-def _score_ids_kernel(starts_ref, col_ref, cards_ref, gidx_ref, misc_ref,
-                      row_ref, q_ref, score_ref, inter_ref, acc_ref, *,
-                      metric, jmax):
-    t = pl.program_id(0)
-    j = pl.program_id(1)
-    seg_len = starts_ref[t + 1] - starts_ref[t]
-    x = jnp.where(j < seg_len, row_ref[...] & q_ref[...], jnp.uint32(0))
-    pc = harley_seal_reduce(x.reshape(1, WORDS // 16, 16))[:, None]
-
-    @pl.when(j == 0)
-    def _():
-        acc_ref[...] = pc
-
-    @pl.when(j > 0)
-    def _():
-        acc_ref[...] = acc_ref[...] + pc
-
-    @pl.when(j == jmax - 1)
-    def _():
-        inter = acc_ref[0, 0]
-        s = similarity_scores(inter, misc_ref[0], cards_ref[t], metric)
-        # exclusion keys on the GLOBAL id; pad slots (>= n_valid) are
-        # forced to -2.0 LAST -- an all-zero pad row would otherwise
-        # score 1.0 under the zero-denominator convention
-        s = jnp.where(gidx_ref[t] == misc_ref[1], jnp.float32(-1.0), s)
-        s = jnp.where(t >= misc_ref[2], jnp.float32(-2.0), s)
-        score_ref[...] = s.reshape(1, 1)
-        inter_ref[...] = inter.reshape(1, 1)
-
-
-def _select_ids_kernel(score_ref, inter_ref, gidx_ref, idx_ref, sco_ref,
-                       int_ref, *, k):
-    """k rounds of (max, lowest GLOBAL id among the maxes): the pinned
-    shard-merge tie rule.  Entries of the winning id mask together, so
-    identical padding entries cannot occupy more than one round."""
-    s = score_ref[...]                           # (1, T)
-    g = gidx_ref[...]
-    big = jnp.int32(2**31 - 1)
-    for i in range(k):
-        m = jnp.max(s)
-        w = jnp.min(jnp.where(s == m, g, big))
-        hit = (g == w) & (s == m)
-        idx_ref[0, i] = w
-        sco_ref[0, i] = m
-        int_ref[0, i] = jnp.max(jnp.where(hit, inter_ref[...], 0))
-        s = jnp.where(hit, jnp.float32(-2.0), s)
+    inter = candidate_inter(
+        row_and_card(rows, row_col, q_words, interpret=interpret),
+        starts.astype(jnp.int32))
+    score = similarity_scores(inter, jnp.asarray(q_card, jnp.int32),
+                              cards, metric)
+    score = jnp.where(jnp.arange(t) == exclude, jnp.float32(-1.0), score)
+    return topk_merge(score, inter, jnp.arange(t, dtype=jnp.int32), k,
+                      interpret=interpret)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("metric", "k", "jmax", "interpret"))
+                   static_argnames=("metric", "k", "axis", "interpret"))
 def similarity_topk_ids(rows: jax.Array, row_col: jax.Array,
                         starts: jax.Array, q_words: jax.Array,
                         q_card: jax.Array, cards: jax.Array,
                         gidx: jax.Array, n_valid: jax.Array,
                         exclude: jax.Array = -1, *, metric: str, k: int,
-                        jmax: int, interpret: bool | None = None
+                        axis: str | None = None,
+                        interpret: bool | None = None
                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused score + k-select over ONE SHARD of a sharded candidate set.
+    """Fused score + k-select over a candidate SUBSET labelled with
+    global ids.
 
-    Layout matches :func:`similarity_topk` with three additions carried
+    Layout matches :func:`similarity_topk` with four additions carried
     by ``kernels.ref.similarity_topk_ids`` (the oracle): ``gidx`` (T,)
     int32 global candidate ids (selection/exclusion key on them),
     ``n_valid`` runtime scalar valid-slot count (pad slots score -2.0),
-    ``exclude`` a GLOBAL id (-1: none).  Returns (gidx (k,) int32,
-    score (k,) float32, inter (k,) int32), ties to the lowest GLOBAL
-    index -- the pinned shard-merge tie rule."""
+    ``exclude`` a GLOBAL id (-1: none), and ``axis``: inside
+    ``shard_map``, the mesh axis the candidates' rows are split over --
+    each shard passes only the rows it holds (``starts`` indexes them),
+    and the per-candidate partial intersections are summed across the
+    axis before scoring, so every shard selects from the same totals.
+    Returns (gidx (k,) int32, score (k,) float32, inter (k,) int32),
+    ties to the lowest GLOBAL index."""
     assert metric in METRICS, metric
-    assert k >= 1 and jmax >= 1
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    n = rows.shape[0]
-    t = starts.shape[0] - 1
-    starts = starts.astype(jnp.int32)
-    misc = jnp.stack([jnp.asarray(q_card, jnp.int32),
-                      jnp.asarray(exclude, jnp.int32),
-                      jnp.asarray(n_valid, jnp.int32)])
-
-    def row_index(ti, j, st, col, cd, gi, ms):
-        return (jnp.minimum(st[ti] + j, n - 1), 0)
-
-    def q_index(ti, j, st, col, cd, gi, ms):
-        return (col[jnp.minimum(st[ti] + j, n - 1)], 0)
-
-    score, inter = pl.pallas_call(
-        functools.partial(_score_ids_kernel, metric=metric, jmax=jmax),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(t, jmax),
-            in_specs=[pl.BlockSpec((1, WORDS), row_index),
-                      pl.BlockSpec((1, WORDS), q_index)],
-            out_specs=[
-                pl.BlockSpec((1, 1),
-                             lambda ti, j, st, col, cd, gi, ms: (ti, 0)),
-                pl.BlockSpec((1, 1),
-                             lambda ti, j, st, col, cd, gi, ms: (ti, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((1, 1), jnp.int32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((t, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((t, 1), jnp.int32)],
-        interpret=interpret,
-    )(starts, row_col.astype(jnp.int32), cards.astype(jnp.int32),
-      gidx.astype(jnp.int32), misc,
-      rows.astype(jnp.uint32), q_words.astype(jnp.uint32))
-    return topk_merge(score.reshape(-1), inter.reshape(-1), gidx, k,
-                      interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def topk_merge(score: jax.Array, inter: jax.Array, gidx: jax.Array,
-               k: int, *, interpret: bool | None = None
-               ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Global top-k merge of labelled k-lists: one select pass over the
-    all-gathered (S*k,) score/inter/gidx entries (k log k work, trivial
-    next to scoring).  Ties to the lowest GLOBAL index -- bit-identical
-    to selecting over the unsharded score vector."""
     assert k >= 1
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    m = score.shape[0]
-    idx, sco, intr = pl.pallas_call(
-        functools.partial(_select_ids_kernel, k=k),
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, m), lambda i: (0, 0)),
-                  pl.BlockSpec((1, m), lambda i: (0, 0)),
-                  pl.BlockSpec((1, m), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((1, k), lambda i: (0, 0)),
-                   pl.BlockSpec((1, k), lambda i: (0, 0)),
-                   pl.BlockSpec((1, k), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, k), jnp.int32),
-                   jax.ShapeDtypeStruct((1, k), jnp.float32),
-                   jax.ShapeDtypeStruct((1, k), jnp.int32)],
-        interpret=interpret,
-    )(score.reshape(1, m).astype(jnp.float32),
-      inter.reshape(1, m).astype(jnp.int32),
-      gidx.reshape(1, m).astype(jnp.int32))
-    return idx[0], sco[0], intr[0]
+    t = starts.shape[0] - 1
+    inter = candidate_inter(
+        row_and_card(rows, row_col, q_words, interpret=interpret),
+        starts.astype(jnp.int32))
+    if axis is not None:
+        inter = jax.lax.psum(inter, axis)
+    score = similarity_scores(inter, jnp.asarray(q_card, jnp.int32),
+                              cards, metric)
+    # exclusion keys on the GLOBAL id; pad slots (>= n_valid) are forced
+    # to -2.0 LAST -- an all-zero pad row would otherwise score 1.0 under
+    # the zero-denominator convention
+    score = jnp.where(gidx == exclude, jnp.float32(-1.0), score)
+    score = jnp.where(jnp.arange(t) >= n_valid, jnp.float32(-2.0), score)
+    return topk_merge(score, inter, gidx, k, interpret=interpret)

@@ -7,6 +7,14 @@ state (the dry-run must set XLA_FLAGS before any device query).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_axes(n: int) -> tuple:
+    """``Auto`` axis types: sharding constraints stay hints to the
+    partitioner (``jax.make_mesh`` defaults to ``Explicit`` axes, under
+    which ``dist.ctx.constrain`` would become a type assertion)."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,14 +22,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (2, 16, 16) = ('pod', 'data', 'model') -- 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=auto_axes(len(axes)))
 
 
 def make_local_mesh(model_parallel: int = 1):
     """Whatever this host has (tests / examples)."""
     n = len(jax.devices())
     mp = min(model_parallel, n)
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return jax.make_mesh((n // mp, mp), ("data", "model"),
+                         axis_types=auto_axes(2))
 
 
 def make_wide_mesh(n: int | None = None):
@@ -36,7 +45,8 @@ def make_wide_mesh(n: int | None = None):
     devs = jax.devices()
     n = len(devs) if n is None else min(n, len(devs))
     return jax.sharding.Mesh(
-        mesh_utils.create_device_mesh((n,), devices=devs[:n]), ("wide",))
+        mesh_utils.create_device_mesh((n,), devices=devs[:n]), ("wide",),
+        axis_types=auto_axes(1))
 
 
 # Hardware constants for the roofline analysis (TPU v5e-class chip).

@@ -159,11 +159,11 @@ class QueryServer:
     only edited rows) instead of dropping the cached slab
     (docs/ARCHITECTURE.md section 6, docs/MEMORY.md); ``mesh`` a 1-D
     ``("wide",)`` mesh -- similarity tickets then coalesce against the
-    SHARDED engine (per-shard arena slabs, k-list all-gather, device
-    merge) and coalesced BOOLEAN plans dispatch against the shard-local
-    arena slabs too (``aggregate._shard_reduce_arena``: resident rows
-    gather from each shard's slab inside one jit, partials fold on
-    device), with the same recovery ladder: ``slab_mismatch``
+    SHARDED engine (per-shard arena slabs: each shard counts the rows it
+    holds, counts sum across shards, device select) and coalesced
+    BOOLEAN plans dispatch against the shard-local arena slabs too
+    (``aggregate._shard_reduce_arena``: each row reduces on the shard
+    that holds it inside one jit, partials fold on device), with the same recovery ladder: ``slab_mismatch``
     revalidates per shard through the arena (only shards owning dirty
     rows repatch), and the terminal host fallback stays the unsharded,
     jax-free host sweep."""
@@ -384,13 +384,17 @@ class QueryServer:
         """Dispatch ``tickets`` with the full recovery ladder: retry
         with backoff on transient failure, split on allocator pressure,
         degrade to the host planner when the kernel keeps failing.
-        Postcondition: every ticket has ``_value`` or ``_error`` set."""
+        Postcondition: every ticket has ``_value`` or ``_error`` set.
+        A degraded batch leaves its first dispatch exception in
+        ``ServerStats.fallback_cause``."""
         attempt = 0
+        first: Exception | None = None
         while True:
             try:
                 self._kernel_batch(tickets)
                 return
-            except AllocPressure:
+            except AllocPressure as e:
+                first = first or e
                 self._stats.batch_splits += 1
                 for t in tickets:
                     t.telemetry.splits += 1
@@ -400,7 +404,8 @@ class QueryServer:
                     self._execute(tickets[mid:])
                     return
                 break                             # 1 ticket: degrade
-            except Exception:                     # noqa: BLE001
+            except Exception as e:                # noqa: BLE001
+                first = first or e
                 attempt += 1
                 if attempt > self.max_retries:
                     break                         # degrade
@@ -408,6 +413,7 @@ class QueryServer:
                 for t in tickets:
                     t.telemetry.retries += 1
                 self._clock.sleep(self.backoff_s * 2 ** (attempt - 1))
+        self._stats.fallback_cause = f"{type(first).__name__}: {first}"
         self._host_batch(tickets)
 
     def _host_batch(self, tickets: list[Ticket]) -> None:

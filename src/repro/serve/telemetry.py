@@ -66,6 +66,9 @@ class ServerStats:
     rows_repatched: int = 0     # arena rows repatched by replan rungs
     host_fallbacks: int = 0
     max_batch: int = 0
+    # "Type: message" of the exception that started the latest degraded
+    # batch's failures: the compiler's or runtime's own words
+    fallback_cause: str = ""
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)

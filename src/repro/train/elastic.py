@@ -16,6 +16,8 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro.launch.mesh import auto_axes
+
 
 @dataclasses.dataclass(frozen=True)
 class MeshPlan:
@@ -47,6 +49,7 @@ def plan_mesh(available_chips: int, model_parallel: int = 16,
 def make_mesh_from_plan(plan: MeshPlan, devices=None):
     devices = devices if devices is not None else jax.devices()
     return jax.make_mesh(plan.shape, plan.axis_names,
+                         axis_types=auto_axes(len(plan.axis_names)),
                          devices=devices[:plan.used_chips])
 
 

@@ -14,10 +14,9 @@ mutation-query interleave, batched parity, ``InvertedIndex.similar``
 wiring, and the query server's ``slab_mismatch`` recovery rung against
 a sharded engine.
 
-In-process tests cover the new per-shard kernel primitives
-(``similarity_topk_ids`` / ``topk_merge``) on the ref and Pallas
-interpret backends, the 1-device mesh fallback, and the arena
-requirement.
+In-process tests cover the kernel primitives (``similarity_topk_ids`` /
+``topk_merge``) on the ref and Pallas interpret backends, the 1-device
+mesh fallback, and the arena requirement.
 """
 
 import os
@@ -79,7 +78,7 @@ for metric in ("jaccard", "cosine", "containment"):
                   eng.topk(query, k, metric), (metric, k))
 
 # 2. tie group straddling shards: identical posting lists at consecutive
-# global indices (homes t % S cycle through every shard) and k cutting
+# global indices (their rows cycle through every shard) and k cutting
 # inside the group -- the winners must be the LOWEST global indices, in
 # ascending order, on both paths
 tie_vals = rng.choice(300_000, size=500, replace=False)
@@ -94,6 +93,19 @@ for k in (2, S, 2 * S):                           # cuts inside the group
     check(want, got, ("tie", k))
     assert got[0].tolist() == list(range(k))      # lowest global indices
     assert np.all(got[1][:k] == got[1][0])        # one tie group
+
+# 2b. no row leaves its shard: the compiled dispatch exchanges only the
+# per-candidate counts (an all-reduce), never slab rows (no all-gather)
+from repro.core.pairwise import _sharded_topk
+tshards = tarena.shard_slabs(mesh)
+plan = teng._plan_sharded(np.arange(len(tied)), tshards)
+gidx_p, cards_p, starts_p, lpos_p, col_p = plan
+assert (starts_p[:, -1] > 0).sum() > 1            # rows on several shards
+hlo = _sharded_topk(mesh, "wide", "jaccard", 3, None).lower(
+    tshards.assembled(), lpos_p, col_p, starts_p,
+    teng._query_block(teng._query_words(0)), np.int32(500), cards_p,
+    gidx_p, np.int32(len(tied)), np.int32(-1)).compile().as_text()
+assert "all-gather" not in hlo and "all-reduce" in hlo
 
 # 3. warm re-queries move ZERO container rows host->device
 shards = arena.shard_slabs(mesh)
@@ -220,22 +232,22 @@ def test_similarity_topk_ids_ref_pallas_parity(metric, rng):
             for be in ("ref", "pallas"):
                 out[be] = kops.similarity_topk_ids(
                     rows, col, starts, q, qc, cards, gidx, metric=metric,
-                    k=3, jmax=4, n_valid=n_valid, exclude=exclude,
+                    k=3, n_valid=n_valid, exclude=exclude,
                     backend=be)
             for a, b in zip(out["ref"], out["pallas"]):
                 assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_topk_merge_tie_rule():
-    """Merged k-lists resolve equal scores to the LOWEST global index --
-    the pinned shard-boundary contract (both backends)."""
+    """Labelled selection resolves equal scores to the LOWEST global
+    index -- the pinned contract (Pallas kernel and jnp oracle)."""
     import jax.numpy as jnp
-    from repro.kernels import ops as kops
+    from repro.kernels import ref, topk_ops
     score = jnp.asarray(np.array([.5, .9, .9, .1, .9, .5], np.float32))
     inter = jnp.asarray(np.array([5, 9, 9, 1, 9, 5], np.int32))
     gidx = jnp.asarray(np.array([40, 31, 7, 2, 19, 3], np.int32))
-    for be in ("ref", "pallas"):
-        idx, sco, itr = kops.topk_merge(score, inter, gidx, 4, backend=be)
+    for merge in (topk_ops.topk_merge, ref.topk_select_ids):
+        idx, sco, itr = merge(score, inter, gidx, 4)
         assert np.asarray(idx).tolist() == [7, 19, 31, 3]
         assert np.array_equal(np.asarray(sco),
                               np.array([.9, .9, .9, .5], np.float32))

@@ -9,6 +9,7 @@ import pytest
 from repro.core import aggregate
 from repro.core.bitmap import RoaringBitmap
 from repro.dist import ctx
+from repro.launch.mesh import make_local_mesh
 
 
 class FakeMesh:
@@ -46,7 +47,7 @@ def test_attn_head_plan_off_mesh_is_dp():
 # ---------------------------------------------------------------------------
 
 def test_activate_sets_and_restores():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     with ctx.activate(mesh):
         assert ctx.current_mesh() is mesh
         assert ctx.axis_sizes() == {"data": 1, "model": 1}
@@ -57,7 +58,7 @@ def test_activate_sets_and_restores():
 
 
 def test_constrain_under_jit_traces():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
 
     def f(x):
         return ctx.constrain(x, {0: ctx.dp_axes(), 1: "model"}) * 2
@@ -91,7 +92,7 @@ def test_constrain_pure_dp_no_duplicate_axes():
     # under pure-dp, dp_axes() includes "model"; a call constraining both
     # the batch dim and an explicit "model" dim (models/mlp.py MoE path)
     # must dedupe instead of building an invalid duplicate-axis spec
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     ctx.set_pure_dp(True)
     with ctx.activate(mesh):
         assert ctx.dp_axes() == ("data", "model")

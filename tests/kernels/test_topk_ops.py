@@ -48,12 +48,10 @@ def test_kernel_matches_oracle(rng, metric):
     for trial in range(3):
         t, c = 12 + trial * 5, 4
         rows, row_col, starts, q, q_card, cards = random_case(rng, t, c)
-        jmax = max(1, int(np.diff(np.asarray(starts)).max()))
         for exclude in (-1, 3):
             ki, ks, kn = topk_ops.similarity_topk(
                 rows, row_col, starts, q, jnp.int32(q_card), cards,
-                jnp.int32(exclude), metric=metric, k=5, jmax=jmax,
-                interpret=True)
+                jnp.int32(exclude), metric=metric, k=5, interpret=True)
             oi, os_, on = ref.similarity_topk(
                 rows, row_col, starts, q, jnp.int32(q_card), cards,
                 jnp.int32(exclude), metric=metric, k=5)
@@ -102,7 +100,7 @@ def test_empty_segments_score_zero(rng):
     q_card = int(np.bitwise_count(np.asarray(q)).sum())
     ki, ks, kn = topk_ops.similarity_topk(
         rows, row_col, starts, q, jnp.int32(q_card), cards,
-        jnp.int32(-1), metric="jaccard", k=3, jmax=4, interpret=True)
+        jnp.int32(-1), metric="jaccard", k=3, interpret=True)
     oi, os_, on = ref.similarity_topk(
         rows, row_col, starts, q, jnp.int32(q_card), cards,
         jnp.int32(-1), metric="jaccard", k=3)

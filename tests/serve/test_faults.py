@@ -157,6 +157,25 @@ def test_alloc_pressure_always_falls_back_to_host(index):
     assert srv.stats().host_fallbacks == 4    # each singleton degraded
 
 
+@pytest.mark.parametrize("site,cause", [
+    ("dispatch_raise", "DispatchFault: injected dispatch failure"),
+    ("alloc_pressure", "AllocPressure: batch of 1 refused"),
+])
+def test_degraded_batch_records_first_exception(index, site, cause):
+    """A degraded batch keeps the exception that started its failures,
+    so an operator sees why the device path was left; a healthy server
+    records none."""
+    srv, _ = make_server(index, {site: "always"})
+    srv.submit(Query.or_("t1"))
+    srv.run_until_idle()
+    assert srv.stats().host_fallbacks == 1
+    assert srv.stats().fallback_cause == cause
+    ok, _ = make_server(index)
+    ok.submit(Query.or_("t1"))
+    ok.run_until_idle()
+    assert ok.stats().fallback_cause == ""
+
+
 # -------------------------------------------------------- slab mismatch
 def test_slab_mismatch_replans_and_succeeds(index):
     srv, clock = make_server(index, {"slab_mismatch": [True]})

@@ -30,13 +30,8 @@ def test_sparse_allreduce_under_shard_map(rng):
         red, res = GC.sparse_allreduce(gl, "dp", k=64)
         return red, res
 
-    if hasattr(jax, "shard_map"):
-        smap = jax.shard_map(f, mesh=mesh, in_specs=P(),
-                             out_specs=(P(), P()), check_vma=False)
-    else:  # jax < 0.6 ships it under experimental with check_rep
-        from jax.experimental.shard_map import shard_map
-        smap = shard_map(f, mesh=mesh, in_specs=P(),
-                         out_specs=(P(), P()), check_rep=False)
+    smap = jax.shard_map(f, mesh=mesh, in_specs=P(),
+                         out_specs=(P(), P()), check_vma=False)
     red, res = jax.jit(smap)(g)
     # single replica: reduction == top-64 of g, residual == the rest
     np.testing.assert_allclose(np.asarray(red + res), np.asarray(g),
