@@ -54,6 +54,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import containers as C
 from repro.core.containers import (
@@ -658,6 +659,12 @@ class SimilarityEngine:
     edit then costs one :meth:`refresh` -- the arena repatches only the
     changed rows (one scatter) and the engine re-gathers, instead of
     re-promoting and re-uploading the whole candidate set.
+
+    ``dispatches`` counts the device top-k dispatches the engine has
+    issued.  A query's device work is three trace spans:
+    ``engine.query_block`` (its query block built on the device),
+    ``engine.dispatch`` (the top-k call enqueued) and ``engine.fetch``
+    (the host waiting for the answer).
     """
 
     def __init__(self, bitmaps, *, arena=None, mesh=None):
@@ -671,6 +678,7 @@ class SimilarityEngine:
         1-device mesh degrades to the single-device engine."""
         self._bitmaps = list(bitmaps)
         self._arena = arena
+        self.dispatches = 0
         self._mesh = None
         self._nshards = 1
         self._shard_axis = None
@@ -897,17 +905,26 @@ class SimilarityEngine:
                                       backend)
         if backend != "host" and _prefer_kernel(backend):
             dev_rows, dev_col, dev_starts, dev_cards = self._device()
-            idx, score, inter = kops.similarity_topk(
-                dev_rows, dev_col, dev_starts,
-                self._query_words_dev(query),
-                qc, dev_cards, metric=metric, k=k,
-                exclude=-1 if exclude is None else exclude,
-                backend=backend)
-            return (np.asarray(idx).astype(np.int64),
-                    np.asarray(score),
-                    np.asarray(inter).astype(np.int64))
+            with TraceAnnotation("engine.query_block"):
+                q_words = self._query_words_dev(query)
+            with TraceAnnotation("engine.dispatch"):
+                res = kops.similarity_topk(
+                    dev_rows, dev_col, dev_starts, q_words, qc, dev_cards,
+                    metric=metric, k=k,
+                    exclude=-1 if exclude is None else exclude,
+                    backend=backend)
+                self.dispatches += 1
+            return self._fetch(res)
         return self._topk_host(self._query_words(query), qc, k, metric,
                                exclude)
+
+    @staticmethod
+    def _fetch(res):
+        """Bring one dispatch's (idx, score, inter) to the host."""
+        with TraceAnnotation("engine.fetch"):
+            idx, score, inter = res
+            return (np.asarray(idx).astype(np.int64), np.asarray(score),
+                    np.asarray(inter).astype(np.int64))
 
     # -- sharded path (per-shard arena slabs, shard-local row gathers) --
 
@@ -998,14 +1015,17 @@ class SimilarityEngine:
         for st in shards.stats:
             st.device_gathers += 1
         fn = _sharded_topk(self._mesh, self._shard_axis, metric, k, backend)
-        idx, score, inter = fn(
-            shards.assembled(), jnp.asarray(lpos), jnp.asarray(col),
-            jnp.asarray(starts), self._query_block(q64),
-            jnp.asarray(np.int32(qc)), jnp.asarray(cards),
-            jnp.asarray(gidx), jnp.asarray(np.int32(surv.size)),
-            jnp.asarray(np.int32(-1 if exclude is None else exclude)))
-        return (np.asarray(idx).astype(np.int64), np.asarray(score),
-                np.asarray(inter).astype(np.int64))
+        with TraceAnnotation("engine.query_block"):
+            q_words = self._query_block(q64)
+        with TraceAnnotation("engine.dispatch"):
+            res = fn(
+                shards.assembled(), jnp.asarray(lpos), jnp.asarray(col),
+                jnp.asarray(starts), q_words,
+                jnp.asarray(np.int32(qc)), jnp.asarray(cards),
+                jnp.asarray(gidx), jnp.asarray(np.int32(surv.size)),
+                jnp.asarray(np.int32(-1 if exclude is None else exclude)))
+            self.dispatches += 1
+        return self._fetch(res)
 
     def topk_batch(self, queries, k: int, metric: str = "jaccard", *,
                    backend: str | None = None) -> list:
@@ -1014,8 +1034,10 @@ class SimilarityEngine:
 
         On the jnp-oracle kernel backend every query sharing an effective
         ``k`` lowers to ONE vmapped score+select dispatch over the cached
-        slab; the Pallas kernel and the pruned host sweep fall back to a
-        per-query loop that still shares every cached structure.  Returns
+        slab.  The Pallas kernel (the default on a TPU) and the sharded
+        path dispatch once per query, and the pruned host sweep (the
+        default on a CPU) dispatches nothing: a per-query loop that still
+        shares every cached structure.  ``dispatches`` shows which.  Returns
         ``[self.topk(q, k, metric) for q in queries]`` bit for bit on
         every path (asserted by the test suite)."""
         queries = list(queries)
@@ -1055,14 +1077,16 @@ class SimilarityEngine:
                         "query cardinality >= 2^31 unsupported")
                 q_card.append(qc)
                 excl.append(ex)
-            idx, score, inter = _batched_topk(metric, kk)(
-                dev_rows, dev_col, dev_starts,
-                self._query_words_dev_batch([queries[i] for i in idxs]),
-                jnp.asarray(q_card, jnp.int32), dev_cards,
-                jnp.asarray(excl, jnp.int32))
-            idx = np.asarray(idx).astype(np.int64)
-            score = np.asarray(score)
-            inter = np.asarray(inter).astype(np.int64)
+            with TraceAnnotation("engine.query_block"):
+                q_words = self._query_words_dev_batch(
+                    [queries[i] for i in idxs])
+            with TraceAnnotation("engine.dispatch"):
+                res = _batched_topk(metric, kk)(
+                    dev_rows, dev_col, dev_starts, q_words,
+                    jnp.asarray(q_card, jnp.int32), dev_cards,
+                    jnp.asarray(excl, jnp.int32))
+                self.dispatches += 1
+            idx, score, inter = self._fetch(res)
             for j, i in enumerate(idxs):
                 out[i] = (idx[j], score[j], inter[j])
         return out

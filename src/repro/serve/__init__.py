@@ -3,8 +3,8 @@
 ``query_server`` is the fault-tolerant continuous batcher (coalesced
 multi-query dispatch, admission control, deadlines, kernel->host
 degradation); ``faults`` its deterministic fault-injection harness;
-``telemetry`` the per-ticket/server observability records plus the MoE
-routing telemetry.
+``telemetry`` the per-ticket/server observability records and the
+trace spans of the server's phases.
 """
 
 from repro.serve.faults import (AllocPressure, DispatchFault, FakeClock,

@@ -7,8 +7,16 @@ batcher: callers ``submit`` queries and get tickets back immediately,
 and each engine tick coalesces EVERYTHING queued into one multi-query
 slab dispatch per op class (``core.aggregate.execute_plans`` -- a query
 id is just another segment coordinate of the segmented-reduce kernel)
-plus one vmapped score+select dispatch per (k, metric) similarity class
-(``SimilarityEngine.topk_batch`` over the cached candidate slab).
+plus one ``SimilarityEngine.topk_batch`` call per (k, metric) similarity
+class over the cached candidate slab: one vmapped score+select dispatch
+on the jnp backend, one dispatch per query on the Pallas and sharded
+paths, none on the host sweep (``ServerStats.sim_dispatches`` counts
+them).
+
+Observability: each tick is a ``serve.tick`` trace span, and the
+similarity path's phases are spans whose time also adds up in
+``ServerStats`` (``telemetry.span``): ``serve.revalidate``,
+``serve.lookup``, ``serve.score`` and ``serve.resolve``.
 
 Robustness contract (the point of the module):
 
@@ -45,7 +53,7 @@ from repro.core import aggregate
 from repro.kernels.ref import METRICS
 from repro.serve.faults import (AllocPressure, DispatchFault,
                                 FaultInjector, SystemClock)
-from repro.serve.telemetry import QueryTelemetry, ServerStats
+from repro.serve.telemetry import QueryTelemetry, ServerStats, span
 
 __all__ = ["Query", "Ticket", "TicketResult", "QueryServer",
            "OK", "OVERLOADED", "INVALID", "DEADLINE", "ERROR"]
@@ -249,6 +257,10 @@ class QueryServer:
     def stats(self) -> ServerStats:
         return dataclasses.replace(self._stats)
 
+    def _phase(self, name: str, field: str):
+        """A span of this server's work, timed into ``ServerStats``."""
+        return span(name, self._stats, field, self._clock)
+
     # -- the engine tick -------------------------------------------------
 
     def step(self) -> int:
@@ -257,6 +269,10 @@ class QueryServer:
         dispatch per op class, resolve every ticket taken.  Returns the
         number of tickets resolved.  Never raises: unexpected failures
         resolve their tickets with status ``ERROR``."""
+        with span("serve.tick"):
+            return self._step()
+
+    def _step(self) -> int:
         self._stats.ticks += 1
         if not self._queue:
             return 0
@@ -289,15 +305,16 @@ class QueryServer:
         if self._faults.fire("slab_mismatch"):
             self._replan(live)
         self._execute(live)
-        for t in live:
-            if t._error is not None:
-                self._resolve(t, ERROR, error=t._error)
-            elif t.deadline is not None and \
-                    self._clock.now() > t.deadline:
-                self._resolve(t, DEADLINE,
-                              error="deadline overrun at dispatch")
-            else:
-                self._resolve(t, OK, value=t._value)
+        with self._phase("serve.resolve", "resolve_s"):
+            for t in live:
+                if t._error is not None:
+                    self._resolve(t, ERROR, error=t._error)
+                elif t.deadline is not None and \
+                        self._clock.now() > t.deadline:
+                    self._resolve(t, DEADLINE,
+                                  error="deadline overrun at dispatch")
+                else:
+                    self._resolve(t, OK, value=t._value)
         return len(batch)
 
     def run_until_idle(self, max_ticks: int = 1_000_000) -> int:
@@ -360,19 +377,25 @@ class QueryServer:
             for t, bm in zip(booleans, out):
                 t._value = bm
         if sims:
-            terms, eng = self.index._sim_engine(mesh=self.mesh)
+            with self._phase("serve.revalidate", "revalidate_s"):
+                terms, eng = self.index._sim_engine(mesh=self.mesh)
             by_class: dict[tuple, list[Ticket]] = {}
             for t in sims:
                 by_class.setdefault((t.query.k, t.query.metric),
                                     []).append(t)
             for (k, metric), group in by_class.items():
-                queries = [self._sim_query(t, terms) for t in group]
-                res = eng.topk_batch(queries, k, metric,
-                                     backend=self.backend)
-                for t, (idx, score, _) in zip(group, res):
-                    t._value = [(terms[i], float(s))
-                                for i, s in zip(idx.tolist(),
-                                                score.tolist())]
+                with self._phase("serve.lookup", "lookup_s"):
+                    queries = [self._sim_query(t, terms) for t in group]
+                sent = eng.dispatches
+                with self._phase("serve.score", "score_s"):
+                    res = eng.topk_batch(queries, k, metric,
+                                         backend=self.backend)
+                self._stats.sim_dispatches += eng.dispatches - sent
+                with self._phase("serve.resolve", "resolve_s"):
+                    for t, (idx, score, _) in zip(group, res):
+                        t._value = [(terms[i], float(s))
+                                    for i, s in zip(idx.tolist(),
+                                                    score.tolist())]
 
     def _sim_query(self, t: Ticket, terms: list):
         term = t.query.terms[0]

@@ -1,25 +1,23 @@
-"""Serving telemetry: per-ticket query timings for the continuous query
-server, plus MoE routing telemetry on Roaring sets (paper section 5.9
-fast counts).
+"""Serving telemetry for the continuous query server.
 
-Query-server side: every resolved ticket carries a ``QueryTelemetry``
-(queue time, dispatch latency, retries, degradation flags) and the
-server aggregates a running ``ServerStats`` -- the observability
-contract the fault-injection tests assert against.
+Every resolved ticket carries a ``QueryTelemetry`` (queue time, dispatch
+latency, retries, degradation flags) and the server aggregates a running
+``ServerStats`` -- the observability contract the fault-injection tests
+assert against.
 
-MoE side: per training/serving step, each expert's routed-token-id set
-is a Roaring bitmap; load balance, expert overlap (Jaccard), and drift
-between steps (symmetric difference) are the paper's count-only
-operations -- computed without materializing intermediate sets.
+``span`` marks a phase of the server's work as a ``jax.profiler``
+trace annotation, on the same clock as the device's ops in a profiler
+trace, and adds the phase's time on the server's own clock to a
+``ServerStats`` field.  With no profiler running an annotation costs
+about a microsecond; there is nothing to turn on or off.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
-import numpy as np
-
-from repro.core import RoaringBitmap
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -69,51 +67,29 @@ class ServerStats:
     # "Type: message" of the exception that started the latest degraded
     # batch's failures: the compiler's or runtime's own words
     fallback_cause: str = ""
+    # seconds of the similarity path's phases (``span``), each a span
+    revalidate_s: float = 0.0   # serve.revalidate: the index's engine check
+    lookup_s: float = 0.0       # serve.lookup: terms to candidate indices
+    score_s: float = 0.0        # serve.score: topk_batch, answers fetched
+    resolve_s: float = 0.0      # serve.resolve: answers to tickets
+    sim_dispatches: int = 0     # device top-k dispatches of the engine
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
 
 
-def routing_sets(expert_idx: np.ndarray, n_experts: int) -> list[RoaringBitmap]:
-    """expert_idx: (tokens, top_k) int -> per-expert token-id bitmaps."""
-    flat_tok = np.repeat(np.arange(expert_idx.shape[0], dtype=np.uint32),
-                         expert_idx.shape[1])
-    flat_e = expert_idx.reshape(-1)
-    out = []
-    for e in range(n_experts):
-        out.append(RoaringBitmap.from_values(flat_tok[flat_e == e]))
-    return out
-
-
-def load_balance_stats(sets: list[RoaringBitmap]) -> dict:
-    loads = np.array([bm.cardinality for bm in sets], np.float64)
-    total = loads.sum()
-    frac = loads / max(total, 1)
-    e = len(sets)
-    return {
-        "max_load_fraction": float(frac.max()),
-        "cv": float(loads.std() / max(loads.mean(), 1e-9)),
-        "entropy_ratio": float(
-            -(frac[frac > 0] * np.log(frac[frac > 0])).sum() / np.log(e)),
-    }
-
-
-def expert_overlap_matrix(sets: list[RoaringBitmap]) -> np.ndarray:
-    """Pairwise Jaccard between experts' token sets (fast counts)."""
-    e = len(sets)
-    out = np.zeros((e, e))
-    for i in range(e):
-        for j in range(i, e):
-            out[i, j] = out[j, i] = sets[i].jaccard(sets[j])
-    return out
-
-
-def routing_drift(prev: list[RoaringBitmap],
-                  cur: list[RoaringBitmap]) -> np.ndarray:
-    """Per-expert symmetric-difference cardinality between steps,
-    normalized by union -- 0 = stable routing, 1 = fully churned."""
-    out = np.zeros(len(cur))
-    for i, (a, b) in enumerate(zip(prev, cur)):
-        union = a.or_card(b)
-        out[i] = a.xor_card(b) / union if union else 0.0
-    return out
+@contextlib.contextmanager
+def span(name: str, stats: ServerStats | None = None,
+         field: str | None = None, clock=None):
+    """A ``TraceAnnotation`` named ``name`` around the block; with
+    ``stats``, the block's time on ``clock`` (an object with ``now()``)
+    is added to ``stats.<field>``, also when the block raises."""
+    with TraceAnnotation(name):
+        if stats is None:
+            yield
+            return
+        t0 = clock.now()
+        try:
+            yield
+        finally:
+            setattr(stats, field, getattr(stats, field) + clock.now() - t0)

@@ -1,5 +1,4 @@
-"""Serving layer: paged KV allocator, constrained decoding, engine,
-telemetry."""
+"""Serving layer: paged KV allocator, constrained decoding, engine."""
 
 import dataclasses
 
@@ -13,7 +12,6 @@ from repro.models import transformer as T
 from repro.serve.constrained import VocabConstraint, lexicon_constraint
 from repro.serve.engine import BlockPolicy, Engine
 from repro.serve.kv_cache import PagedKVAllocator
-from repro.serve import telemetry
 
 
 # ---------------------------------------------------------------- kv cache
@@ -131,17 +129,3 @@ def test_block_policy_sets():
     vis = pol.visible_set(kv_len=128 * 20, block_size=128)
     got = set(vis.to_array().tolist())
     assert got == {0, 1, 10, 17, 18, 19}
-
-
-# --------------------------------------------------------------- telemetry
-def test_routing_telemetry(rng):
-    idx = rng.integers(0, 4, (128, 2))
-    sets = telemetry.routing_sets(idx, 4)
-    assert sum(s.cardinality for s in sets) == idx.size - sum(
-        1 for r in idx if r[0] == r[1])  # same expert twice collapses
-    stats = telemetry.load_balance_stats(sets)
-    assert 0 < stats["max_load_fraction"] <= 1
-    j = telemetry.expert_overlap_matrix(sets)
-    assert np.allclose(np.diag(j), 1.0)
-    drift = telemetry.routing_drift(sets, sets)
-    assert np.allclose(drift, 0.0)
