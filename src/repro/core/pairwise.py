@@ -661,10 +661,13 @@ class SimilarityEngine:
     re-promoting and re-uploading the whole candidate set.
 
     ``dispatches`` counts the device top-k dispatches the engine has
-    issued.  A query's device work is three trace spans:
-    ``engine.query_block`` (its query block built on the device),
-    ``engine.dispatch`` (the top-k call enqueued) and ``engine.fetch``
-    (the host waiting for the answer).
+    issued; ``segment_map_builds`` counts the layouts built (construction
+    and each :meth:`refresh` that changed something), each with its
+    row-to-candidate map ``seg``, which every dispatch then reads.  A
+    query's device work is three trace spans: ``engine.query_block``
+    (its query block built on the device), ``engine.dispatch`` (the top-k
+    call enqueued) and ``engine.fetch`` (the host waiting for the
+    answer).
     """
 
     def __init__(self, bitmaps, *, arena=None, mesh=None):
@@ -679,6 +682,7 @@ class SimilarityEngine:
         self._bitmaps = list(bitmaps)
         self._arena = arena
         self.dispatches = 0
+        self.segment_map_builds = 0
         self._mesh = None
         self._nshards = 1
         self._shard_axis = None
@@ -730,6 +734,10 @@ class SimilarityEngine:
             self._snap = None
         self.row_col = np.asarray(row_col, np.int32)
         self.starts = starts
+        # row r belongs to candidate seg[r]: fixed until the next _build
+        self.seg = np.repeat(np.arange(self.n, dtype=np.int32),
+                             np.diff(starts))
+        self.segment_map_builds += 1
         self._dev = None                         # lazy device upload
 
     def refresh(self) -> bool:
@@ -775,7 +783,7 @@ class SimilarityEngine:
         a member query gathers its rows from the resident slab (nothing
         crosses the host bridge); a bitmap query ships only its occupied
         rows and scatters them into place on device."""
-        dev_rows, dev_col, _, _ = self._device()
+        dev_rows, dev_col = self._device()[:2]
         nc = max(self.n_keys, 1)
         zeros = jnp.zeros((nc, WORDS), jnp.uint32)
         if isinstance(query, (int, np.integer)):
@@ -801,7 +809,7 @@ class SimilarityEngine:
         resident slab, one shipping bitmap queries' occupied rows) --
         the per-query ``_query_words_dev`` loop costs one jit dispatch
         per query, which dominates coalesced similarity batches."""
-        dev_rows, dev_col, _, _ = self._device()
+        dev_rows, dev_col = self._device()[:2]
         nc = max(self.n_keys, 1)
         block = jnp.zeros((len(queries), nc, WORDS), jnp.uint32)
         mem_b, mem_r = [], []            # member queries: slab row ids
@@ -849,6 +857,9 @@ class SimilarityEngine:
                             np.zeros(1, np.int32)),
                 jnp.asarray(self.starts),
                 jnp.asarray(self.cards.astype(np.int32)),
+                # the one padding row of an empty slab maps to T: dropped
+                jnp.asarray(self.seg if self.seg.size else
+                            np.full(1, self.n, np.int32)),
             )
         return self._dev
 
@@ -904,7 +915,8 @@ class SimilarityEngine:
             return self._topk_sharded(query, qc, k, metric, exclude,
                                       backend)
         if backend != "host" and _prefer_kernel(backend):
-            dev_rows, dev_col, dev_starts, dev_cards = self._device()
+            dev_rows, dev_col, dev_starts, dev_cards, dev_seg = \
+                self._device()
             with TraceAnnotation("engine.query_block"):
                 q_words = self._query_words_dev(query)
             with TraceAnnotation("engine.dispatch"):
@@ -912,7 +924,7 @@ class SimilarityEngine:
                     dev_rows, dev_col, dev_starts, q_words, qc, dev_cards,
                     metric=metric, k=k,
                     exclude=-1 if exclude is None else exclude,
-                    backend=backend)
+                    seg=dev_seg, backend=backend)
                 self.dispatches += 1
             return self._fetch(res)
         return self._topk_host(self._query_words(query), qc, k, metric,
@@ -1061,7 +1073,7 @@ class SimilarityEngine:
             else:
                 batch.setdefault(kk, []).append(i)
         for kk, idxs in batch.items():
-            dev_rows, dev_col, dev_starts, dev_cards = self._device()
+            dev_rows, dev_col, dev_starts, dev_cards, _ = self._device()
             q_card, excl = [], []
             for i in idxs:
                 q = queries[i]

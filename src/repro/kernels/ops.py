@@ -152,17 +152,19 @@ _ref_bitset_pair_card = jax.jit(ref.bitset_pair_card)
 
 
 def similarity_topk(rows, row_col, starts, q_words, q_card, cards, *,
-                    metric: str, k: int, exclude=-1,
+                    metric: str, k: int, exclude=-1, seg=None,
                     backend: Backend | None = None):
     """Fused similarity top-k: score a query against T device-resident
     candidates and select the best k in ONE jit (score + select never
-    leave the device; only k indices/scores return).  See
+    leave the device; only k indices/scores return).  ``seg`` is the
+    layout's cached row-to-candidate map, which the Pallas path sums by;
+    the jnp oracle derives its own from ``starts``.  See
     kernels/topk_ops.py for the layout and docs/ARCHITECTURE.md for where
     this sits in the paper map."""
     exclude = jnp.asarray(exclude, jnp.int32)
     if _use_pallas(backend):
         return _topk_ops.similarity_topk(rows, row_col, starts, q_words,
-                                         q_card, cards, exclude,
+                                         q_card, cards, exclude, seg,
                                          metric=metric, k=k)
     return _ref_similarity_topk(rows, row_col, starts, q_words,
                                 jnp.asarray(q_card, jnp.int32),

@@ -311,18 +311,25 @@ def similarity_scores(inter: jax.Array, q_card: jax.Array,
                      jnp.float32(1.0))
 
 
-def candidate_inter(per_row: jax.Array, starts: jax.Array) -> jax.Array:
+def candidate_inter(per_row: jax.Array, starts: jax.Array,
+                    seg: jax.Array | None = None) -> jax.Array:
     """Per-candidate intersection cardinalities from per-row counts:
     candidate ``t`` owns rows ``starts[t]:starts[t+1]``; rows past
     ``starts[-1]`` (layout padding) are dropped.  A per-segment sum, NOT
     a global prefix: the grand total of intersection bits across all
     candidates can overflow int32 even though each candidate's own count
-    cannot."""
+    cannot.
+
+    ``seg`` (N,) int32 is the row-to-candidate map when the caller keeps
+    one (``SimilarityEngine`` builds it once per layout): non-decreasing,
+    ``T`` on padding rows.  Without it the map is derived from ``starts``
+    by a binary search over every row."""
     t = starts.shape[0] - 1
-    seg_id = jnp.searchsorted(starts[1:], jnp.arange(per_row.shape[0]),
-                              side="right")
-    return jax.ops.segment_sum(per_row, seg_id, num_segments=t) \
-        .astype(jnp.int32)
+    if seg is None:
+        seg = jnp.searchsorted(starts[1:], jnp.arange(per_row.shape[0]),
+                               side="right")
+    return jax.ops.segment_sum(per_row, seg, num_segments=t,
+                               indices_are_sorted=True).astype(jnp.int32)
 
 
 def topk_select(score: jax.Array, inter: jax.Array,

@@ -18,6 +18,9 @@ device -- the serving contract):
   * ``row_col`` (N,) int32: which global chunk key each row belongs to --
     the scoring step ANDs row r with ``q_words[row_col[r]]``, so a query
     that lacks the key contributes zero automatically.
+  * ``seg``     (N,) int32: which candidate each row belongs to (``T`` on
+    layout padding), built from ``starts`` once per layout, so no
+    dispatch searches ``starts`` for it.
   * ``q_words`` (C, WORDS) uint32: the query's containers scattered over
     the global key columns.  This is the ONLY per-query device transfer
     (C * 8 kB); the candidate slab stays resident.
@@ -29,7 +32,8 @@ Three stages compose inside one jit:
      arrives as an SMEM block, never as a prefetched N-vector, which
      would overflow the TPU's 1 MiB of SMEM at index sizes), ANDs and
      Harley-Seal popcounts them.
-  2. per-candidate sums (``ref.candidate_inter``) and the float32 score
+  2. per-candidate sums by ``seg`` (``ref.candidate_inter``; derived
+     from ``starts`` when no map is given) and the float32 score
      (``ref.similarity_scores``: correctly rounded, so scores and ties
      match the host twin bit for bit).
   3. ``topk_merge`` -- ``_select_ids_kernel`` runs k rounds of (max,
@@ -216,8 +220,8 @@ def topk_merge(score: jax.Array, inter: jax.Array, gidx: jax.Array,
 @functools.partial(jax.jit, static_argnames=("metric", "k", "interpret"))
 def similarity_topk(rows: jax.Array, row_col: jax.Array, starts: jax.Array,
                     q_words: jax.Array, q_card: jax.Array, cards: jax.Array,
-                    exclude: jax.Array = -1, *, metric: str, k: int,
-                    interpret: bool | None = None
+                    exclude: jax.Array = -1, seg: jax.Array | None = None,
+                    *, metric: str, k: int, interpret: bool | None = None
                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused score + k-select over a device-resident candidate slab.
 
@@ -227,6 +231,8 @@ def similarity_topk(rows: jax.Array, row_col: jax.Array, starts: jax.Array,
     q_words: (C, WORDS) uint32 query bitset rows over the global keys.
     q_card:  scalar int32 query cardinality; cards: (T,) int32.
     exclude: runtime int32 candidate index scored -1 (-1: none).
+    seg:     optional (N,) int32 candidate of each row, T on padding rows
+             (the engine's cached map); None derives it from ``starts``.
     metric:  "jaccard" | "cosine" | "containment" (static).
     k:       static selection size.
 
@@ -247,7 +253,7 @@ def similarity_topk(rows: jax.Array, row_col: jax.Array, starts: jax.Array,
     t = starts.shape[0] - 1
     inter = candidate_inter(
         row_and_card(rows, row_col, q_words, interpret=interpret),
-        starts.astype(jnp.int32))
+        starts.astype(jnp.int32), seg)
     score = similarity_scores(inter, jnp.asarray(q_card, jnp.int32),
                               cards, metric)
     score = jnp.where(jnp.arange(t) == exclude, jnp.float32(-1.0), score)

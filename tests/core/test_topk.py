@@ -9,7 +9,7 @@ the k boundary -- and the kernel path is ONE engine dispatch."""
 import numpy as np
 import pytest
 
-from repro.core import RoaringBitmap
+from repro.core import BitmapArena, RoaringBitmap
 from repro.core.pairwise import METRICS, SimilarityEngine, _scores_host
 from repro.data.index import InvertedIndex
 
@@ -230,3 +230,39 @@ def test_topk_batch_edge_cases(rng):
         eng.topk_batch([0], 5, metric="bogus", backend="ref")
     with pytest.raises(IndexError):
         eng.topk_batch([3], 5, backend="ref")
+
+
+def test_arena_pallas_segment_map(rng):
+    """The served layout: an arena-backed engine on the Pallas kernel
+    sums per-row counts by its cached row-to-candidate map.  Answers
+    equal the host sweep bit for bit, one row per candidate and after an
+    edit gives one candidate a second row; the map is built once per
+    layout, never per query, and a no-op refresh keeps it."""
+    bms = [RoaringBitmap.from_values(
+        rng.choice(1 << 16, int(rng.integers(50, 4000)),
+                   replace=False).astype(np.uint32)) for _ in range(12)]
+    eng = SimilarityEngine(bms, arena=BitmapArena())
+    assert eng.segment_map_builds == 1
+    assert np.array_equal(eng.seg, np.arange(12))
+    q = RoaringBitmap.from_values(
+        rng.choice(1 << 17, 3000, replace=False).astype(np.uint32))
+    q.add((1 << 16) + 7)                         # meets the edit below
+    queries = [0, 5, 11, q, 4]
+
+    def same_as_host():
+        got = eng.topk_batch(queries, 4, "jaccard", backend="pallas")
+        for query, res in zip(queries, got):
+            want = eng.topk(query, 4, "jaccard", backend="host")
+            assert all(np.array_equal(a, b) for a, b in zip(res, want))
+
+    same_as_host()
+    for i in range(20):
+        eng.topk(i % 12, 3, "cosine", backend="pallas")
+    assert eng.segment_map_builds == 1
+    bms[4].add((1 << 16) + 7)                    # new chunk: a second row
+    assert eng.refresh() is True
+    assert eng.segment_map_builds == 2
+    assert eng.seg.tolist() == [0, 1, 2, 3, 4, 4, 5, 6, 7, 8, 9, 10, 11]
+    same_as_host()
+    assert eng.refresh() is False
+    assert eng.segment_map_builds == 2

@@ -103,12 +103,13 @@ def test_segment_reduce_rows_dual(one_chip):
 
 
 def test_similarity_topk(one_chip):
-    def fn(rows, col, starts, q, cards):
+    def fn(rows, col, starts, q, cards, seg):
         return topk_ops.similarity_topk(
             rows, col, starts, q, jnp.int32(1000), cards, jnp.int32(7),
-            metric="cosine", k=10, interpret=False)
+            seg, metric="cosine", k=10, interpret=False)
     _compile(one_chip, fn, ((ROWS, WORDS), _U32), ((ROWS,), _I32),
-             ((ROWS + 1,), _I32), ((KEYS, WORDS), _U32), ((ROWS,), _I32))
+             ((ROWS + 1,), _I32), ((KEYS, WORDS), _U32), ((ROWS,), _I32),
+             ((ROWS,), _I32))
 
 
 def test_similarity_topk_ids(one_chip):
