@@ -8,9 +8,11 @@ one step below the exactness the configuration states, must fail.
 For each seed it makes one run of the cell as ``run.py`` does (the same
 data, set-up, warm-up and window, at the cell's own size and load), and
 then ``Run.check`` compares the window's own sample with the control's
-answers in place of the served ones: Jaccard scores computed in
-bfloat16 on the default device (the chip, when run there) instead of
-float32.  It prints each run's line; ``correct`` has to come out false.
+answers in place of the served ones: each query's kind module gives its
+``control`` (``kinds/similar.py``: Jaccard scores divided in bfloat16
+on the default device, the chip when run there, instead of float32;
+``kinds/bool.py``: the exact answer without its largest value).  It
+prints each run's line; ``correct`` has to come out false.
 """
 
 from __future__ import annotations
@@ -23,19 +25,7 @@ import argparse  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 
-import numpy as np  # noqa: E402
-
 import run as bench  # noqa: E402
-
-
-def bf16_divide(a, b):
-    import jax.numpy as jnp
-    q = jnp.asarray(a, jnp.bfloat16) / jnp.asarray(b, jnp.bfloat16)
-    return np.asarray(q.astype(jnp.float32))
-
-
-def bf16_topk(ref, i: int, k: int) -> list:
-    return ref.topk(i, k, bf16_divide)
 
 
 def main(argv=None) -> int:
@@ -54,7 +44,7 @@ def main(argv=None) -> int:
         gc.collect()            # the previous seed's index and engine
         line = harness.run(bench.ROOT, args.workload, seed, args.seconds,
                            False, t_start=T_START, chips=chips,
-                           control=bf16_topk)
+                           control=True)
         print(json.dumps({"control_seed": seed, **line}), flush=True)
     return 0
 

@@ -1,19 +1,38 @@
 """One run of one cell: set-up, warm-up, the measured window, the check.
 
 Everything a cell needs is found by name from ``BENCHMARK.json``:
-``configs/<config>.json`` (sizes) with ``configs/<config>.py`` (its
-generator), ``traffic/<traffic>.json`` (read by ``traffic.py``) and
-``metrics/<metric>.py`` (one reader per metric).  Adding a cell, a
-configuration or a metric adds files; nothing here changes.
+
+* ``configs/<config>.json``: the sizes, and under ``tiny`` the sizes
+  the CPU tests run the configuration at; ``configs/<config>.py``: its
+  generator of named sets (``generate``) and of the program's bitmaps
+  (``postings``);
+* ``traffic/<traffic>.json``: the loop (``closed`` with ``clients``, or
+  ``open`` with ``rate``), the server's settings and the mix of one
+  entry, read by ``traffic.py``;
+* ``kinds/<kind>.py``, the mix entry's kind: ``queries(entry, sets,
+  rng)``, ``to_query(q)`` (the program's ``Query``), ``reference(sets)``,
+  ``expected(ref, q)``, ``control(ref, q)`` (the reference with the
+  exactness the configuration states broken, for ``control.py``) and
+  ``same(got, want)``;
+* ``metrics/<metric>.py``: one reader per metric;
+* the cell's ``chips``: above 1, the run installs a wide mesh over that
+  many devices, stripes the arena's rows over them and serves through
+  ``QueryServer(mesh=)``.
+
+Adding a cell, a configuration, a kind or a metric adds files; nothing
+here changes.
 
 The entry the window drives is the served path: ``QueryServer.submit``
-and ``QueryServer.step`` over an arena-backed ``InvertedIndex`` on one
-device, with no backend override.  The client is this module's closed
-loop, single-threaded like the server: each of the cell's clients sends
-its next query as soon as its previous one resolved, and the loop runs
-one server tick while work is queued.  Latency runs from a query's due
-time (when its client sent it) to its ``resolved_at`` (the answer on
-the host), on the same monotonic clock.
+and ``QueryServer.step`` over an arena-backed ``InvertedIndex``, with
+no backend override.  The client is this module's loop, single-threaded
+like the server.  In a closed loop each of the cell's clients sends its
+next query as soon as its previous one resolved, and the loop runs one
+server tick while work is queued.  In an open loop the client sends
+every query whose due time has passed, runs one server tick while work
+is queued, and sleeps to the next due time when none is.  Latency runs
+from a query's due time to its ``resolved_at`` (the answer on the
+host), on the same monotonic clock, so a long tick counts against the
+queries that came due during it.
 """
 
 from __future__ import annotations
@@ -34,7 +53,6 @@ HERE = Path(__file__).resolve().parent
 if str(HERE) not in sys.path:
     sys.path.insert(0, str(HERE))
 
-import reference  # noqa: E402
 import traffic as traffic_gen  # noqa: E402
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -73,8 +91,8 @@ trace_red = _module(HERE / "trace.py", "chip_trace")
 
 
 def resolve(manifest: dict, workload: str, base: Path = HERE) -> dict:
-    """The cell's config, traffic and metric entries, found by name under
-    ``base`` (this directory)."""
+    """The cell's config, traffic, kind module and metric entries, found
+    by name under ``base`` (this directory)."""
     cells = {w["name"]: w for w in manifest["workloads"]}
     if workload not in cells:
         raise BenchError(f"unknown workload {workload!r}")
@@ -87,10 +105,14 @@ def resolve(manifest: dict, workload: str, base: Path = HERE) -> dict:
         return [m for m in manifest[group]
                 if workload in m.get("workloads", [workload])]
 
+    traffic = traffic_gen.load(cell["traffic"], base)
+    (entry,) = traffic["mix"]
     return {"cell": cell, "config_entry": cfg_entry, "config": cfg,
             "generator": _module(base / "configs" / f"{cell['config']}.py",
                                  f"config_{cell['config']}"),
-            "traffic": traffic_gen.load(cell["traffic"], base),
+            "traffic": traffic,
+            "kind": _module(base / "kinds" / f"{entry['kind']}.py",
+                            f"kind_{entry['kind']}"),
             "end_to_end": metrics("end_to_end"),
             "per_layer": metrics("per_layer")}
 
@@ -196,6 +218,29 @@ class Client:
         while self.server.pending and time.monotonic() < deadline:
             self.step()
 
+    def open_loop(self, queries, dues, start: float, end: float
+                  ) -> list[Rec]:
+        """Send each query at its due time (``start`` plus the next of
+        ``dues``) until ``end``, whether or not earlier ones resolved;
+        between sends, one server tick while work is queued."""
+        it = iter(queries)
+        recs = []
+        due = start + next(dues)
+        self.wait_until(start)
+        while True:
+            now = time.monotonic()
+            if now >= end:
+                return recs
+            while due <= now:
+                recs.append(self.submit(next(it), due))
+                due = start + next(dues)
+            if self.server.pending:
+                self.step()
+            elif due < end:
+                self.wait_until(due)
+            else:
+                self.wait_until(end)
+
     def closed_loop(self, queries, clients: int, start: float, end: float
                     ) -> list[Rec]:
         """``clients`` callers, each sending its next query as soon as
@@ -227,17 +272,24 @@ class Run:
     """Set-up, warm-up, window and check of one cell for one seed.
 
     ``overrides`` replaces configuration sizes (the CPU tests run every
-    cell at a tiny size through it); the benchmark's own runs pass none.
+    cell at its configuration's ``tiny`` size through it); the
+    benchmark's own runs pass none.  ``base`` is where the cell's files
+    are found (this directory); ``chips`` the devices it runs on, by
+    default the cell's own.
     """
 
     def __init__(self, root: Path, workload: str, seed: int, seconds: float,
                  trace: bool, *, t_start: float, overrides=None,
-                 manifest=None, log=None):
+                 manifest=None, log=None, base: Path = HERE,
+                 chips: int | None = None):
         self.root = Path(root)
+        self.base = Path(base)
         self.manifest = manifest or load_manifest(self.root)
-        self.spec = resolve(self.manifest, workload)
+        self.spec = resolve(self.manifest, workload, self.base)
+        self.chips = int(chips or self.spec["cell"]["chips"])
         self.cfg = dict(self.spec["config"], **(overrides or {}))
         self.traffic = self.spec["traffic"]
+        self.kind = self.spec["kind"]
         self.workload = workload
         self.seed = int(seed)
         self.seconds = float(seconds)
@@ -252,7 +304,7 @@ class Run:
         import jax
         from repro.core import BitmapArena
         from repro.data.index import InvertedIndex
-        from repro.serve import Query, QueryServer
+        from repro.serve import QueryServer
         self.jax = jax
         self.compiles = CompileCounter.get()
         t0 = time.monotonic()
@@ -276,22 +328,38 @@ class Run:
         self.index = InvertedIndex.from_postings(postings, self.sets.universe,
                                                  arena=self.arena)
         self.log("index built")
-        self.arena.sync()
+        self.mesh = None
+        if self.chips > 1:
+            from repro.dist import ctx
+            self.mesh = ctx.install_wide_mesh(self.chips)
+            self.arena.shard_slabs(self.mesh).sync()
+        else:
+            self.arena.sync()
         sizes = self.sets.sizes()
         self.log(f"data: {len(self.sets)} sets of {int(sizes.sum())} values "
                  f"(largest {int(sizes.max())}), {rows} container rows, "
                  f"{self.arena.capacity * ROW_BYTES} slab bytes, "
                  f"built in {time.monotonic() - t0:.3f} s")
-        self.server = QueryServer(self.index,
+        self.server = QueryServer(self.index, mesh=self.mesh,
                                   **self.traffic.get("server", {}))
 
-        self.to_query = lambda q: Query.similar(q["terms"][0], q["k"],
-                                                q["metric"])
-        self.window_queries = traffic_gen.similar_queries(
-            self.traffic, self.sets.names, self.seed, traffic_gen.WINDOW)
-        self.warm_queries = traffic_gen.similar_queries(
-            self.traffic, self.sets.names, self.seed, traffic_gen.WARM_UP)
+        self.to_query = self.kind.to_query
+        self.window_queries = traffic_gen.queries(
+            self.traffic, self.kind, self.sets, self.seed, traffic_gen.WINDOW)
+        self.warm_queries = traffic_gen.queries(
+            self.traffic, self.kind, self.sets, self.seed,
+            traffic_gen.WARM_UP)
         self.warm_up()
+
+    def drive(self, client: Client, queries, stream: int, start: float,
+              end: float) -> list[Rec]:
+        """The cell's own loop from ``start`` to ``end``; an open loop's
+        arrivals come from the arrival stream that goes with ``stream``."""
+        tr = self.traffic
+        if tr["loop"] == "closed":
+            return client.closed_loop(queries, tr["clients"], start, end)
+        return client.open_loop(
+            queries, traffic_gen.arrivals(tr, self.seed, stream), start, end)
 
     def warm_up(self) -> None:
         """Drive the cell's own loop with the warm-up stream's queries,
@@ -307,8 +375,8 @@ class Run:
                 and time.monotonic() < t_end:
             n = self.compiles.count
             start = time.monotonic()
-            client.closed_loop(self.warm_queries, self.traffic["clients"],
-                               start, start + WARM_PASS_S)
+            self.drive(client, self.warm_queries, traffic_gen.WARM_UP,
+                       start, start + WARM_PASS_S)
             client.drain(time.monotonic() + DRAIN_S)
             passes.append(self.compiles.count - n)
         self.log(f"warm-up passes: {len(passes)}, compiles per pass: "
@@ -337,9 +405,8 @@ class Run:
         span = (jax.profiler.TraceAnnotation("bench.window") if self.trace
                 else contextlib.nullcontext())
         with span:
-            recs = client.closed_loop(self.window_queries,
-                                      self.traffic["clients"], start,
-                                      self.end)
+            recs = self.drive(client, self.window_queries,
+                              traffic_gen.WINDOW, start, self.end)
         if self.trace:
             jax.profiler.stop_trace()
         self.window_gcs = [g["collections"] - c
@@ -354,9 +421,16 @@ class Run:
         self.stats0, self.stats1 = stats0, self.server.stats()
         self.arena0, self.arena1 = arena0, dataclasses.replace(
             self.arena.stats)
-        dev = jax.devices()[0]
-        self.device_kind = dev.device_kind
-        self.memory_peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+        devs = (list(self.mesh.devices.flat) if self.mesh is not None
+                else jax.devices()[:1])
+        self.device_kind = devs[0].device_kind
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in devs]
+        if len(devs) > 1:
+            for d, p in zip(devs, peaks):
+                print(f"memory_peak_bytes of {d}: {p}", file=sys.stderr)
+        known = [p for p in peaks if p is not None]
+        self.memory_peak = max(known) if known else None
         self.reduced = None
         if self.trace:
             path = trace_red.find_xplane(str(self.trace_dir))
@@ -406,6 +480,8 @@ class Run:
                  f"({self.window_compile_s:.3f} s, "
                  f"{self.window_misses} not in the compile cache)")
         self.log(f"batch size: {json.dumps(_quantiles(batch))}")
+        self.log("send lateness, s after the due time: " + json.dumps(
+            _quantiles([r.submitted - r.due for r in self.recs])))
         self.log(f"ticks in window: {len(self.ticks)}")
         self.log(f"memory_peak_bytes: {self.memory_peak}")
         self.log(f"garbage collections in window by generation: "
@@ -414,13 +490,15 @@ class Run:
 
     # -- the check ------------------------------------------------------
 
-    def check(self, control=None) -> dict:
+    def check(self, control: bool = False) -> dict:
         """Compare a sample of the served answers, drawn from the seed,
         with the plain reference.  Frees the program's state first.
 
-        ``control(ref, i, k)``, when given, stands in the program's place:
-        its answers for the same sample are compared instead of the
-        served ones (``control.py``)."""
+        Each answer is judged by the cell's kind: ``expected`` from the
+        kind's ``reference`` over the configuration's sets, compared by
+        ``same``.  With ``control`` the kind's ``control(ref, q)`` stands
+        in the program's place: its answers for the same sample are
+        compared instead of the served ones (``control.py``)."""
         from repro.serve import OK
         answered = [r for r in self.recs if r.ticket.done
                     and r.ticket.result.status == OK]
@@ -432,13 +510,13 @@ class Run:
         self.recs = [dataclasses.replace(r, ticket=_Done(r.ticket))
                      for r in self.recs]
         t0 = time.monotonic()
-        ref = reference.Jaccard(self.sets)
+        kind = self.kind
+        ref = kind.reference(self.sets)
         wrong = 0
         for q, got in sample:
-            i = self.sets.pos[q["terms"][0]]
-            if control is not None:
-                got = control(ref, i, q["k"])
-            if got != ref.topk(i, q["k"]):
+            if control:
+                got = kind.control(ref, q)
+            if not kind.same(got, kind.expected(ref, q)):
                 wrong += 1
         self.log(f"reference check: {len(sample)} answers in "
                  f"{time.monotonic() - t0:.3f} s")
@@ -454,7 +532,7 @@ class Run:
             else self.spec["end_to_end"]
         out = {}
         for m in group:
-            value = reader(m["name"]).read(self)
+            value = reader(m["name"], self.base).read(self)
             if value is not None:
                 out[m["name"]] = {"value": value, "unit": m["unit"]}
         return out
@@ -471,12 +549,14 @@ class _Done:
 
 def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
         *, t_start: float, chips: int | None = None, overrides=None,
-        manifest=None, log=None, control=None) -> dict:
+        manifest=None, log=None, control: bool = False,
+        base: Path = HERE) -> dict:
     """Run one cell and return its result line (a dict), with the
     compared numbers under ``checks``, last.  ``control``: see
-    ``Run.check``."""
+    ``Run.check``; ``base``, ``chips``: see ``Run``."""
     r = Run(root, workload, seed, seconds, trace, t_start=t_start,
-            overrides=overrides, manifest=manifest, log=log)
+            overrides=overrides, manifest=manifest, log=log, base=base,
+            chips=chips)
     r.setup()
     r.window()
     r.report()
@@ -485,7 +565,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     import jax
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": chips or r.spec["cell"]["chips"],
+              "count": r.chips,
               "memory_peak_bytes": r.memory_peak}
     breakdown = None
     if trace and r.reduced is not None:

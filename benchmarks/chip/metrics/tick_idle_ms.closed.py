@@ -21,16 +21,6 @@ TICK = "serve.tick"
 PROGRAM = ("serve.", "engine.")
 
 
-def _pieces(idle: list, spans: list) -> list:
-    """Cut each idle interval at every span boundary inside it."""
-    cuts = sorted({t for _, s, e in spans for t in (s, e)})
-    out = []
-    for s, e in idle:
-        edges = [s] + [t for t in cuts if s < t < e] + [e]
-        out.extend(zip(edges[:-1], edges[1:]))
-    return out
-
-
 def tick_idle(data):
     """``(mean idle seconds per tick, {span: idle seconds over all ticks},
     ticks)`` of a ``jax.profiler.ProfileData``, or None when it holds no
@@ -59,24 +49,26 @@ def tick_idle(data):
         return None
     idle_s, split = 0.0, {}
     for lo, hi in ticks:
-        inside = [x for x in spans if x[1] < hi and x[2] > lo]
         by_name: dict = {}
-        for name, s, e in inside:
-            by_name.setdefault(name, []).append((s, e))
+        for name, s, e in spans:
+            if s < hi and e > lo:
+                by_name.setdefault(name, []).append((s, e))
+        idle = []
         for ops in devices:
             clipped = [(max(s, lo), min(e, hi)) for s, e in ops
                        if s < hi and e > lo]
             busy, gaps = trace_red._union(clipped)
             if clipped:
                 first, last = clipped[0][0], max(e for _, e in clipped)
-                idle = [(lo, first)] + gaps + [(last, hi)]
+                idle += [(lo, first)] + gaps + [(last, hi)]
             else:
-                idle = [(lo, hi)]
+                idle.append((lo, hi))
             idle_s += (hi - lo - busy) * 1e-9 / len(devices)
-            for s, e in _pieces([x for x in idle if x[1] > x[0]], inside):
-                label = trace_red._label(s, by_name)
-                split[label] = split.get(label, 0.0) \
-                    + (e - s) * 1e-9 / len(devices)
+        pieces = trace_red.cut([x for x in idle if x[1] > x[0]], by_name)
+        for (_, s, e), label in zip(pieces, trace_red.labels(
+                [s for _, s, _ in pieces], by_name)):
+            split[label] = split.get(label, 0.0) \
+                + (e - s) * 1e-9 / len(devices)
     return idle_s / len(ticks), split, len(ticks)
 
 

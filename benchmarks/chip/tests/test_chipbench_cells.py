@@ -18,10 +18,8 @@ HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 
-import control  # noqa: E402
 import harness  # noqa: E402
 
-TINY = {"dedup_netflix": {"n_sets": 384, "n_values": 384 * 40}}
 SEED = 2**31 + 977            # past 32 signed bits, as the driver's are
 CELLS = [w["name"] for w in harness.load_manifest(ROOT)["workloads"]]
 
@@ -38,12 +36,12 @@ def short_warm_up(monkeypatch):
     monkeypatch.setattr(harness, "DRAIN_S", 5.0)
 
 
-def run_cell(manifest, workload, trace=False, seed=SEED, control=None):
-    cell = {w["name"]: w for w in manifest["workloads"]}[workload]
+def run_cell(manifest, workload, trace=False, seed=SEED, control=False):
+    tiny = harness.resolve(manifest, workload)["config"]["tiny"]
     return harness.run(ROOT, workload, seed, 0.5, trace,
-                       t_start=time.monotonic(),
-                       overrides=TINY[cell["config"]], manifest=manifest,
-                       log=lambda *a: None, control=control)
+                       t_start=time.monotonic(), overrides=tiny,
+                       manifest=manifest, log=lambda *a: None,
+                       control=control)
 
 
 def check_line(line, manifest, workload, trace):
@@ -129,9 +127,10 @@ def test_a_broken_timed_path_is_not_correct(manifest, monkeypatch, fault):
         "value"] > 0 or line["attempted"] == 0 or line["failed"] > 0
 
 
+@pytest.mark.parametrize("workload", CELLS)
 @pytest.mark.parametrize("seed", [1, SEED])
-def test_the_control_fails_the_check(manifest, seed):
-    line = run_cell(manifest, CELLS[0], seed=seed, control=control.bf16_topk)
+def test_the_control_fails_the_check(manifest, workload, seed):
+    line = run_cell(manifest, workload, seed=seed, control=True)
     assert line["correct"] is False
     assert line["checks"]["wrong_answers"]["value"] > 0
 
@@ -147,6 +146,16 @@ def _command(cwd, env_extra=None):
 
 def test_the_command_without_a_tpu_prints_no_result():
     out = _command(ROOT)
+    assert out.returncode != 0
+    assert "{" not in out.stdout
+
+
+def test_the_control_without_a_tpu_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "benchmarks/chip/control.py", "--workload",
+         CELLS[0], "--seconds", "1", "--seeds", "1", str(SEED)], cwd=ROOT,
+        env=env, capture_output=True, text=True, timeout=300)
     assert out.returncode != 0
     assert "{" not in out.stdout
 

@@ -55,6 +55,8 @@ def test_config_files(manifest):
         cfg = json.loads((ROOT / c["file"]).read_text())
         for key in c["reduced"]:
             assert NAME.match(key) and key in cfg
+        # the sizes the CPU tests run it at, each a key of the sizes
+        assert cfg["tiny"] and set(cfg["tiny"]) <= set(cfg)
         assert any(w["config"] == c["name"] for w in manifest["workloads"])
     assert len(files) == len(manifest["configs"])
 

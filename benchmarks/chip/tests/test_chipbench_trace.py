@@ -103,3 +103,110 @@ def test_recorded_v5e_trace():
     assert calls > 0 and 0 < sec < red.busy_s
     names = [n for n, _ in red.breakdown()["device_ops"]]
     assert "segment_reduce" in names
+
+
+# a tick whose idle device time falls inside the program's own spans:
+# ops at 0..1 us and 9..10 us, the gap 1..9 us inside serve.revalidate
+# (2..8 us), which sits in serve.tick inside bench.step
+PROGRAM_SPANS = """
+planes {
+  id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 1000000 }
+    events { metadata_id: 1 offset_ps: 9000000 duration_ps: 1000000 } }
+  event_metadata { key: 1 value { id: 1 name: "%fusion.1 = u32[8] fusion(u32[8])" } }
+}
+planes {
+  id: 2 name: "/host:CPU"
+  lines { id: 1 name: "python3" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 10000000 }
+    events { metadata_id: 2 offset_ps: 500000 duration_ps: 9000000 }
+    events { metadata_id: 3 offset_ps: 600000 duration_ps: 8800000 }
+    events { metadata_id: 4 offset_ps: 700000 duration_ps: 8000000 } }
+  event_metadata { key: 1 value { id: 1 name: "bench.window" } }
+  event_metadata { key: 2 value { id: 2 name: "bench.step" } }
+  event_metadata { key: 3 value { id: 3 name: "serve.tick" } }
+  event_metadata { key: 4 value { id: 4 name: "serve.revalidate" } }
+}
+"""
+
+
+def test_a_gap_is_labelled_by_the_innermost_program_span():
+    from jax.profiler import ProfileData
+    red = trace.reduce_data(ProfileData.from_text_proto(PROGRAM_SPANS))
+    assert red.window_s == pytest.approx(10e-6)
+    assert red.busy_s == pytest.approx(2e-6)
+    assert red.gaps == [(pytest.approx(8e-6), "serve.revalidate")]
+    assert red.breakdown()["idle_gaps"] == [
+        ["serve.revalidate", pytest.approx(8e-6)]]
+
+
+def test_labels_pick_the_shortest_open_span():
+    spans = {"bench.step": [(0, 100)], "serve.tick": [(10, 90)],
+             "serve.lookup": [(20, 30), (50, 60)]}
+    assert trace.labels([-1, 0, 15, 25, 30, 55, 95, 100], spans) == [
+        "none", "bench.step", "serve.tick", "serve.lookup", "serve.tick",
+        "serve.lookup", "bench.step", "none"]
+
+
+# one gap from the fetch of a tick's last answer into the next tick's
+# revalidation: ops at 0..1 us and 9..10 us; engine.fetch 0.5..2.5 us,
+# then serve.revalidate 3..8.5 us; the gap 1..9 us begins in the fetch,
+# and 5.5 of its 8 us lie in the revalidation
+CROSSING = """
+planes {
+  id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 1000000 }
+    events { metadata_id: 1 offset_ps: 9000000 duration_ps: 1000000 } }
+  event_metadata { key: 1 value { id: 1 name: "%fusion.1 = u32[8] fusion(u32[8])" } }
+}
+planes {
+  id: 2 name: "/host:CPU"
+  lines { id: 1 name: "python3" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 10000000 }
+    events { metadata_id: 2 offset_ps: 500000 duration_ps: 2000000 }
+    events { metadata_id: 3 offset_ps: 3000000 duration_ps: 5500000 } }
+  event_metadata { key: 1 value { id: 1 name: "bench.window" } }
+  event_metadata { key: 2 value { id: 2 name: "engine.fetch" } }
+  event_metadata { key: 3 value { id: 3 name: "serve.revalidate" } }
+}
+"""
+
+
+def test_a_gap_across_spans_is_labelled_by_the_span_holding_most():
+    from jax.profiler import ProfileData
+    red = trace.reduce_data(ProfileData.from_text_proto(CROSSING))
+    assert red.busy_s == pytest.approx(2e-6)
+    assert red.gaps == [(pytest.approx(8e-6), "serve.revalidate")]
+
+
+def test_cut_splits_at_every_span_edge_inside():
+    spans = {"engine.fetch": [(5, 25)], "serve.revalidate": [(30, 85)]}
+    assert trace.cut([(10, 90), (0, 5), (40, 50)], spans) == [
+        (0, 10, 25), (0, 25, 30), (0, 30, 85), (0, 85, 90), (1, 0, 5),
+        (2, 40, 50)]
+    assert trace.held_by([(10, 90), (10, 40), (86, 95)], spans) == [
+        "serve.revalidate", "engine.fetch", "none"]
+
+
+# what the recorded trace reduced to when gaps were labelled by the
+# benchmark's spans alone: only the labels may change
+RECORDED_WINDOW_S = 1.027072809
+RECORDED_BUSY_S = 0.000813323
+RECORDED_OPS_SHA256 = \
+    "cdaf1d109c1ad8f25c6901edd2a24f4a135b385a73eb60eafe37f917e311acb1"
+
+
+def test_recorded_v5e_trace_reduces_as_before():
+    import hashlib
+    import json
+
+    from jax.profiler import ProfileData
+    red = trace.reduce_data(ProfileData.from_serialized_xspace(
+        gzip.decompress(RECORDED.read_bytes())))
+    assert red.window_s == RECORDED_WINDOW_S
+    assert red.busy_s == RECORDED_BUSY_S
+    ops = json.dumps(sorted(red.ops.items())).encode()
+    assert hashlib.sha256(ops).hexdigest() == RECORDED_OPS_SHA256
+    assert len(red.gaps) == 1230

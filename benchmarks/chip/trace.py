@@ -2,9 +2,9 @@
 
 Read with ``jax.profiler.ProfileData`` and nothing else.  Device planes
 are those named ``/device:TPU:<n>``; their ``XLA Ops`` line holds one
-event per operation run on the chip.  Host spans are the benchmark's own
-``TraceAnnotation`` events (names starting ``bench.``) on the host plane,
-on the same clock.
+event per operation run on the chip.  Host spans are ``TraceAnnotation``
+events on the host plane, on the same clock: the benchmark's own
+(``bench.*``) and the program's (``serve.*``, ``engine.*``).
 
 An op event's name is its HLO instruction's text, ``%name.N = shape
 op(...)``: a Pallas kernel is a ``custom-call`` named after the jitted
@@ -14,20 +14,25 @@ function that holds it (``%segment_reduce.1 = ... custom-call(...)``).
 ``reduce(path, window)`` gives, over the traced window ``(start_ns,
 end_ns)``: ``busy_s`` (the union of op intervals, averaged over the
 chips), ``window_s``, the device time and count of each op's text
-(``ops``), and the longest idle gaps, each labelled with the benchmark
-span open when it began.
+(``ops``), and the longest idle gaps, each labelled with the host span
+that holds most of it: the gap is cut at every span edge inside it, each
+piece takes the innermost span open over it, and the span with the most
+of the gap's length names it.
 """
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
+import heapq
 import os
 import re
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = "bench."          # the benchmark's spans; bench.window
+SPAN_PREFIXES = (SPAN_PREFIX, "serve.", "engine.")
 _INSTR = re.compile(r"^%?([A-Za-z_][\w-]*?)(?:\.\d+)?(?: = |$)")
 
 
@@ -107,14 +112,49 @@ def _union(intervals: list) -> tuple[int, list]:
     return total, gaps
 
 
-def _label(t: int, spans: dict) -> str:
-    """The innermost benchmark span open at ``t`` (``none`` if none)."""
-    best, best_len = "none", None
-    for name, ivs in spans.items():
-        for s, e in ivs:
-            if s <= t < e and (best_len is None or e - s < best_len):
-                best, best_len = name, e - s
-    return best
+def labels(times: list, spans: dict) -> list:
+    """The innermost (shortest) span of ``spans`` (name -> intervals)
+    open at each of ``times`` (``none`` if none); of equally long ones,
+    the first.  One sweep: spans enter a heap by length as they open, and
+    leave it from the top once they have closed."""
+    flat = [(name, s, e) for name, ivs in spans.items() for s, e in ivs]
+    opened = sorted((s, e - s, n, e, name)
+                    for n, (name, s, e) in enumerate(flat))
+    out, heap, i = {}, [], 0
+    for t in sorted(set(times)):
+        while i < len(opened) and opened[i][0] <= t:
+            _, length, n, e, name = opened[i]
+            heapq.heappush(heap, (length, n, e, name))
+            i += 1
+        while heap and heap[0][2] <= t:
+            heapq.heappop(heap)
+        out[t] = heap[0][3] if heap else "none"
+    return [out[t] for t in times]
+
+
+def cut(intervals: list, spans: dict) -> list:
+    """``(k, start, end)``: each of ``intervals[k]`` cut at every edge of
+    ``spans`` (name -> intervals) inside it, so that one set of spans is
+    open over each piece."""
+    edges = sorted({t for ivs in spans.values() for iv in ivs for t in iv})
+    out = []
+    for k, (s, e) in enumerate(intervals):
+        points = [s, *edges[bisect.bisect_right(edges, s):
+                            bisect.bisect_left(edges, e)], e]
+        out.extend((k, a, b) for a, b in zip(points[:-1], points[1:]))
+    return out
+
+
+def held_by(intervals: list, spans: dict) -> list:
+    """For each interval, the span of ``spans`` that holds most of its
+    length: the innermost one over each piece (see ``cut``), summed by
+    name; of equal sums, the one met first."""
+    pieces = cut(intervals, spans)
+    held: list = [{} for _ in intervals]
+    for (k, a, b), name in zip(pieces, labels([a for _, a, _ in pieces],
+                                               spans)):
+        held[k][name] = held[k].get(name, 0) + b - a
+    return [max(h, key=h.get) for h in held]
 
 
 def reduce(path: str, window: tuple[int, int] | None = None) -> Reduced:
@@ -134,7 +174,7 @@ def reduce_data(data, window: tuple[int, int] | None = None) -> Reduced:
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name.startswith(SPAN_PREFIX):
+                if ev.name.startswith(SPAN_PREFIXES):
                     spans.setdefault(ev.name, []).append(
                         (int(ev.start_ns), int(ev.start_ns + ev.duration_ns)))
     if window is None:
@@ -168,8 +208,9 @@ def reduce_data(data, window: tuple[int, int] | None = None) -> Reduced:
             all_gaps.extend(gaps)
     if window is None:
         window = (lo or 0, hi or 0)
-    labelled = sorted(((e - s) * 1e-9, _label(s, spans))
-                      for s, e in all_gaps if e > s)[::-1]
+    all_gaps = [(s, e) for s, e in all_gaps if e > s]
+    labelled = sorted(zip([(e - s) * 1e-9 for s, e in all_gaps],
+                          held_by(all_gaps, spans)))[::-1]
     return Reduced(window_s=(window[1] - window[0]) * 1e-9,
                    busy_s=(sum(busy) / len(busy) * 1e-9) if busy else 0.0,
                    n_devices=len(devices), ops=ops, gaps=labelled,
