@@ -34,6 +34,17 @@ AND runs the paper's cardinality-ascending planning at the top level too:
 key sets intersect cheapest-bitmap-first and the whole query exits early the
 moment the candidate key set goes empty.
 
+**AND of ORs** (``plan_wide("and_of_or", clauses)``, a filter in
+conjunctive normal form such as a star-schema WHERE clause): the key set
+is the intersection over clauses of each clause's key union, and each key
+is either anchored on the host -- its smallest clause is all arrays of at
+most 4,096 values in total, so that clause's values are probed against the
+others -- or one device segment whose rows OR within each clause and AND
+across clauses.  Every CNF segment of a tick reduces in ONE jitted call
+(``kernels.ops.segment_reduce_cnf``: one pass over the rows, each clause
+ORed in a VMEM accumulator and ANDed into its segment).  Results are
+exact whether or not a clause's bitmaps overlap.
+
 **Sharded multi-device path.**  When a 1-D device mesh is supplied (or
 installed with ``set_default_mesh``), each slab segment's rows are
 round-robined across the mesh axis and every shard runs the same
@@ -78,6 +89,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import containers as C
 from repro.core.containers import (
@@ -87,7 +99,7 @@ from repro.core.containers import (
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.ref import WORDS
-from repro.kernels.segment_ops import counter_planes
+from repro.kernels.segment_ops import CNF_ROW_CHUNK, counter_planes
 
 __all__ = ["or_many", "and_many", "xor_many", "andnot_many",
            "threshold_many", "set_default_mesh", "WidePlan", "plan_wide",
@@ -393,7 +405,8 @@ def _repack_segments(seg_keys, words, cards) -> dict[int, Container]:
 def _dispatch(seg_keys: list, seg_rows: list[list[np.ndarray]],
               op: str, threshold, backend,
               seg_weights: list[list[int]] | None = None,
-              mesh=None, arena=None) -> dict:
+              mesh=None, arena=None,
+              seg_clauses: list[list[int]] | None = None) -> dict:
     """Stack per-segment rows into one slab, reduce in one kernel call,
     repack each segment's (words, card) into the optimal container kind.
     With a multi-device mesh, rows shard across the mesh axis instead
@@ -406,9 +419,17 @@ def _dispatch(seg_keys: list, seg_rows: list[list[np.ndarray]],
     carries its own T into the same dispatch).  With an ``arena``
     (core/arena.py), row entries may be int slab-row ids: those gather
     from the resident device slab (no per-call staging) and only ndarray
-    rows ride a staged block appended after it."""
+    rows ride a staged block appended after it.  Op "and_of_or" takes
+    ``seg_clauses`` and reduces in one call (``_reduce_cnf``).
+
+    On one device each call's phases are trace spans: ``engine.stage``
+    (row ids and cold rows to the device), ``engine.reduce`` (the kernel
+    call and its answer on the host) and ``engine.repack``."""
     if not seg_keys:
         return {}
+    if op == "and_of_or":
+        return _reduce_cnf(seg_keys, seg_rows, seg_clauses, backend,
+                           mesh, arena)
     tvec = None if isinstance(threshold, (int, np.integer)) else \
         [int(x) for x in threshold]
 
@@ -521,19 +542,23 @@ def _dispatch(seg_keys: list, seg_rows: list[list[np.ndarray]],
                     [t_arg, np.ones(s_pad - s, np.int32)])
         t_kw = t_arg if tv_g is None else jnp.asarray(t_arg)
         w_kw = None if weights is None else jnp.asarray(weights)
-        if arena is None:
-            slab64 = np.stack([w for rows in rows_g for w in rows])
-            slab32 = slab64.view(np.uint32).reshape(n, WORDS)
-            if n_pad != n:
-                slab32 = np.concatenate(
-                    [slab32, np.zeros((n_pad - n, WORDS), np.uint32)])
-            words, cards = kops.segment_reduce(
-                jnp.asarray(slab32), jnp.asarray(starts), op, jmax=jmax,
-                threshold=t_kw, weights=w_kw,
-                planes=planes, wbits=wbits, backend=backend)
-        else:
-            pos, sidx, staged = _stage_arena_rows(arena, rows_g, n_pad)
-            if staged is None:              # warm: pure resident gather
+        with TraceAnnotation("engine.stage"):
+            if arena is None:
+                slab64 = np.stack([w for rows in rows_g for w in rows])
+                slab32 = slab64.view(np.uint32).reshape(n, WORDS)
+                if n_pad != n:
+                    slab32 = np.concatenate(
+                        [slab32, np.zeros((n_pad - n, WORDS), np.uint32)])
+                slab32 = jnp.asarray(slab32)
+            else:
+                pos, sidx, staged = _stage_arena_rows(arena, rows_g, n_pad)
+        with TraceAnnotation("engine.reduce"):
+            if arena is None:
+                words, cards = kops.segment_reduce(
+                    slab32, jnp.asarray(starts), op, jmax=jmax,
+                    threshold=t_kw, weights=w_kw,
+                    planes=planes, wbits=wbits, backend=backend)
+            elif staged is None:            # warm: pure resident gather
                 words, cards = kops.segment_reduce_rows(
                     arena.device_slab(), pos, jnp.asarray(starts), op,
                     jmax=jmax, threshold=t_kw, weights=w_kw,
@@ -544,13 +569,69 @@ def _dispatch(seg_keys: list, seg_rows: list[list[np.ndarray]],
                     jnp.asarray(starts), op, jmax=jmax, threshold=t_kw,
                     weights=w_kw, planes=planes, wbits=wbits,
                     backend=backend)
-        peeled.update(_repack_segments(
-            [seg_keys[i] for i in idxs], words[:s], cards[:s]))
+            words, cards = np.asarray(words[:s]), np.asarray(cards[:s])
+        with TraceAnnotation("engine.repack"):
+            peeled.update(_repack_segments(
+                [seg_keys[i] for i in idxs], words, cards))
     return peeled
 
 
+def _reduce_cnf(seg_keys: list, seg_rows: list[list],
+                seg_clauses: list[list[int]], backend, mesh,
+                arena) -> dict:
+    """Every "and_of_or" segment in ONE jitted device call
+    (``kernels.ops.segment_reduce_cnf``): rows gather from the arena slab
+    (cold ones from a staged block), OR within each clause and AND across
+    each segment's clauses.  Calls past ``CNF_ROW_CHUNK`` rows split at
+    segment boundaries.  Runs on one device: a multi-device mesh raises
+    ``ValueError``."""
+    mesh = _resolve_mesh(mesh)
+    if mesh is not None and _mesh_size(mesh) > 1:
+        raise ValueError("and_of_or plans reduce on one device")
+    out: dict = {}
+    lo = 0
+    while lo < len(seg_keys):
+        hi, n = lo + 1, len(seg_rows[lo])
+        while hi < len(seg_keys) and \
+                n + len(seg_rows[hi]) <= CNF_ROW_CHUNK:
+            n += len(seg_rows[hi])
+            hi += 1
+        out.update(_cnf_call(seg_keys[lo:hi], seg_rows[lo:hi],
+                             seg_clauses[lo:hi], backend, arena))
+        lo = hi
+    return out
+
+
+def _cnf_call(seg_keys, seg_rows, seg_clauses, backend, arena) -> dict:
+    """One device call of ``_reduce_cnf``.  Rows and segments pad to
+    powers of two, and the clause offsets to the padded row count, so
+    that the calls of a served mix reuse a few compilations."""
+    lens = [n for per in seg_clauses for n in per]
+    per_seg = [len(per) for per in seg_clauses]
+    n, c, s = sum(lens), len(lens), len(seg_keys)
+    n_pad, s_pad = _pow2(n), _pow2(s)
+    with TraceAnnotation("engine.stage"):
+        # padded clauses and segments are empty: they start at the end
+        cstarts = np.full(n_pad + 1, n, np.int32)
+        cstarts[0], cstarts[1:c + 1] = 0, np.cumsum(lens)
+        starts = np.full(s_pad + 1, c, np.int32)
+        starts[0], starts[1:s + 1] = 0, np.cumsum(per_seg)
+        pos, sidx, staged = _stage_arena_rows(arena, seg_rows, n_pad)
+        zero = np.zeros((1, WORDS), np.uint32)
+        table = zero if arena is None else arena.device_slab()
+    with TraceAnnotation("engine.reduce"):
+        words, cards = kops.segment_reduce_cnf(
+            table, zero if staged is None else staged, pos, sidx,
+            jnp.asarray(cstarts), jnp.asarray(starts),
+            cmax=_pow2(max(lens)), jmax=_pow2(max(per_seg)),
+            backend=backend)
+        words, cards = np.asarray(words)[:s], np.asarray(cards)[:s]
+    with TraceAnnotation("engine.repack"):
+        return _repack_segments(seg_keys, words, cards)
+
+
 def _stage_arena_rows(arena, rows_g: list[list], n_pad: int):
-    """Turn one depth bucket's row refs into dual-source gather inputs
+    """Turn one call's row refs into dual-source gather inputs
     ``(pos, sidx, staged)``: resident ids index the arena's device slab
     by position, cold ndarray rows stage into a small pow2-padded host
     block (row 0 reserved zero) indexed by ``sidx``.  Exactly one side of
@@ -560,7 +641,7 @@ def _stage_arena_rows(arena, rows_g: list[list], n_pad: int):
     never copied per call.  Padding slots point both indices at the zero
     rows (the kernel masks padding by segment length anyway).  Warm
     queries return ``staged=None``: the only host->device traffic is the
-    position vector itself."""
+    position vector itself.  Without an ``arena`` every row is staged."""
     pos: list[int] = []
     sidx: list[int] = []
     host: list[np.ndarray] = []
@@ -581,8 +662,10 @@ def _stage_arena_rows(arena, rows_g: list[list], n_pad: int):
         hb = np.zeros((h_pad, 1024), np.uint64)
         hb[1: 1 + len(host)] = np.stack(host)
         staged = jnp.asarray(hb.view(np.uint32).reshape(h_pad, WORDS))
-        arena.stats.host_rows_staged += len(host)
-    arena.stats.device_gathers += 1
+        if arena is not None:
+            arena.stats.host_rows_staged += len(host)
+    if arena is not None:
+        arena.stats.device_gathers += 1
     return (jnp.asarray(np.asarray(pos, np.int32)),
             jnp.asarray(np.asarray(sidx, np.int32)), staged)
 
@@ -902,6 +985,8 @@ class WidePlan:
     seg_rows: list[list]                  # uint64 row | arena row id each
     seg_weights: list[list[int]] | None = None
     arena: object | None = None           # BitmapArena owning the id rows
+    # "and_of_or": per segment, the row count of each clause, in order
+    seg_clauses: list[list[int]] | None = None
 
     def slab_bytes(self) -> int:
         """Bytes this plan contributes to a coalesced slab (the admission
@@ -913,10 +998,13 @@ def plan_wide(op: str, bitmaps, t: int = 0, weights=None, *,
               backend: str | None = None, arena=None) -> WidePlan:
     """Plan one wide aggregate without dispatching it.
 
-    ``op`` is "or" | "and" | "xor" | "andnot" | "threshold"; for "andnot"
-    the FIRST bitmap is the minuend and the rest are subtrahends; for
-    "threshold", ``t`` / ``weights`` follow ``threshold_many`` (t == 1
-    degenerates to an "or" plan and coalesces with the or class).
+    ``op`` is "or" | "and" | "xor" | "andnot" | "threshold" |
+    "and_of_or"; for "andnot" the FIRST bitmap is the minuend and the rest
+    are subtrahends; for "threshold", ``t`` / ``weights`` follow
+    ``threshold_many`` (t == 1 degenerates to an "or" plan and coalesces
+    with the or class); for "and_of_or", ``bitmaps`` is a sequence of
+    clauses, each a non-empty sequence of bitmaps, and the plan is the
+    AND over clauses of each clause's OR.
     Validation errors (bad op, t < 1, bad weights) raise here, at
     admission time -- never inside a dispatch batch.
 
@@ -939,6 +1027,8 @@ def plan_wide(op: str, bitmaps, t: int = 0, weights=None, *,
         return _plan_andnot(bitmaps[0], bitmaps[1:], backend, arena)
     if op == "threshold":
         return _plan_threshold(bitmaps, t, weights, backend, arena)
+    if op == "and_of_or":
+        return _plan_and_of_or(bitmaps, arena)
     raise ValueError(f"unknown wide op {op!r}")
 
 
@@ -947,7 +1037,7 @@ def _finish(plan: WidePlan, backend, mesh):
     merged.update(_dispatch(plan.seg_keys, plan.seg_rows, plan.op,
                             plan.threshold, backend,
                             seg_weights=plan.seg_weights, mesh=mesh,
-                            arena=plan.arena))
+                            arena=plan.arena, seg_clauses=plan.seg_clauses))
     return _build(merged)
 
 
@@ -972,11 +1062,14 @@ def execute_plans(plans, *, backend: str | None = None,
         rows: list[list] = []
         wts: list[list[int]] = []
         ts: list[int] = []
+        clauses: list[list[int]] = []
         any_w = any(plans[i].seg_weights is not None for i in idxs)
         for i in idxs:
             p = plans[i]
             keys.extend((i, k) for k in p.seg_keys)
             rows.extend(p.seg_rows)
+            if op == "and_of_or":
+                clauses.extend(p.seg_clauses)
             ts.extend([p.threshold] * len(p.seg_keys))
             if any_w:
                 wts.extend(p.seg_weights if p.seg_weights is not None
@@ -984,7 +1077,8 @@ def execute_plans(plans, *, backend: str | None = None,
         out = _dispatch(keys, rows, op,
                         ts if op == "threshold" else 0, backend,
                         seg_weights=wts if any_w else None, mesh=mesh,
-                        arena=plans[idxs[0]].arena)
+                        arena=plans[idxs[0]].arena,
+                        seg_clauses=clauses if op == "and_of_or" else None)
         for (i, k), cont in out.items():
             results[i][k] = cont
     return [_build(r) for r in results]
@@ -1016,6 +1110,11 @@ def execute_plan_host(plan: WidePlan):
             w = stack[0]
             if stack.shape[0] > 1:
                 w = w & ~np.bitwise_or.reduce(stack[1:], axis=0)
+        elif plan.op == "and_of_or":
+            bounds = np.cumsum([0, *plan.seg_clauses[i]])
+            w = np.bitwise_and.reduce(
+                [np.bitwise_or.reduce(stack[a:b], axis=0)
+                 for a, b in zip(bounds[:-1], bounds[1:])], axis=0)
         elif plan.op == "threshold":
             bits = np.unpackbits(stack.view(np.uint8), axis=1,
                                  bitorder="little").astype(np.int64)
@@ -1186,6 +1285,90 @@ def _plan_and(bitmaps, backend, arena=None) -> WidePlan:
         seg_rows.append([_row_ref(c, arena) for c in g])
     merged.update(_sweep_run_groups(run_groups, "and", 0))
     return WidePlan("and", 0, merged, seg_keys, seg_rows, arena=arena)
+
+
+def _clause_mask(vals: np.ndarray, clause: list) -> np.ndarray:
+    """Membership of the sorted uint16 ``vals`` in the OR of ``clause``'s
+    containers.  The arrays scatter into one chunk indicator and the
+    bitsets OR into one, which beats a binary search of ``vals`` per
+    member; runs are probed one by one."""
+    arrays = [c.values for c in clause if isinstance(c, ArrayContainer)]
+    bitsets = [c.words for c in clause if isinstance(c, BitsetContainer)]
+    hit = np.zeros(vals.size, bool)
+    if arrays:
+        ind = np.zeros(CHUNK, bool)
+        ind[np.concatenate(arrays)] = True
+        hit |= ind[vals]
+    if bitsets:
+        hit |= C.bitset_test_many(np.bitwise_or.reduce(bitsets), vals)
+    for c in clause:
+        if isinstance(c, RunContainer):
+            hit |= _member_mask(vals, c)
+    return hit
+
+
+def _plan_and_of_or(clauses, arena=None) -> WidePlan:
+    """AND over ``clauses`` of each clause's OR, as one plan.
+
+    Keys: the intersection over clauses of the union of each clause's
+    keys, smallest first, with an early exit when it goes empty.  Per key,
+    a clause holding a full chunk drops out (the AND identity), and the
+    rest is chosen from the input alone: a lone literal passes through;
+    when the smallest clause (by summed member cardinality) is all arrays
+    of at most ``ARRAY_MAX`` values, the key is anchored on the host --
+    that clause's values are filtered by membership in every other
+    clause, smallest first; otherwise the key is one device segment with
+    each clause's rows in turn (``seg_clauses`` holds their counts).
+    Exact whether or not a clause's bitmaps overlap."""
+    clauses = [list(c) for c in clauses]
+    if not clauses:
+        raise ValueError("and_of_or needs at least one clause")
+    if any(not c for c in clauses):
+        raise ValueError("every and_of_or clause needs at least one bitmap")
+    key_sets = sorted((set().union(*(bm.keys for bm in c))
+                       for c in clauses), key=len)
+    common = key_sets[0]
+    for ks in key_sets[1:]:
+        common = common & ks
+        if not common:
+            return WidePlan("and_of_or", 0, {}, [], [], seg_clauses=[])
+    lookups = [[dict(zip(bm.keys, bm.containers)) for bm in c]
+               for c in clauses]
+    merged: dict[int, Container] = {}
+    seg_keys: list[int] = []
+    seg_rows: list[list] = []
+    seg_clauses: list[list[int]] = []
+    for k in sorted(common):
+        groups = []
+        for lk in lookups:
+            g = [d[k] for d in lk if k in d]
+            if not any(_is_full(c) for c in g):
+                groups.append(g)
+        if not groups:
+            merged[k] = _full_run()                # every clause full
+            continue
+        if len(groups) == 1 and len(groups[0]) == 1:
+            merged[k] = groups[0][0]               # zero-copy literal
+            continue
+        cards = [sum(c.card for c in g) for g in groups]
+        order = sorted(range(len(groups)), key=cards.__getitem__)
+        anchor = groups[order[0]]
+        if cards[order[0]] <= ARRAY_MAX and \
+                all(isinstance(c, ArrayContainer) for c in anchor):
+            vals = anchor[0].values if len(anchor) == 1 else \
+                np.unique(np.concatenate([c.values for c in anchor]))
+            for j in order[1:]:
+                vals = vals[_clause_mask(vals, groups[j])]
+                if vals.size == 0:
+                    break
+            if vals.size:
+                merged[k] = ArrayContainer(vals)
+            continue
+        seg_keys.append(k)
+        seg_rows.append([_row_ref(c, arena) for g in groups for c in g])
+        seg_clauses.append([len(g) for g in groups])
+    return WidePlan("and_of_or", 0, merged, seg_keys, seg_rows,
+                    arena=arena, seg_clauses=seg_clauses)
 
 
 def andnot_many(minuend, subtrahends, *, backend: str | None = None,

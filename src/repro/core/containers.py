@@ -350,8 +350,9 @@ def containers_to_word_rows(conts, block: int = 256) -> np.ndarray:
     cumsum trick as ``RunContainer.to_array_values``), processed in
     ``block``-row chunks to bound the indicator's memory at
     ``block * 64 KiB``.  No per-container conversion work happens in
-    Python.  Complexity: O(total payload bytes); returns a fresh
-    writable array safe to hand to a device slab.
+    Python.  Complexity: O(total payload bytes), the (row, value) stream
+    sorted once by row so that each block reads only its own slice;
+    returns a fresh writable array safe to hand to a device slab.
     """
     n = len(conts)
     out = np.zeros((n, BITSET_WORDS), np.uint64)
@@ -401,12 +402,18 @@ def containers_to_word_rows(conts, block: int = 256) -> np.ndarray:
         rows_list.append(np.repeat(owner, lens))
     rows = np.concatenate(rows_list)
     vals = np.concatenate(vals_list)
+    # the stream is two runs each sorted by row (array values, then run
+    # values), so the stable sort is a merge; each block then takes one
+    # slice of it instead of a mask over every value
+    order = np.argsort(rows, kind="stable")
+    rows, vals = rows[order], vals[order]
     dense = np.asarray(dense_idx, np.int64)
-    for lo in range(0, dense.size, block):
+    cuts = np.searchsorted(rows, np.arange(0, dense.size + block, block))
+    for b, lo in enumerate(range(0, dense.size, block)):
         hi = min(lo + block, dense.size)
-        sel = (rows >= lo) & (rows < hi)
+        a, z = cuts[b], cuts[b + 1]
         ind = np.zeros((hi - lo, CHUNK), np.uint8)
-        ind[rows[sel] - lo, vals[sel]] = 1
+        ind[rows[a:z] - lo, vals[a:z]] = 1
         out[dense[lo:hi]] = np.packbits(
             ind, axis=1, bitorder="little").view(np.uint64)
     return out

@@ -166,6 +166,19 @@ class InvertedIndex:
         return RoaringBitmap.andnot_many(ops[0], ops[1:],
                                          arena=self.arena)
 
+    def query_and_of_or(self, *clauses) -> RoaringBitmap:
+        """Documents that hold a term of every clause: the AND of the
+        clauses' ORs (conjunctive normal form, e.g. a star-schema
+        WHERE clause), planned once -- host-anchored where a clause is
+        sparse, one device reduction for the rest
+        (``repro.core.aggregate.plan_wide("and_of_or", ...)``).  Each
+        clause is a non-empty iterable of terms."""
+        from repro.core import aggregate
+        plan = aggregate.plan_wide(
+            "and_of_or", [self._adopt([self._get(t) for t in c])
+                          for c in clauses], arena=self.arena)
+        return aggregate.execute_plans([plan])[0]
+
     def count_and(self, a: str, b: str) -> int:
         return self._get(a).and_card(self._get(b))  # fast count, sec 5.9
 

@@ -284,6 +284,28 @@ def segment_reduce_rows_dual(table, staged, pos, sidx, starts, op: str, *,
                                          weights=weights)
 
 
+_ref_segment_reduce_cnf = jax.jit(
+    ref.segment_reduce_cnf, static_argnames=("cmax", "jmax"))
+
+
+def segment_reduce_cnf(table, staged, pos, sidx, clause_starts, starts, *,
+                       cmax: int, jmax: int, backend: Backend | None = None):
+    """AND of ORs in one call: slot ``i`` gathers ``table[pos[i]] |
+    staged[sidx[i]]`` (as :func:`segment_reduce_rows_dual`), clause ``c``
+    ORs slots ``clause_starts[c]:clause_starts[c+1]`` and segment ``s``
+    ANDs clauses ``starts[s]:starts[s+1]``, fused with the segment's
+    cardinality.  ``cmax`` / ``jmax`` bound a clause's rows and a
+    segment's clauses for the jnp oracle (the kernel needs no bound).
+    See kernels/segment_ops.py."""
+    pos = jnp.asarray(pos, jnp.int32)
+    sidx = jnp.asarray(sidx, jnp.int32)
+    if _use_pallas(backend):
+        return _segment_ops.segment_reduce_cnf(
+            table, staged, pos, sidx, clause_starts, starts)
+    return _ref_segment_reduce_cnf(table, staged, pos, sidx, clause_starts,
+                                   starts, cmax=cmax, jmax=jmax)
+
+
 def segment_counters(slab, starts, *, jmax: int, planes: int, weights=None,
                      backend: Backend | None = None):
     """Per-segment bit-sliced occurrence counters (S, planes, WORDS) --

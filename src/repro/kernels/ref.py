@@ -612,6 +612,21 @@ def segment_reduce_rows_dual(table: jax.Array, staged: jax.Array,
                           threshold=threshold, weights=weights)
 
 
+def segment_reduce_cnf(table: jax.Array, staged: jax.Array,
+                       pos: jax.Array, sidx: jax.Array,
+                       clause_starts: jax.Array, starts: jax.Array, *,
+                       cmax: int, jmax: int
+                       ) -> tuple[jax.Array, jax.Array]:
+    """AND of ORs over rows gathered by :func:`gather_rows_dual`: clause
+    ``c`` ORs rows ``clause_starts[c]:clause_starts[c+1]``, segment ``s``
+    ANDs clauses ``starts[s]:starts[s+1]`` (an empty segment is zero).
+    ``cmax`` / ``jmax`` bound a clause's rows and a segment's clauses.
+    Returns (words (S, WORDS) uint32, cards (S,) int32)."""
+    slab = gather_rows_dual(table, staged, pos, sidx)
+    clauses, _ = segment_reduce(slab, clause_starts, "or", jmax=cmax)
+    return segment_reduce(clauses, starts, "and", jmax=jmax)
+
+
 # ---------------------------------------------------------------------------
 # bit-sliced occurrence counters (the exchange payload of the sharded
 # threshold path: each shard counts locally, counters are all-gathered and

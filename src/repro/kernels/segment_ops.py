@@ -41,6 +41,14 @@ minuend (each segment's first row) parks in the output block while the
 subtrahends OR into a VMEM accumulator; the ANDNOT and the popcount fuse
 into finalization ("Compressed bitmap indexes: beyond unions and
 intersections", Kaser & Lemire).
+
+``segment_reduce_cnf`` reduces an AND of ORs (a filter in conjunctive
+normal form) in one pass over the rows: its grid walks rows, not
+``(segment, depth)``, so clauses and segments of any length cost one step
+a row.  A VMEM accumulator ORs each clause (restarted at the clause's
+first row, the way ``andnot`` parks its minuend), each finished clause
+ANDs into its segment's output block, and the segment's popcount fuses
+into its last row.
 """
 
 from __future__ import annotations
@@ -341,3 +349,118 @@ def segment_reduce_rows_dual(table: jax.Array, staged: jax.Array,
     return segment_reduce(slab, starts, op, jmax=jmax, threshold=threshold,
                           weights=weights, planes=planes, wbits=wbits,
                           interpret=interpret)
+
+
+# rows per segment_reduce_cnf call: one prefetched int32 a row in SMEM
+CNF_ROW_CHUNK = 1 << 16
+
+# flags of an AND-of-ORs row: first / last row of its clause, its clause
+# the first / last of its segment
+_C_FIRST, _C_LAST, _S_FIRST, _S_LAST = 1, 2, 4, 8
+
+
+def _cnf_kernel(code_ref, slab_ref, out_ref, card_ref, acc_ref):
+    """One row of an AND-of-ORs stream.  The row ORs into the clause
+    accumulator ``acc_ref`` (restarted by a clause's first row); a
+    clause's last row ANDs the clause into the segment's output block
+    (set by the segment's first clause); a segment's last row emits its
+    popcount.  ``code_ref[r]`` packs ``segment << 4 | flags``."""
+    code = code_ref[pl.program_id(0)]
+    x = slab_ref[...]
+    acc = jnp.where((code & _C_FIRST) != 0, x, acc_ref[...] | x)
+    acc_ref[...] = acc
+
+    @pl.when((code & _C_LAST) != 0)
+    def _():
+        out_ref[...] = jnp.where((code & _S_FIRST) != 0, acc,
+                                 out_ref[...] & acc)
+
+    @pl.when((code & _S_LAST) != 0)
+    def _():
+        card_ref[...] = harley_seal_reduce(out_ref[...])
+
+
+
+def _cnf_codes(n: int, clause_starts, starts):
+    """Per row of an ``n``-row slab: ``segment << 4 | flags`` (first and
+    last row of its clause, its clause first and last of its segment).
+    Rows past the last clause go to segment ``S``, a slot of their own.
+    A row's clause is the count of clause starts at or before it, less
+    one (a scatter and a prefix sum, no search); a clause's segment
+    likewise."""
+    def owner(bounds, size):
+        hits = jnp.zeros(size + 1, jnp.int32).at[bounds[:-1]].add(1)
+        return jnp.cumsum(hits)[:size] - 1
+
+    r = jnp.arange(n, dtype=jnp.int32)
+    c_hi = clause_starts.shape[0] - 2
+    s_pad = starts.shape[0] - 1
+    clause = jnp.clip(owner(clause_starts, n), 0, c_hi)
+    seg = jnp.clip(owner(starts, c_hi + 1)[clause], 0, s_pad - 1)
+    c_last = r == clause_starts[clause + 1] - 1
+    code = (jnp.where(r == clause_starts[clause], _C_FIRST, 0)
+            | jnp.where(c_last, _C_LAST, 0)
+            | jnp.where(clause == starts[seg], _S_FIRST, 0)
+            | jnp.where(c_last & (clause == starts[seg + 1] - 1), _S_LAST, 0))
+    pad = r >= clause_starts[-1]
+    seg = jnp.where(pad, s_pad, seg)
+    code = jnp.where(pad, _C_FIRST | _C_LAST | _S_FIRST | _S_LAST, code)
+    return (seg << 4) | code
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def segment_reduce_cnf(table: jax.Array, staged: jax.Array, pos: jax.Array,
+                       sidx: jax.Array, clause_starts: jax.Array,
+                       starts: jax.Array, *, interpret: bool | None = None
+                       ) -> tuple[jax.Array, jax.Array]:
+    """AND of ORs over rows gathered like
+    :func:`segment_reduce_rows_dual` (``table[pos] | staged[sidx]``).
+
+    clause_starts: (C + 1,) int32 row offsets; clause c ORs rows
+            clause_starts[c]:clause_starts[c+1], at least one.
+    starts: (S + 1,) int32 clause offsets; segment s ANDs clauses
+            starts[s]:starts[s+1]; an empty segment reduces to zero.
+    Rows past ``clause_starts[-1]`` are padding.
+
+    One pass of one kernel over the rows in order (grid = rows): a VMEM
+    accumulator ORs each clause, and the clause ANDs into its segment's
+    output block, which stays in VMEM while its rows stream -- so the
+    kernel reads each row once and writes each segment once, with no
+    per-segment padding to a common depth.  The per-row segment ids and
+    flags are scalar-prefetched (one int32 a row, in SMEM).  Returns
+    (words (S, WORDS) uint32, cards (S,) int32).
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    slab = (jnp.take(table.astype(jnp.uint32), pos.astype(jnp.int32),
+                     axis=0)
+            | jnp.take(staged.astype(jnp.uint32), sidx.astype(jnp.int32),
+                       axis=0))
+    n = slab.shape[0]
+    starts = starts.astype(jnp.int32)
+    s = starts.shape[0] - 1
+    code = _cnf_codes(n, clause_starts.astype(jnp.int32), starts)
+
+    def seg_block(r, code_ref):
+        return (code_ref[r] >> 4, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n,),
+        in_specs=[pl.BlockSpec((None, 1, WORDS),
+                               lambda r, code_ref: (r, 0, 0))],
+        out_specs=[pl.BlockSpec((None, 1, WORDS), seg_block),
+                   pl.BlockSpec((None, 1, 1), seg_block)],
+        scratch_shapes=[pltpu.VMEM((1, WORDS), jnp.uint32)],
+    )
+    words, cards = pl.pallas_call(
+        _cnf_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((s + 1, 1, WORDS), jnp.uint32),
+                   jax.ShapeDtypeStruct((s + 1, 1, 1), jnp.int32)],
+        interpret=interpret,
+    )(code, slab.reshape(n, 1, WORDS))
+    # a segment no row reaches was never written: it is empty
+    full = starts[1:] > starts[:-1]
+    return (jnp.where(full[:, None], words[:s, 0], jnp.uint32(0)),
+            jnp.where(full, cards[:s, 0, 0], 0))

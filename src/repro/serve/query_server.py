@@ -14,9 +14,14 @@ paths, none on the host sweep (``ServerStats.sim_dispatches`` counts
 them).
 
 Observability: each tick is a ``serve.tick`` trace span, and the
-similarity path's phases are spans whose time also adds up in
-``ServerStats`` (``telemetry.span``): ``serve.revalidate``,
-``serve.lookup``, ``serve.score`` and ``serve.resolve``.
+server's phases are spans whose time also adds up in ``ServerStats``
+(``telemetry.span``): ``serve.plan`` (a boolean query planned at
+admission), ``serve.aggregate`` (a tick's boolean plans executed),
+``serve.revalidate``, ``serve.lookup``, ``serve.score`` and
+``serve.resolve``.  The boolean batches that reach the device also count
+their segments' rows and (query, chunk) groups, and the groups the
+planner resolved on the host (``ServerStats.seg_rows``, ``seg_groups``,
+``host_groups``).
 
 Robustness contract (the point of the module):
 
@@ -58,7 +63,7 @@ from repro.serve.telemetry import QueryTelemetry, ServerStats, span
 __all__ = ["Query", "Ticket", "TicketResult", "QueryServer",
            "OK", "OVERLOADED", "INVALID", "DEADLINE", "ERROR"]
 
-BOOLEAN_KINDS = ("and", "or", "xor", "andnot", "threshold")
+BOOLEAN_KINDS = ("and", "or", "xor", "andnot", "threshold", "and_of_or")
 
 # ticket terminal statuses
 OK = "ok"                 # value holds the query result
@@ -77,9 +82,12 @@ class Query:
     """One query: a boolean aggregate over terms or a similarity top-k.
 
     ``kind`` is "and" | "or" | "xor" | "andnot" | "threshold" |
-    "similar".  For "andnot" the first term is the minuend; "threshold"
-    uses ``t``/``weights`` (see ``threshold_many``); "similar" queries
-    ``terms[0]`` with ``k``/``metric``."""
+    "and_of_or" | "similar".  For "andnot" the first term is the
+    minuend; "threshold" uses ``t``/``weights`` (see
+    ``threshold_many``); "and_of_or" takes ``terms`` as clauses, each a
+    non-empty tuple of terms, and matches the documents that hold a term
+    of every clause; "similar" queries ``terms[0]`` with
+    ``k``/``metric``."""
     kind: str
     terms: tuple
     t: int = 0
@@ -103,6 +111,10 @@ class Query:
     def threshold(cls, terms, t, weights=None):
         return cls("threshold", tuple(terms), t,
                    None if weights is None else tuple(weights))
+
+    @classmethod
+    def and_of_or(cls, *clauses):
+        return cls("and_of_or", tuple(tuple(c) for c in clauses))
 
     @classmethod
     def similar(cls, term, k=10, metric="jaccard"):
@@ -234,14 +246,8 @@ class QueryServer:
         never inside a coalesced batch)."""
         q = t.query
         if q.kind in BOOLEAN_KINDS:
-            bms = [self.index._get(x) for x in q.terms]
-            if self.arena is not None:
-                for bm in bms:
-                    if bm.containers:
-                        self.arena.adopt(bm)
-            t._plan = aggregate.plan_wide(
-                q.kind, bms, q.t, q.weights, backend=self.backend,
-                arena=self.arena)
+            with self._phase("serve.plan", "plan_s"):
+                t._plan = self._plan(q)
         elif q.kind == "similar":
             if q.metric not in METRICS:
                 raise ValueError(f"unknown metric {q.metric!r}")
@@ -249,6 +255,34 @@ class QueryServer:
                 raise ValueError("similar takes exactly one term")
         else:
             raise ValueError(f"unknown query kind {q.kind!r}")
+
+    def _plan(self, q: Query):
+        if q.kind != "and_of_or":
+            bms = self._adopt([self.index._get(x) for x in q.terms])
+            return aggregate.plan_wide(q.kind, bms, q.t, q.weights,
+                                       backend=self.backend,
+                                       arena=self.arena)
+        from repro.dist import ctx
+        if ctx.resolve_wide(self.mesh)[1] > 1:
+            raise ValueError("and_of_or is served on one device")
+        if not q.terms:
+            raise ValueError("and_of_or needs at least one clause")
+        for clause in q.terms:
+            if not isinstance(clause, (tuple, list)) or not clause or \
+                    not all(isinstance(x, str) for x in clause):
+                raise ValueError(f"an and_of_or clause is a non-empty "
+                                 f"tuple of terms, not {clause!r}")
+        return aggregate.plan_wide(
+            "and_of_or", [self._adopt([self.index._get(x) for x in c])
+                          for c in q.terms], arena=self.arena)
+
+    def _adopt(self, bms: list) -> list:
+        """Adopt the non-empty operands into the arena, if there is one."""
+        if self.arena is not None:
+            for bm in bms:
+                if bm.containers:
+                    self.arena.adopt(bm)
+        return bms
 
     @property
     def pending(self) -> int:
@@ -371,11 +405,17 @@ class QueryServer:
             # unchanged -- slab_mismatch revalidates through the arena,
             # which repatches only the shards owning dirty rows, and the
             # terminal host fallback (_host_batch) stays jax-free
-            out = aggregate.execute_plans([t._plan for t in booleans],
-                                          backend=self.backend,
-                                          mesh=self.mesh)
+            plans = [t._plan for t in booleans]
+            with self._phase("serve.aggregate", "aggregate_s"):
+                out = aggregate.execute_plans(plans, backend=self.backend,
+                                              mesh=self.mesh)
             for t, bm in zip(booleans, out):
                 t._value = bm
+            st = self._stats
+            for p in plans:
+                st.seg_rows += sum(len(r) for r in p.seg_rows)
+                st.seg_groups += len(p.seg_keys)
+                st.host_groups += len(p.merged)
         if sims:
             with self._phase("serve.revalidate", "revalidate_s"):
                 terms, eng = self.index._sim_engine(mesh=self.mesh)

@@ -73,6 +73,14 @@ class ServerStats:
     score_s: float = 0.0        # serve.score: topk_batch, answers fetched
     resolve_s: float = 0.0      # serve.resolve: answers to tickets
     sim_dispatches: int = 0     # device top-k dispatches of the engine
+    # the boolean path: its phases' seconds (``span``), and what the
+    # plans of the batches that reached the device held
+    plan_s: float = 0.0         # serve.plan: planning at admission
+    aggregate_s: float = 0.0    # serve.aggregate: execute_plans a tick
+    seg_rows: int = 0           # rows of the device segments
+    seg_groups: int = 0         # (query, chunk) groups reduced on device
+    host_groups: int = 0        # non-empty (query, chunk) groups the
+    #                             planner resolved on the host
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)

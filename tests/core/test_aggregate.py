@@ -411,3 +411,172 @@ def test_plan_slab_bytes_accounting(rng):
         sum(len(r) for r in plan.seg_rows) * 8192
     empty = aggregate.plan_wide("or", [], backend="ref")
     assert empty.slab_bytes() == 0
+
+
+# ---------------------------------------------------------------------------
+# AND of ORs (conjunctive normal form): one plan, host-anchored or device
+# ---------------------------------------------------------------------------
+
+def _cnf_values(rng):
+    """2-4 clauses of 1-4 value sets over chunks 0-4.  Chunk 0 is sparse
+    in every clause (a host anchor), chunk 1 dense in every clause (a
+    device segment), chunks 2-4 a mix of sparse, dense and runs; the
+    members of a clause draw from the same chunks, so they overlap."""
+    out = []
+    for _ in range(int(rng.integers(2, 5))):
+        clause = []
+        for _ in range(int(rng.integers(1, 5))):
+            parts = [rng.integers(0, 1 << 16, int(rng.integers(1, 900))),
+                     (1 << 16) + rng.integers(0, 1 << 16, 30000)]
+            for key in rng.choice([2, 3, 4], int(rng.integers(1, 4)),
+                                  replace=False):
+                kind = int(rng.integers(3))
+                if kind == 0:
+                    n = int(rng.integers(1, 3000))
+                    vals = rng.integers(0, 1 << 16, n)
+                elif kind == 1:
+                    vals = rng.integers(0, 1 << 16, 20000)
+                else:
+                    lo = int(rng.integers(0, 30000))
+                    vals = np.arange(lo, lo + int(rng.integers(1, 30000)))
+                parts.append((int(key) << 16) + vals)
+            clause.append(np.unique(np.concatenate(parts)).astype(np.uint32))
+        out.append(clause)
+    return out
+
+
+def _cnf_want(clause_vals) -> list:
+    return sorted(reduce(operator.and_, [
+        reduce(operator.or_, [set(v.tolist()) for v in c])
+        for c in clause_vals]))
+
+
+def _cnf_bitmaps(clause_vals, arena=None):
+    out = [[bm(v) for v in c] for c in clause_vals]
+    if arena is not None:
+        for c in out:
+            for b in c:
+                b.run_optimize()
+                arena.adopt(b)
+    return out
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("resident", [False, True],
+                         ids=["staged", "arena"])
+def test_and_of_or_vs_set_oracle(seed, resident):
+    from repro.core import BitmapArena
+    rng = np.random.default_rng(seed)
+    arena = BitmapArena() if resident else None
+    queries = [_cnf_values(rng) for _ in range(4)]
+    plans = [aggregate.plan_wide("and_of_or", _cnf_bitmaps(q, arena),
+                                 arena=arena) for q in queries]
+    # both sides of the host/device choice in the one batch: chunk 0 is
+    # anchored on the host, chunk 1 goes to the device
+    assert all(0 not in p.seg_keys and 1 in p.seg_keys for p in plans)
+    for got, q in zip(aggregate.execute_plans(plans, backend="ref"),
+                      queries):
+        assert got.to_array().tolist() == _cnf_want(q)
+        _check_invariants(got)
+
+
+def test_and_of_or_kernel_and_host_twin_bit_identical(rng):
+    """The Pallas path (interpret mode), the jnp oracle and the numpy
+    host twin give the same containers, kinds included."""
+    from repro.core import BitmapArena
+    arena = BitmapArena()
+    queries = [_cnf_values(rng) for _ in range(3)]
+    plans = [aggregate.plan_wide("and_of_or", _cnf_bitmaps(q, arena),
+                                 arena=arena) for q in queries]
+    pallas = aggregate.execute_plans(plans, backend="pallas")
+    oracle = aggregate.execute_plans(plans, backend="ref")
+    for p, a, b, q in zip(plans, pallas, oracle, queries):
+        host = aggregate.execute_plan_host(p)
+        assert a == b == host
+        assert [c.kind for c in a.containers] == \
+            [c.kind for c in host.containers]
+        assert a.to_array().tolist() == _cnf_want(q)
+
+
+def test_and_of_or_coalesces_into_one_device_call(monkeypatch, rng):
+    from repro.kernels import ops as kops
+    real = kops.segment_reduce_cnf
+    calls = []
+
+    def counting(*a, **kw):
+        calls.append(kw)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(kops, "segment_reduce_cnf", counting)
+    queries = [_cnf_values(rng) for _ in range(5)]
+    plans = [aggregate.plan_wide("and_of_or", _cnf_bitmaps(q))
+             for q in queries]
+    out = aggregate.execute_plans(plans, backend="ref")
+    assert len(calls) == 1
+    assert [o.to_array().tolist() for o in out] == \
+        [_cnf_want(q) for q in queries]
+
+
+def test_and_of_or_overlapping_members_and_literals():
+    a = np.arange(0, 40000, dtype=np.uint32)            # bitset, chunk 0
+    b = np.arange(30000, 70000, dtype=np.uint32)        # overlaps a
+    c = np.array([5, 35000, 65600, 1 << 20], np.uint32)
+    cases = [
+        [[a, a], [b]],                                  # a member twice
+        [[a, b], [b, c]],                               # overlap, anchor
+        [[c]],                                          # one literal
+        [[a], [b], [c]],                                # single literals
+        [[a, b]],                                       # one clause: OR
+    ]
+    for vals in cases:
+        plan = aggregate.plan_wide("and_of_or", _cnf_bitmaps(vals))
+        got = aggregate.execute_plans([plan], backend="ref")[0]
+        assert got.to_array().tolist() == _cnf_want(vals)
+        assert aggregate.execute_plan_host(plan) == got
+
+
+def test_and_of_or_empty_clauses_keys_and_full_chunks():
+    a = bm(range(0, 100))
+    far = bm(range(5 << 16, (5 << 16) + 10))
+    empty = RoaringBitmap()
+    # a clause of empty bitmaps (unknown terms): empty, no segment
+    plan = aggregate.plan_wide("and_of_or", [[a], [empty, empty]])
+    assert plan.seg_keys == [] and plan.merged == {}
+    # disjoint keys: the key intersection exits early
+    plan = aggregate.plan_wide("and_of_or", [[a], [far]])
+    assert plan.seg_keys == [] and plan.merged == {}
+    # a full chunk is the AND identity
+    full = bm(range(0, 1 << 16))
+    got = aggregate.execute_plans(
+        [aggregate.plan_wide("and_of_or", [[full], [a, far]])])[0]
+    assert got.to_array().tolist() == list(range(100))
+    got = aggregate.execute_plans(
+        [aggregate.plan_wide("and_of_or", [[full], [full]])])[0]
+    assert got.cardinality == 1 << 16
+
+
+def test_and_of_or_validates_at_admission():
+    with pytest.raises(ValueError, match="at least one clause"):
+        aggregate.plan_wide("and_of_or", [])
+    with pytest.raises(ValueError, match="at least one bitmap"):
+        aggregate.plan_wide("and_of_or", [[bm([1])], []])
+
+
+def test_and_of_or_splits_calls_past_the_row_chunk(monkeypatch, rng):
+    from repro.kernels import ops as kops
+    real = kops.segment_reduce_cnf
+    calls = []
+
+    def counting(*a, **kw):
+        calls.append(kw)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(kops, "segment_reduce_cnf", counting)
+    monkeypatch.setattr(aggregate, "CNF_ROW_CHUNK", 8)
+    queries = [_cnf_values(rng) for _ in range(3)]
+    plans = [aggregate.plan_wide("and_of_or", _cnf_bitmaps(q))
+             for q in queries]
+    out = aggregate.execute_plans(plans, backend="pallas")
+    assert len(calls) > 1
+    assert [o.to_array().tolist() for o in out] == \
+        [_cnf_want(q) for q in queries]

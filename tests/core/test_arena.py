@@ -198,6 +198,36 @@ class TestAdoptFrozen:
         got = aggregate.xor_many(froz, backend="ref", arena=arena)
         assert got == RoaringBitmap.xor_many(bms)
 
+    def test_bulk_of_many_multi_container_bitmaps_equals_adopt(self):
+        """Hundreds of bitmaps over several chunks -- arrays, runs and
+        bitsets, more than one conversion block of array and run rows --
+        promote in one call to the same rows as adopting each bitmap."""
+        rng = np.random.default_rng(11)
+        bms = []
+        for i in range(240):
+            parts = []
+            for key in rng.choice(8, int(rng.integers(2, 6)), replace=False):
+                if (i + key) % 3 == 0:
+                    lo = int(rng.integers(0, 60000))
+                    vals = np.arange(lo, lo + int(rng.integers(1, 5000)))
+                else:
+                    vals = rng.integers(0, 1 << 16, int(rng.choice(
+                        [3, 700, 4000, 9000])))
+                parts.append((int(key) << 16) + vals)
+            bms.append(bm(np.concatenate(parts)).run_optimize())
+        kinds = {c.kind for b in bms for c in b.containers}
+        assert kinds == {"array", "run", "bitset"}
+        assert sum(c.kind != "bitset" for b in bms
+                   for c in b.containers) > 2 * 256
+        bulk, each = BitmapArena(), BitmapArena()
+        bulk.adopt_frozen(bms)
+        for b in bms:
+            each.adopt(b)
+        for b in bms:
+            for c in b.containers:
+                assert np.array_equal(bulk.host_row(bulk.lookup(c)),
+                                      each.host_row(each.lookup(c)))
+
     def test_single_bitmap_and_shared_rows(self):
         arena = BitmapArena()
         a = bm(range(5000, 9000))

@@ -223,3 +223,50 @@ def test_segment_counters_exchange_roundtrip(rng):
         got = np.asarray(ref.counters_ge(tot, jnp.int32(t)))
         want = _np_reduce(slab, starts, "threshold", t, w)
         assert np.array_equal(got, want), t
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_segment_reduce_cnf_vs_oracle(seed):
+    """AND of ORs over rows gathered from a table and a staged block:
+    the Pallas kernel (interpret mode) against the jnp oracle and numpy,
+    padding rows, clauses and segments included."""
+    from repro.kernels.segment_ops import segment_reduce_cnf
+    rng = np.random.default_rng(seed)
+    table = rng.integers(0, 1 << 32, (12, WORDS), dtype=np.uint32)
+    table[0] = 0                                   # the reserved zero row
+    staged = rng.integers(0, 1 << 32, (4, WORDS), dtype=np.uint32)
+    staged[0] = 0
+    clauses = [[int(rng.integers(1, 5)) for _ in range(int(
+        rng.integers(1, 4)))] for _ in range(5)]
+    lens = [n for per in clauses for n in per]
+    n = sum(lens)
+    from_table = rng.random(n) < 0.7
+    pos = np.where(from_table, rng.integers(1, 12, n), 0).astype(np.int32)
+    sidx = np.where(from_table, 0, rng.integers(1, 4, n)).astype(np.int32)
+    rows = table[pos] | staged[sidx]
+    pos = np.concatenate([pos, np.zeros(3, np.int32)])      # padding rows
+    sidx = np.concatenate([sidx, np.zeros(3, np.int32)])
+    # two padding clauses and three padding segments, all empty
+    cstarts = np.concatenate(([0], np.cumsum(lens), [n, n])).astype(
+        np.int32)
+    c = len(lens)
+    starts = np.concatenate(([0], np.cumsum([len(p) for p in clauses]),
+                             [c, c, c])).astype(np.int32)
+    want = np.zeros((starts.size - 1, WORDS), np.uint32)
+    for s_, per in enumerate(clauses):
+        first = int(starts[s_])
+        acc = None
+        for j in range(len(per)):
+            lo, hi = cstarts[first + j], cstarts[first + j + 1]
+            clause = np.bitwise_or.reduce(rows[lo:hi], axis=0)
+            acc = clause if acc is None else acc & clause
+        want[s_] = acc
+    args = [jnp.asarray(x) for x in (table, staged, pos, sidx, cstarts,
+                                     starts)]
+    got_w, got_c = segment_reduce_cnf(*args)
+    ref_w, ref_c = ref.segment_reduce_cnf(*args, cmax=8, jmax=4)
+    np.testing.assert_array_equal(np.asarray(got_w), want)
+    np.testing.assert_array_equal(np.asarray(ref_w), want)
+    cards = np.bitwise_count(want).sum(axis=1)
+    np.testing.assert_array_equal(np.asarray(got_c), cards)
+    np.testing.assert_array_equal(np.asarray(ref_c), cards)

@@ -102,6 +102,19 @@ def test_segment_reduce_rows_dual(one_chip):
              ((SEGS * 8,), _I32), ((SEGS * 8,), _I32), ((SEGS + 1,), _I32))
 
 
+def test_segment_reduce_cnf(one_chip):
+    """A whole call of rows at the SMEM bound: one int32 a row."""
+    n = segment_ops.CNF_ROW_CHUNK
+
+    def fn(table, staged, pos, sidx, clause_starts, starts):
+        return segment_ops.segment_reduce_cnf(
+            table, staged, pos, sidx, clause_starts, starts,
+            interpret=False)
+    _compile(one_chip, fn, ((ROWS, WORDS), _U32), ((1, WORDS), _U32),
+             ((n,), _I32), ((n,), _I32), ((n + 1,), _I32),
+             ((SEGS + 1,), _I32))
+
+
 def test_similarity_topk(one_chip):
     def fn(rows, col, starts, q, cards, seg):
         return topk_ops.similarity_topk(

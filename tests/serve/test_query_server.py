@@ -197,3 +197,92 @@ def test_sim_batch_groups_by_k_and_metric(index):
     assert srv.step() == 4
     for t, q in zip(tickets, qs):
         assert t.result.value == index.similar(q.terms[0], q.k, q.metric)
+
+
+# ---------------------------------------------------------------------------
+# AND of ORs served over an arena-backed index
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def cnf_index():
+    """Postings over three chunks: sparse in chunk 0 (host anchors),
+    dense in chunk 1 (device segments), mixed in chunk 2; members of one
+    attribute overlap."""
+    from repro.core import BitmapArena, RoaringBitmap
+    rng = np.random.default_rng(16)
+    postings = {}
+    for i in range(12):
+        parts = [rng.integers(0, 1 << 16, int(rng.integers(10, 1500))),
+                 (1 << 16) + rng.integers(0, 1 << 16, 25000)]
+        if i % 3:
+            parts.append((2 << 16) + rng.integers(
+                0, 1 << 16, int(rng.integers(100, 20000))))
+        postings[f"a{i}"] = RoaringBitmap.from_values(
+            np.concatenate(parts).astype(np.uint32))
+    return InvertedIndex.from_postings(postings, 3 << 16,
+                                       arena=BitmapArena())
+
+
+def _cnf_sets(ix, q):
+    out = None
+    for clause in q.terms:
+        u = set()
+        for term in clause:
+            u |= set(ix._get(term).to_array().tolist())
+        out = u if out is None else out & u
+    return sorted(out)
+
+
+def test_and_of_or_served_matches_sets_and_index(cnf_index, monkeypatch):
+    """CNF queries through submit/step with no backend override: one
+    device call a tick for all of them, answers equal to Python-set
+    algebra and to ``InvertedIndex.query_and_of_or``."""
+    from repro.kernels import ops as kops
+    real = kops.segment_reduce_cnf
+    calls = []
+    monkeypatch.setattr(kops, "segment_reduce_cnf",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    qs = [Query.and_of_or(("a0", "a1"), ("a2",)),
+          Query.and_of_or(("a3",), ("a4", "a5", "a4"), ("a6", "a7")),
+          Query.and_of_or(("a8", "nope"), ("a9", "a10", "a11")),
+          Query.and_of_or(("a1",)),
+          Query.and_of_or(("a2",), ("nope",))]
+    srv = QueryServer(cnf_index, clock=FakeClock())
+    tickets = [srv.submit(q) for q in qs]
+    assert srv.step() == len(qs)
+    assert len(calls) == 1
+    for t, q in zip(tickets, qs):
+        assert t.result.status == OK and not t.telemetry.degraded
+        assert t.result.value.to_array().tolist() == _cnf_sets(cnf_index, q)
+        assert t.result.value == cnf_index.query_and_of_or(*q.terms)
+    st = srv.stats()
+    assert st.seg_groups > 0 and st.host_groups > 0
+
+
+def test_and_of_or_malformed_clauses_are_invalid(cnf_index):
+    srv = QueryServer(cnf_index, clock=FakeClock())
+    bad = [Query.and_of_or(),                       # no clause
+           Query.and_of_or(("a1",), ()),            # an empty clause
+           Query("and_of_or", ("a1", "a2")),        # terms, not clauses
+           Query("and_of_or", (("a1", 7),)),        # a term not a name
+           Query("and_of_or", (None,))]
+    for q in bad:
+        t = srv.submit(q)
+        assert t.done and t.result.status == INVALID and t.result.error
+    assert srv.pending == 0
+
+
+def test_and_of_or_is_refused_on_a_multi_device_mesh(cnf_index):
+    """CNF plans reduce on one device: a server over a wider mesh
+    refuses them at admission, and ``execute_plans`` raises."""
+    from types import SimpleNamespace
+
+    from repro.core import aggregate
+    wide = SimpleNamespace(axis_names=("wide",), devices=np.empty(4))
+    srv = QueryServer(cnf_index, clock=FakeClock(), mesh=wide)
+    t = srv.submit(Query.and_of_or(("a1",), ("a2",)))
+    assert t.result.status == INVALID and "one device" in t.result.error
+    plan = aggregate.plan_wide(
+        "and_of_or", [[cnf_index._get("a1")], [cnf_index._get("a2")]])
+    with pytest.raises(ValueError, match="one device"):
+        aggregate.execute_plans([plan], mesh=wide)

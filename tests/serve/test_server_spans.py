@@ -158,3 +158,85 @@ def test_a_traced_tick_holds_its_phases(index, tmp_path):
     fetch = [(s, e) for name, s, e in events if name == "engine.fetch"]
     assert len(fetch) == 1
     assert score[0][0] <= fetch[0][0] and fetch[0][1] <= score[0][1]
+
+
+# ---------------------------------------------------------------------------
+# the boolean path: serve.plan, serve.aggregate and the segment counters
+# ---------------------------------------------------------------------------
+
+PLAN_S = 0.125
+AGGREGATE_S = 0.5
+
+
+def _cnf_index():
+    """``a`` and ``b`` overlap sparsely in chunk 0; ``a``, ``b``, ``c``
+    are dense in chunk 1; ``b`` alone reaches chunk 2."""
+    from repro.core import BitmapArena, RoaringBitmap
+    dense = np.random.default_rng(3).integers(0, 1 << 16, (3, 30000))
+    vals = {"a": [np.arange(0, 100), (1 << 16) + dense[0]],
+            "b": [np.arange(50, 250), (1 << 16) + dense[1],
+                  (2 << 16) + dense[2]],
+            "c": [(1 << 16) + dense[2]]}
+    postings = {k: RoaringBitmap.from_values(
+        np.concatenate(v).astype(np.uint32)) for k, v in vals.items()}
+    return InvertedIndex.from_postings(postings, 3 << 16,
+                                       arena=BitmapArena())
+
+
+# (a) & (b | c): chunk 0 anchored on a's 100 values, 50 of them in b;
+# chunk 1 one segment of 3 rows in 2 clauses.  (c) & (a | b): chunk 1
+# alone, 3 rows in 2 clauses.
+CNF = [Query.and_of_or(("a",), ("b", "c")),
+       Query.and_of_or(("c",), ("a", "b"))]
+
+
+def test_boolean_phases_and_segment_counters(monkeypatch):
+    from repro.core import aggregate
+    clock = FakeClock()
+    real_plan, real_exec = aggregate.plan_wide, aggregate.execute_plans
+
+    def plan(*a, **kw):
+        clock.sleep(PLAN_S)
+        return real_plan(*a, **kw)
+
+    def execute(*a, **kw):
+        clock.sleep(AGGREGATE_S)
+        return real_exec(*a, **kw)
+
+    monkeypatch.setattr(aggregate, "plan_wide", plan)
+    monkeypatch.setattr(aggregate, "execute_plans", execute)
+    srv = QueryServer(_cnf_index(), clock=clock)
+    tickets = [srv.submit(q) for q in CNF]
+    srv.step()
+    st = srv.stats()
+    assert all(t.result.ok for t in tickets)
+    assert tickets[0].result.value.cardinality >= 50
+    assert st.plan_s == 2 * PLAN_S and st.aggregate_s == AGGREGATE_S
+    assert (st.seg_rows, st.seg_groups, st.host_groups) == (6, 2, 1)
+    srv.submit(CNF[0])
+    srv.step()
+    st = srv.stats()
+    assert st.plan_s == 3 * PLAN_S and st.aggregate_s == 2 * AGGREGATE_S
+    assert (st.seg_rows, st.seg_groups, st.host_groups) == (9, 3, 2)
+
+
+def test_a_traced_boolean_tick_holds_its_phases(tmp_path):
+    srv = QueryServer(_cnf_index())
+    srv.submit(CNF[0])
+    srv.run_until_idle()                          # compiled before the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for q in CNF:
+            srv.submit(q)
+        srv.step()
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    events = _events(found[0])
+    names = {name for name, _, _ in events}
+    assert "serve.plan" in names
+    (agg,) = [(s, e) for name, s, e in events if name == "serve.aggregate"]
+    inside = {name for name, s, e in events
+              if agg[0] <= s and e <= agg[1]}
+    assert {"engine.stage", "engine.reduce", "engine.repack"} <= inside
