@@ -661,7 +661,9 @@ class SimilarityEngine:
     re-promoting and re-uploading the whole candidate set.
 
     ``dispatches`` counts the device top-k dispatches the engine has
-    issued; ``segment_map_builds`` counts the layouts built (construction
+    issued, and ``resident_dispatches`` those of them whose scoring
+    kernel held the query block in VMEM (``kops.similarity_resident``);
+    ``segment_map_builds`` counts the layouts built (construction
     and each :meth:`refresh` that changed something), each with its
     row-to-candidate map ``seg``, which every dispatch then reads.  A
     query's device work is three trace spans: ``engine.query_block``
@@ -682,6 +684,7 @@ class SimilarityEngine:
         self._bitmaps = list(bitmaps)
         self._arena = arena
         self.dispatches = 0
+        self.resident_dispatches = 0
         self.segment_map_builds = 0
         self._mesh = None
         self._nshards = 1
@@ -926,6 +929,8 @@ class SimilarityEngine:
                     exclude=-1 if exclude is None else exclude,
                     seg=dev_seg, backend=backend)
                 self.dispatches += 1
+                self.resident_dispatches += kops.similarity_resident(
+                    q_words.shape[0], backend)
             return self._fetch(res)
         return self._topk_host(self._query_words(query), qc, k, metric,
                                exclude)
@@ -1037,6 +1042,8 @@ class SimilarityEngine:
                 jnp.asarray(gidx), jnp.asarray(np.int32(surv.size)),
                 jnp.asarray(np.int32(-1 if exclude is None else exclude)))
             self.dispatches += 1
+            self.resident_dispatches += kops.similarity_resident(
+                q_words.shape[0], backend)
         return self._fetch(res)
 
     def topk_batch(self, queries, k: int, metric: str = "jaccard", *,

@@ -175,6 +175,13 @@ _ref_similarity_topk = jax.jit(ref.similarity_topk,
                                static_argnames=("metric", "k"))
 
 
+def similarity_resident(n_cols: int, backend: Backend | None = None) -> bool:
+    """Whether a similarity dispatch with an (n_cols, WORDS) query block
+    runs the Pallas scoring kernel with that block resident in VMEM
+    (``topk_ops.resident_query``)."""
+    return _use_pallas(backend) and _topk_ops.resident_query(n_cols)
+
+
 def similarity_topk_ids(rows, row_col, starts, q_words, q_card, cards,
                         gidx, *, metric: str, k: int, n_valid,
                         exclude=-1, axis: str | None = None,

@@ -27,11 +27,20 @@ device -- the serving contract):
 
 Three stages compose inside one jit:
 
-  1. ``_and_card_kernel`` -- grid over 8-row blocks of ``rows``: each
-     step DMAs the 8 query rows its candidate rows name (``row_col``
-     arrives as an SMEM block, never as a prefetched N-vector, which
-     would overflow the TPU's 1 MiB of SMEM at index sizes), ANDs and
-     Harley-Seal popcounts them.
+  1. ``row_and_card`` -- AND each candidate row with the query row its
+     ``row_col`` names and Harley-Seal popcount it, 16 rows a pass
+     (two sublane tiles: more independent work than one, measured
+     faster).  A query block of at most ``RESIDENT_BYTES`` (C <= 512) is
+     one VMEM block with a constant index map, so it loads once a call,
+     and the grid streams the slab through it in ``ROW_BLOCK``-row
+     blocks: with C == 1 (``_and_card_one_kernel``) every row meets the
+     one query row broadcast over sublanes and ``row_col`` is not read;
+     otherwise (``_and_card_cols_kernel``) each 16-row pass copies the
+     query rows it names from the resident block.  A larger block keeps
+     ``_and_card_dma_kernel``: 8-row steps that DMA the query rows they
+     name from HBM.  ``row_col`` arrives as SMEM blocks, never as a
+     prefetched N-vector, which would overflow the TPU's 1 MiB of SMEM
+     at index sizes.
   2. per-candidate sums by ``seg`` (``ref.candidate_inter``; derived
      from ``starts`` when no map is given) and the float32 score
      (``ref.similarity_scores``: correctly rounded, so scores and ties
@@ -63,21 +72,60 @@ from repro.kernels.ref import (METRICS, WORDS, candidate_inter,
                                similarity_scores)
 
 
-ROW_BLOCK = 8           # candidate rows per AND-popcount grid step
+ROW_BLOCK = 256         # candidate rows per grid step, query block resident
+_SUB = 16               # rows per Harley-Seal pass: two sublane tiles
+RESIDENT_BYTES = 4 << 20  # the largest query block held in VMEM
+DMA_ROW_BLOCK = 8       # candidate rows per grid step of the DMA fallback
 _COL_BLOCK = 1024       # row_col entries per SMEM block (XLA tiles 1-D by 1024)
 SELECT_ROWS = 64        # select block: (64, 128) labelled entries
 _LANES = 128
 _BIG = 2**31 - 1        # label of layout padding: never the lowest
 
 
-def _and_card_kernel(col_ref, rows_ref, q_hbm, out_ref, qbuf, sem):
-    """|rows[r] & q_words[row_col[r]]| for one 8-row block: the query
-    rows the block names are DMA'd from HBM by column.  Query rows live
-    as (C, 1, WORDS) so that one row is a whole tile to the DMA engine."""
+def resident_query(n_cols: int) -> bool:
+    """Whether a (n_cols, WORDS) query block is held in VMEM for a whole
+    scoring call; a larger one is DMA'd row by row as the candidate rows
+    name its columns."""
+    return n_cols * WORDS * 4 <= RESIDENT_BYTES
+
+
+def _and_card_one_kernel(rows_ref, q_ref, out_ref):
+    """One query column: every row of the block ANDs with the resident
+    query row, broadcast over sublanes, 16 rows a Harley-Seal pass."""
+    q = q_ref[...]
+
+    def body(j, carry):
+        r = pl.multiple_of(j * _SUB, _SUB)
+        out_ref[pl.ds(r, _SUB), :] = harley_seal_reduce(
+            rows_ref[pl.ds(r, _SUB), :] & q)
+        return carry
+    jax.lax.fori_loop(0, ROW_BLOCK // _SUB, body, 0)
+
+
+def _and_card_cols_kernel(col_ref, rows_ref, q_ref, out_ref, qbuf):
+    """Several query columns: each 16-row pass copies the resident query
+    rows its candidate rows name into one (16, WORDS) buffer."""
     base = (pl.program_id(0) % (_COL_BLOCK // ROW_BLOCK)) * ROW_BLOCK
+
+    def body(j, carry):
+        r = pl.multiple_of(j * _SUB, _SUB)
+        for i in range(_SUB):
+            qbuf[pl.ds(i, 1), :] = q_ref[pl.ds(col_ref[base + r + i], 1), :]
+        out_ref[pl.ds(r, _SUB), :] = harley_seal_reduce(
+            rows_ref[pl.ds(r, _SUB), :] & qbuf[...])
+        return carry
+    jax.lax.fori_loop(0, ROW_BLOCK // _SUB, body, 0)
+
+
+def _and_card_dma_kernel(col_ref, rows_ref, q_hbm, out_ref, qbuf, sem):
+    """One 8-row block against a query block too large to hold: the
+    query rows the block names are DMA'd from HBM by column.  Query rows
+    live as (C, 1, WORDS) so that one row is a whole tile to the DMA
+    engine."""
+    base = (pl.program_id(0) % (_COL_BLOCK // DMA_ROW_BLOCK)) * DMA_ROW_BLOCK
     copies = [pltpu.make_async_copy(q_hbm.at[col_ref[base + i]],
                                     qbuf.at[i], sem.at[i])
-              for i in range(ROW_BLOCK)]
+              for i in range(DMA_ROW_BLOCK)]
     for c in copies:
         c.start()
     for i, c in enumerate(copies):
@@ -88,6 +136,7 @@ def _and_card_kernel(col_ref, rows_ref, q_hbm, out_ref, qbuf, sem):
 
 def _interpret_params(interpret: bool):
     # DMAs and semaphores need the TPU-semantics interpreter off the chip
+    # (slower than the plain one, which the resident paths use)
     return pltpu.InterpretParams() if interpret else False
 
 
@@ -102,27 +151,48 @@ def _pad_to(x, size, fill):
 def row_and_card(rows: jax.Array, row_col: jax.Array, q_words: jax.Array,
                  *, interpret: bool) -> jax.Array:
     """(N,) int32 per-row ``popcount(rows[r] & q_words[row_col[r]])``.
-    The candidate slab is never padded or copied: the last 8-row block
-    may run past row N-1, and its extra outputs are dropped."""
-    n = rows.shape[0]
-    steps = -(-n // ROW_BLOCK)
-    n_col = -(-n // _COL_BLOCK) * _COL_BLOCK
+
+    The query block's shape picks the path (``resident_query``): a block
+    that fits is loaded into VMEM once and the slab streams through it
+    in ``ROW_BLOCK``-row steps (with one column no ``row_col`` is read);
+    a larger one DMAs each 8-row step's query rows.  The candidate slab
+    is never padded or copied: the last block may run past row N-1, and
+    its extra outputs are dropped."""
+    n, c = rows.shape[0], q_words.shape[0]
+    rows = rows.astype(jnp.uint32)
+    q_words = q_words.astype(jnp.uint32)
+    block = ROW_BLOCK if resident_query(c) else DMA_ROW_BLOCK
+    row_spec = pl.BlockSpec((block, WORDS), lambda i: (i, 0))
+    q_spec = pl.BlockSpec((c, WORDS), lambda i: (0, 0))
+    cols = _pad_to(row_col.astype(jnp.int32),
+                   -(-n // _COL_BLOCK) * _COL_BLOCK, 0)
+    col_spec = pl.BlockSpec((_COL_BLOCK,),
+                            lambda i: (i // (_COL_BLOCK // block),),
+                            memory_space=pltpu.SMEM)
+    if block == DMA_ROW_BLOCK:
+        kernel = _and_card_dma_kernel
+        scratch = [pltpu.VMEM((block, 1, WORDS), jnp.uint32),
+                   pltpu.SemaphoreType.DMA((block,))]
+        in_specs = [col_spec, row_spec, pl.BlockSpec(memory_space=pl.ANY)]
+        args = (cols, rows, q_words.reshape(c, 1, WORDS))
+        interpret = _interpret_params(interpret)
+    elif c == 1:
+        kernel, scratch = _and_card_one_kernel, []
+        in_specs, args = [row_spec, q_spec], (rows, q_words)
+    else:
+        kernel = _and_card_cols_kernel
+        scratch = [pltpu.VMEM((_SUB, WORDS), jnp.uint32)]
+        in_specs, args = [col_spec, row_spec, q_spec], (cols, rows, q_words)
+    steps = -(-n // block)
     out = pl.pallas_call(
-        _and_card_kernel,
+        kernel,
         grid=(steps,),
-        in_specs=[pl.BlockSpec((_COL_BLOCK,),
-                               lambda i: (i // (_COL_BLOCK // ROW_BLOCK),),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec((ROW_BLOCK, WORDS), lambda i: (i, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((ROW_BLOCK, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((steps * ROW_BLOCK, 1), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((ROW_BLOCK, 1, WORDS), jnp.uint32),
-                        pltpu.SemaphoreType.DMA((ROW_BLOCK,))],
-        interpret=_interpret_params(interpret),
-    )(_pad_to(row_col.astype(jnp.int32), n_col, 0),
-      rows.astype(jnp.uint32),
-      q_words.astype(jnp.uint32).reshape(q_words.shape[0], 1, WORDS))
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((block, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps * block, 1), jnp.int32),
+        scratch_shapes=scratch,
+        interpret=interpret,
+    )(*args)
     return out[:n, 0]
 
 

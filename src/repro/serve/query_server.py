@@ -11,7 +11,8 @@ plus one ``SimilarityEngine.topk_batch`` call per (k, metric) similarity
 class over the cached candidate slab: one vmapped score+select dispatch
 on the jnp backend, one dispatch per query on the Pallas and sharded
 paths, none on the host sweep (``ServerStats.sim_dispatches`` counts
-them).
+them, and ``sim_resident_dispatches`` those whose scoring kernel held
+the query block in VMEM).
 
 Observability: each tick is a ``serve.tick`` trace span, and the
 server's phases are spans whose time also adds up in ``ServerStats``
@@ -426,11 +427,13 @@ class QueryServer:
             for (k, metric), group in by_class.items():
                 with self._phase("serve.lookup", "lookup_s"):
                     queries = [self._sim_query(t, terms) for t in group]
-                sent = eng.dispatches
+                sent, sent_resident = eng.dispatches, eng.resident_dispatches
                 with self._phase("serve.score", "score_s"):
                     res = eng.topk_batch(queries, k, metric,
                                          backend=self.backend)
                 self._stats.sim_dispatches += eng.dispatches - sent
+                self._stats.sim_resident_dispatches += (
+                    eng.resident_dispatches - sent_resident)
                 with self._phase("serve.resolve", "resolve_s"):
                     for t, (idx, score, _) in zip(group, res):
                         t._value = [(terms[i], float(s))

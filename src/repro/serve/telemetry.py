@@ -73,6 +73,7 @@ class ServerStats:
     score_s: float = 0.0        # serve.score: topk_batch, answers fetched
     resolve_s: float = 0.0      # serve.resolve: answers to tickets
     sim_dispatches: int = 0     # device top-k dispatches of the engine
+    sim_resident_dispatches: int = 0  # of them, query block held in VMEM
     # the boolean path: its phases' seconds (``span``), and what the
     # plans of the batches that reached the device held
     plan_s: float = 0.0         # serve.plan: planning at admission

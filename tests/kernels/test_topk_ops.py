@@ -6,7 +6,9 @@ must produce identical (idx, score, inter) triples from
 ``topk_ops.similarity_topk`` (interpret mode) and ``ref.similarity_topk``
 -- including first-max tie ordering, which the selection contract rides
 on -- whether the kernel is given the layout's row-to-candidate map or
-derives it from ``starts``."""
+derives it from ``starts``, and on each of the scoring kernel's paths:
+one query column, several columns held in VMEM, and a query block over
+the VMEM budget, whose rows are DMA'd."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -63,21 +65,36 @@ def row_candidates(starts, n_rows):
     return jnp.asarray(np.asarray(seg, np.int32))
 
 
-def layouts(rng):
+# query columns per path: one, several held in VMEM, one over the budget
+COLS = [1, 4, topk_ops.RESIDENT_BYTES // (4 * WORDS) + 1]
+assert topk_ops.resident_query(COLS[1])
+assert not topk_ops.resident_query(COLS[2])
+
+
+def layouts(rng, c):
     """Zero-row candidates (4 of 12), the same with 5 padding rows past
-    ``starts[-1]``, and one row per candidate (NETFLIX's layout)."""
+    ``starts[-1]``, and one row per candidate (NETFLIX's layout), each
+    fewer rows than a row block; then one row per candidate over a row
+    block and a part of the next."""
     lens = [0, 2, 1, 0, 4, 1, 0, 3, 1, 2, 0, 1]
-    yield random_case(rng, 12, 4, lens=lens)
-    yield random_case(rng, 12, 4, lens=lens, pad=5)
-    yield random_case(rng, 17, 4, lens=[1] * 17)
+    yield random_case(rng, 12, c, lens=lens)
+    yield random_case(rng, 12, c, lens=lens, pad=5)
+    yield random_case(rng, 17, c, lens=[1] * 17)
+    t = topk_ops.ROW_BLOCK + 44
+    yield random_case(rng, t, c, lens=[1] * t)
 
 
 @pytest.mark.parametrize("given", [True, False], ids=["map_given",
                                                       "map_derived"])
 @pytest.mark.parametrize("metric", ref.METRICS)
-def test_kernel_matches_oracle(rng, metric, given):
-    for rows, row_col, starts, q, q_card, cards in layouts(rng):
+@pytest.mark.parametrize("c", COLS, ids=["one_col", "resident", "dma"])
+def test_kernel_matches_oracle(rng, c, metric, given):
+    """Without a map, the labelled entry ``similarity_topk_ids`` (which
+    always derives it) is held to its oracle on the same layouts."""
+    for rows, row_col, starts, q, q_card, cards in layouts(rng, c):
+        t = cards.shape[0]
         seg = row_candidates(starts, rows.shape[0]) if given else None
+        gidx = jnp.asarray(np.arange(t, dtype=np.int32) * 3 + 1)
         for exclude in (-1, 3):
             ki, ks, kn = topk_ops.similarity_topk(
                 rows, row_col, starts, q, jnp.int32(q_card), cards,
@@ -90,6 +107,15 @@ def test_kernel_matches_oracle(rng, metric, given):
             assert np.array_equal(np.asarray(ks), np.asarray(os_))
             assert np.array_equal(np.asarray(kn), np.asarray(on))
             assert exclude not in np.asarray(ki).tolist() or exclude == -1
+            if given:
+                continue
+            args = (rows, row_col, starts, q, jnp.int32(q_card), cards,
+                    gidx, jnp.int32(t - 2), jnp.int32(3 * exclude + 1))
+            got = topk_ops.similarity_topk_ids(*args, metric=metric, k=5,
+                                               interpret=True)
+            want = ref.similarity_topk_ids(*args, metric=metric, k=5)
+            for a, b in zip(got, want):
+                assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_candidate_inter_drops_padding(rng):
@@ -134,15 +160,17 @@ def test_oracle_inter_and_tie_order(rng):
 
 @pytest.mark.parametrize("given", [True, False], ids=["map_given",
                                                       "map_derived"])
-def test_empty_segments_score_zero(rng, given):
+@pytest.mark.parametrize("c", COLS, ids=["one_col", "resident", "dma"])
+def test_empty_segments_score_zero(rng, c, given):
     """Candidates with no rows (empty bitmaps) must score from
-    inter = 0, not garbage, on both paths."""
+    inter = 0, not garbage, on both paths and each scoring kernel."""
     rows = jnp.asarray((rng.random((3, WORDS)) < 0.05)
                        .astype(np.uint32))
-    row_col = jnp.asarray(np.zeros(3, np.int32))
+    row_col = jnp.asarray(np.asarray([0, c - 1, 0], np.int32))
     starts = jnp.asarray(np.asarray([0, 0, 3, 3], np.int32))  # t0/t2 empty
     seg = jnp.asarray(np.asarray([1, 1, 1], np.int32)) if given else None
-    q = rows[:1]
+    q = jnp.zeros((c, WORDS), jnp.uint32).at[c - 1].set(
+        rows[1] ^ rows[2]).at[0].set(rows[0])
     cards = jnp.asarray(np.asarray(
         [0, int(np.bitwise_count(np.asarray(rows)).sum()), 0], np.int32))
     q_card = int(np.bitwise_count(np.asarray(q)).sum())

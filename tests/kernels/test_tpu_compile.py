@@ -115,25 +115,44 @@ def test_segment_reduce_cnf(one_chip):
              ((SEGS + 1,), _I32))
 
 
-def test_similarity_topk(one_chip):
+def _compile_topk(sharding, keys):
     def fn(rows, col, starts, q, cards, seg):
         return topk_ops.similarity_topk(
             rows, col, starts, q, jnp.int32(1000), cards, jnp.int32(7),
             seg, metric="cosine", k=10, interpret=False)
-    _compile(one_chip, fn, ((ROWS, WORDS), _U32), ((ROWS,), _I32),
-             ((ROWS + 1,), _I32), ((KEYS, WORDS), _U32), ((ROWS,), _I32),
+    _compile(sharding, fn, ((ROWS, WORDS), _U32), ((ROWS,), _I32),
+             ((ROWS + 1,), _I32), ((keys, WORDS), _U32), ((ROWS,), _I32),
              ((ROWS,), _I32))
 
 
-def test_similarity_topk_ids(one_chip):
+def _compile_topk_ids(sharding, keys):
     def fn(rows, col, starts, q, cards, gidx):
         return topk_ops.similarity_topk_ids(
             rows, col, starts, q, jnp.int32(1000), cards, gidx,
             jnp.int32(ROWS - 5), jnp.int32(7), metric="jaccard", k=10,
             interpret=False)
-    _compile(one_chip, fn, ((ROWS, WORDS), _U32), ((ROWS,), _I32),
-             ((ROWS + 1,), _I32), ((KEYS, WORDS), _U32), ((ROWS,), _I32),
+    _compile(sharding, fn, ((ROWS, WORDS), _U32), ((ROWS,), _I32),
+             ((ROWS + 1,), _I32), ((keys, WORDS), _U32), ((ROWS,), _I32),
              ((ROWS,), _I32))
+
+
+def test_similarity_topk(one_chip):
+    _compile_topk(one_chip, KEYS)
+
+
+def test_similarity_topk_ids(one_chip):
+    _compile_topk_ids(one_chip, KEYS)
+
+
+# the scoring kernel's paths: one resident query column (NETFLIX's
+# layout), a mid-size resident block, and a block over the VMEM budget
+@pytest.mark.parametrize("keys", [1, 64, 1024],
+                         ids=["one_col", "resident", "dma"])
+@pytest.mark.parametrize("entry", [_compile_topk, _compile_topk_ids],
+                         ids=["topk", "topk_ids"])
+def test_similarity_topk_paths(one_chip, entry, keys):
+    assert topk_ops.resident_query(keys) == (keys < 1024)
+    entry(one_chip, keys)
 
 
 def test_topk_merge(one_chip):

@@ -34,6 +34,7 @@ class _Engine:
     def __init__(self, clock):
         self.clock = clock
         self.dispatches = 0
+        self.resident_dispatches = 0
 
     def topk_batch(self, queries, k, metric, backend=None):
         self.clock.sleep(SCORE_S)
@@ -118,6 +119,40 @@ def test_sim_dispatches_count_the_engines_device_calls(index, backend,
         q = t.query
         assert t.result.ok and not t.telemetry.degraded
         assert t.result.value == index.similar(q.terms[0], q.k, q.metric)
+
+
+def _wide_index():
+    """Postings over one chunk key more than the scoring kernel holds in
+    VMEM: ``a`` has a value in each chunk, ``b`` and ``c`` in a few."""
+    from repro.core import RoaringBitmap
+    from repro.kernels import topk_ops
+    from repro.kernels.ref import WORDS
+    n = topk_ops.RESIDENT_BYTES // (4 * WORDS) + 1
+    chunks = {"a": np.arange(n), "b": np.arange(0, n, 97),
+              "c": np.arange(3, n, 131)}
+    postings = {t: RoaringBitmap.from_values(
+        ((c.astype(np.uint32) << 16) | 5).astype(np.uint32))
+        for t, c in chunks.items()}
+    return InvertedIndex.from_postings(postings, n << 16)
+
+
+@pytest.mark.parametrize("wide", [False, True],
+                         ids=["one_chunk", "over_budget"])
+def test_resident_dispatches_follow_the_query_block(index, wide):
+    """A single-chunk index scores every query against a resident query
+    block; an index over more chunk keys than the budget holds none."""
+    ix = _wide_index() if wide else index
+    qs = [Query.similar("b", k=2)] if wide else _queries()
+    srv = QueryServer(ix, backend="pallas", clock=FakeClock())
+    tickets = [srv.submit(q) for q in qs]
+    srv.step()
+    st = srv.stats()
+    assert st.sim_dispatches == len(qs)
+    assert st.sim_resident_dispatches == (0 if wide else len(qs))
+    for t in tickets:
+        q = t.query
+        assert t.result.ok and not t.telemetry.degraded
+        assert t.result.value == ix.similar(q.terms[0], q.k, q.metric)
 
 
 def _events(path):
